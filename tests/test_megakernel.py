@@ -396,3 +396,43 @@ def test_streamed_eval_bounds_matches_oracle():
         nq, board, depth, mt=16, interpret=True))
     np.testing.assert_array_equal(nq_tiled, nq_one)
     assert nq_one.shape == board.shape
+
+
+# -- stable names in a profiler trace ---------------------------------------
+
+def _kernel_jaxprs(prog):
+    """Every pallas_call eqn of the resident step's jaxpr."""
+    from tpu_tree_search.analysis.contracts import prim_eqns
+
+    state = prog.init_state({}, 0)
+    jaxpr = jax.make_jaxpr(prog._step)(*state)
+    return [eqn for name, eqn in prim_eqns(jaxpr) if name == "pallas_call"]
+
+
+@pytest.mark.parametrize("mt", [None, "16"])
+@pytest.mark.parametrize("family,name", [
+    ("nqueens", "tts_cycle_nqueens"),
+    ("pfsp-lb1", "tts_cycle_lb1"),
+    ("pfsp-lb2", "tts_cycle_lb2"),
+])
+def test_cycle_kernel_carries_its_trace_names(family, name, mt, monkeypatch):
+    """The armed step's one pallas_call is named ``tts_cycle_<family>``
+    (the HLO instruction a profiler trace names the kernel by), single-tile
+    and streamed alike, and its body holds the ``tts_bound`` and
+    ``tts_compact`` regions."""
+    from tpu_tree_search.engine.resident import _make_program, resolve_capacity
+
+    monkeypatch.setenv("TTS_MEGAKERNEL", "force")
+    if mt:
+        monkeypatch.setenv("TTS_MEGAKERNEL_MT", mt)
+    prob = _mk_problem(family)()
+    capacity, M = resolve_capacity(prob, 64, None)
+    prog = _make_program(prob, 4, M, 4, capacity, jax.devices()[0])
+    assert prog.megakernel.enabled and prog.megakernel.tiled == bool(mt)
+    from tpu_tree_search.analysis.contracts import prim_eqns
+
+    (eqn,) = _kernel_jaxprs(prog)
+    assert eqn.params["name"] == name
+    scopes = {str(e.source_info.name_stack)
+              for _, e in prim_eqns(eqn.params["jaxpr"])}
+    assert {MK.BOUND_SCOPE, MK.COMPACT_SCOPE} <= scopes

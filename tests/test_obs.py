@@ -275,6 +275,84 @@ def test_recorder_thread_merge_and_disabled_noop(monkeypatch):
     assert {e["tid"] for e in evts} == {0, 1, 2, 99}
 
 
+class _FakeProfiler:
+    """Stands in for ``jax.profiler``: records every annotation entered
+    and left, with its stats."""
+
+    def __init__(self):
+        self.log = []
+        log = self.log
+
+        class TraceAnnotation:
+            def __init__(self, name, **stats):
+                self.name, self.stats = name, stats
+
+            def __enter__(self):
+                log.append(("enter", self.name, self.stats))
+
+            def __exit__(self, *exc):
+                log.append(("exit", self.name))
+
+        self.TraceAnnotation = TraceAnnotation
+
+
+@pytest.mark.parametrize("obs", ["0", "host"])
+def test_span_enters_the_profiler_annotation_either_way(obs, monkeypatch):
+    """``events.span`` always puts its block into the profiler's trace
+    (its args as the annotation's stats); only with TTS_OBS on does it
+    also record a Chrome event."""
+    import sys
+
+    fake = _FakeProfiler()
+    monkeypatch.setitem(sys.modules, "jax.profiler", fake)
+    monkeypatch.setenv("TTS_OBS", obs)
+    events.reset()
+    with events.span("tts.wait", args={"search": 4, "dispatch": 2}):
+        fake.log.append(("body",))
+    assert fake.log == [("enter", "tts.wait", {"search": 4, "dispatch": 2}),
+                        ("body",), ("exit", "tts.wait")]
+    evts = events.drain()
+    if obs == "0":
+        assert evts == []
+    else:
+        (ev,) = evts
+        assert (ev["name"], ev["ph"]) == ("tts.wait", "X")
+        assert ev["args"] == {"search": 4, "dispatch": 2}
+
+
+def test_span_without_jax_loaded_annotates_nothing(monkeypatch):
+    """Where JAX was never imported (the host-only fleet router), a span
+    neither imports it nor annotates."""
+    import sys
+
+    monkeypatch.delitem(sys.modules, "jax.profiler", raising=False)
+    monkeypatch.setenv("TTS_OBS", "host")
+    events.reset()
+    with events.span("tts.program") as sp:
+        assert sp._ann is None
+    assert "jax.profiler" not in sys.modules
+    assert [e["name"] for e in events.drain()] == ["tts.program"]
+
+
+def test_search_span_numbers_each_search_once(monkeypatch):
+    """A search span binds one number for the spans inside it (an inner
+    search span keeps it) and the next search gets another."""
+    import sys
+
+    fake = _FakeProfiler()
+    monkeypatch.setitem(sys.modules, "jax.profiler", fake)
+    with events.search_span("tts.search"):
+        seq = events.search_seq()
+        assert events.search_seq() == seq
+        with events.search_span("tts.search"):
+            assert events.search_seq() == seq
+    assert events.search_seq() > seq
+    with events.search_span("tts.search"):
+        assert events.search_seq() > seq + 1
+    stats = [s for kind, _, *s in fake.log if kind == "enter"]
+    assert stats[0] == stats[1] == [{"search": seq}]
+
+
 def test_counter_block_merge_semantics():
     import numpy as np
 

@@ -216,54 +216,42 @@ def test_cli_phase_profile_flag_rejected_off_resident():
                   "--phase-profile"])
 
 
-def test_xla_trace_window_brackets_steady_state(tmp_path, monkeypatch):
-    calls = []
+def test_cli_profile_trace_nests_the_search_spans(tmp_path):
+    """``--profile DIR`` is the one in-program profiler entry point: on
+    one host line of its trace, the search's host spans nest under
+    ``tts.search`` in the order the search runs them, every one carries
+    the search's number, and each dispatch has one ``tts.dispatch`` and
+    one later ``tts.wait`` of the same number."""
+    import glob
 
-    class _FakeProfiler:
-        @staticmethod
-        def start_trace(d):
-            calls.append(("start", d))
-
-        @staticmethod
-        def stop_trace():
-            calls.append(("stop", None))
-
-    import jax
-
-    monkeypatch.setattr(jax, "profiler", _FakeProfiler)
-    monkeypatch.setenv("TTS_XLA_TRACE", str(tmp_path / "xt"))
-    win = phases.XlaTraceWindow("resident")
-    win.on_dispatch(1)  # first dispatch = compile; window stays closed
-    assert calls == []
-    win.on_dispatch(2)
-    assert calls == [("start", str(tmp_path / "xt"))]
-    # A second window (dist_mesh virtual-host thread) is a no-op while
-    # the first is active — the jax profiler is process-global.
-    win2 = phases.XlaTraceWindow("dist_mesh")
-    win2.on_dispatch(5)
-    win2.close()
-    assert calls == [("start", str(tmp_path / "xt"))]
-    win.on_dispatch(3)  # already started: no re-arm
-    win.close()
-    assert calls[-1] == ("stop", None)
-    # Released: a later run can open a new window.
-    win3 = phases.XlaTraceWindow("resident")
-    assert win3._owner
-    win3.close()
-
-
-def test_cli_xla_trace_end_to_end(tmp_path, monkeypatch):
-    import os
+    from jax.profiler import ProfileData
 
     out_dir = tmp_path / "xprof"
     rc = cli.main(["nqueens", "--N", "9", "--tier", "device", "--M", "128",
-                   "--m", "5", "--K", "4", "--xla-trace", str(out_dir)])
+                   "--m", "5", "--K", "4", "--profile", str(out_dir)])
     assert rc == 0
-    # The steady-state capture landed (jax writes
-    # plugins/profile/<ts>/*.xplane.pb under the directory).
-    files = [f for _, _, fs in os.walk(out_dir) for f in fs]
-    assert files, "no XLA trace artifacts written"
-    assert os.environ.get("TTS_XLA_TRACE") is None
+    (path,) = glob.glob(str(out_dir / "**" / "*.xplane.pb"), recursive=True)
+    prof = ProfileData.from_file(path)
+    lines = [[(e.name, e.start_ns, e.start_ns + e.duration_ns, dict(e.stats))
+              for e in line.events if e.name.startswith("tts.")]
+             for plane in prof.planes if plane.name == "/host:CPU"
+             for line in plane.lines]
+    (spans,) = [ln for ln in lines if ln]
+    spans.sort(key=lambda s: (s[1], -s[2]))
+    (name, t0, t1, stats), rest = spans[0], spans[1:]
+    assert name == "tts.search"
+    assert all(t0 <= a and b <= t1 for _, a, b, _ in rest)
+    assert {s["search"] for *_, s in spans} == {stats["search"]}
+    names = [n for n, *_ in rest]
+    assert names[:3] == ["tts.warmup", "tts.program", "tts.upload"]
+    assert names[-2:] == ["tts.download", "tts.drain"]
+    loop = rest[3:-2]
+    assert {n for n, *_ in loop} == {"tts.dispatch", "tts.wait"}
+    sent = {s["dispatch"]: b for n, _, b, s in loop if n == "tts.dispatch"}
+    waited = {s["dispatch"]: a for n, a, _, s in loop if n == "tts.wait"}
+    assert len(sent) == len([n for n, *_ in loop if n == "tts.dispatch"])
+    assert sorted(sent) == sorted(waited) == list(range(1, len(sent) + 1))
+    assert all(sent[d] <= waited[d] for d in sent)
 
 
 def test_flightrec_snapshot_names_dominant_phase(monkeypatch):

@@ -6,8 +6,9 @@ the (8, 128) tiling, a shape cast between sublanes and lanes, more VMEM
 than the scope) would hide behind a green suite.  These tests compile each
 kernel at the real widths for a ``v5e:2x2`` topology that JAX describes
 from the installed libtpu, and assert that the program carries the kernel
-(``tpu_custom_call``).  Nothing runs, so results and times come only from
-``chip_smoke.py`` on the chip.
+(``tpu_custom_call``) under the name a profiler trace shows it by
+(``%tts_cycle_<family>``, ``%tts_eval_<family>``).  Nothing runs, so
+results and times come only from ``chip_smoke.py`` on the chip.
 
 The topology is described inside a module fixture — never while a module
 is imported — so every pytest-xdist worker collects the same tests and
@@ -68,6 +69,7 @@ def test_nqueens_labels_compiles_n14(chip):
         ((B, N), jnp.uint8), ((B,), jnp.int32),
     )
     assert "tpu_custom_call" in txt
+    assert "%tts_eval_nqueens" in txt  # the name a profiler trace shows
 
 
 def test_lb1_bounds_compiles_ta014(chip):
@@ -83,6 +85,7 @@ def test_lb1_bounds_compiles_ta014(chip):
         chip, ((B, n), jnp.int32), ((B,), jnp.int32),
     )
     assert "tpu_custom_call" in txt
+    assert "%tts_eval_lb1" in txt
 
 
 @pytest.mark.parametrize("fam", ["lb1", "nqueens"])
@@ -112,3 +115,4 @@ def test_megakernel_cycle_compiles_at_auto_tile(fam, chip):
         ((), jnp.int32),
     )
     assert "tpu_custom_call" in txt
+    assert f"%tts_cycle_{fam}" in txt

@@ -53,7 +53,7 @@ DEFAULT_BASELINE = ".tts-contracts.json"
 KNOBS = (
     "TTS_COMPACT", "TTS_OBS", "TTS_PHASEPROF", "TTS_LB2_PAIRBLOCK",
     "TTS_PIPELINE", "TTS_K", "TTS_GUARD", "TTS_PALLAS", "TTS_PALLAS_LB2",
-    "TTS_LB2_STAGED", "TTS_XLA_TRACE", "TTS_FLIGHTREC", "TTS_COSTMODEL",
+    "TTS_LB2_STAGED", "TTS_FLIGHTREC", "TTS_COSTMODEL",
     "TTS_QUALITY", "TTS_MEGAKERNEL", "TTS_MEGAKERNEL_MT", "TTS_STEAL",
     "TTS_PODS", "TTS_SIM_LAT_ICI", "TTS_SIM_LAT_DCN", "TTS_NARROW",
     "TTS_HBM_GBPS", "TTS_KERNEL_BACKEND", "TTS_PALLAS_GPU_MB",

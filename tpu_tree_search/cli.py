@@ -128,8 +128,13 @@ def build_parser() -> argparse.ArgumentParser:
                         "seconds (default 0.02; backs off geometrically "
                         "while all hosts are busy)")
     common.add_argument("--profile", type=str, default=None,
+                        metavar="DIR",
                         help="write a jax profiler trace of the search to "
-                        "this directory (view with TensorBoard/XProf)")
+                        "this directory (view with TensorBoard/XProf): "
+                        "the search's host spans (tts.search, "
+                        "tts.dispatch, tts.wait, ...) and its named device "
+                        "kernels (tts_cycle_<family>, tts_eval_<family>) "
+                        "on one clock (docs/OBSERVABILITY.md leg 2)")
     common.add_argument("--trace", type=str, default=None,
                         help="write a Chrome-trace-event JSON of the run's "
                         "telemetry to this file (open in Perfetto; "
@@ -167,14 +172,6 @@ def build_parser() -> argparse.ArgumentParser:
                         "with the results. Never use for headline "
                         "measurements — see `tts profile` and "
                         "docs/OBSERVABILITY.md leg 7")
-    common.add_argument("--xla-trace", type=str, default=None,
-                        metavar="DIR",
-                        help="capture an XLA profiler trace of the "
-                        "steady-state dispatch window into DIR (opens "
-                        "after the first dispatch — warmup and while-loop "
-                        "compile excluded; view with TensorBoard/XProf). "
-                        "Equivalent to TTS_XLA_TRACE=DIR; --profile "
-                        "traces the whole session instead")
     common.add_argument("--guard", action="store_true",
                         help="resident tiers: assert every steady-state "
                         "device dispatch performs zero recompilations and "
@@ -250,10 +247,11 @@ def build_parser() -> argparse.ArgumentParser:
     prof = sub.add_parser(
         "profile",
         help="run a search with the per-phase cycle clocks armed and "
-        "print the decomposition table (plus an optional --xla-trace "
-        "capture): `tts profile pfsp --inst 14 --tier device "
-        "[--xla-trace DIR]` — sugar for the same run command with "
-        "--phase-profile forced on (docs/OBSERVABILITY.md leg 7)",
+        "print the decomposition table: `tts profile pfsp --inst 14 "
+        "--tier device [--profile DIR]` — sugar for the same run command "
+        "with --phase-profile forced on. On the one-kernel cycle the "
+        "clocks charge the whole cycle to eval; --profile DIR traces the "
+        "named kernels instead (docs/OBSERVABILITY.md leg 7)",
     )
     prof.add_argument("rest", nargs=argparse.REMAINDER,
                       help="a full run command (problem + flags)")
@@ -473,11 +471,6 @@ def validate_args(parser: argparse.ArgumentParser, args) -> None:
             "dist_mesh); the offload/multi/dist workers have no device "
             "cycle to decompose"
         )
-    if args.xla_trace is not None and args.profile is not None:
-        parser.error(
-            "--xla-trace (steady-state dispatch window) and --profile "
-            "(whole session) are both XLA profiler captures — pick one"
-        )
     if args.compact is not None and not uses_compaction(args):
         parser.error(
             "--compact only applies to runs with device-side compaction "
@@ -613,14 +606,21 @@ def uses_compaction(args) -> bool:
 
 
 def run_tier(problem, args):
+    """One whole search of ``problem`` as ``args`` ask, under the
+    ``tts.search`` span (docs/OBSERVABILITY.md leg 2)."""
+    from .obs import events as obs_events
+
+    with obs_events.search_span("tts.search"):
+        return _run_tier(problem, args)
+
+
+def _run_tier(problem, args):
     args.M = resolve_chunk_size(args.M, getattr(problem, "name", ""),
                                 args.tier, args.engine)
     # Flag > env for THIS run only: restore on exit so a caller invoking
     # main() twice in one process does not inherit the pins (compaction
     # programs cache per mode via the routing token; the guard is read at
     # engine start).
-    import os
-
     pins = {}
     if args.compact is not None:
         pins["TTS_COMPACT"] = args.compact
@@ -630,8 +630,6 @@ def run_tier(problem, args):
         pins["TTS_GUARD"] = "1"
     if args.phase_profile:
         pins["TTS_PHASEPROF"] = "1"
-    if args.xla_trace is not None:
-        pins["TTS_XLA_TRACE"] = args.xla_trace
     if (
         (args.trace is not None or args.metrics_file is not None
          or args.obs_serve is not None or args.costmodel is not None)
@@ -786,9 +784,6 @@ def print_settings(args) -> None:
         if args.phase_profile:
             print("Phase profiler (TTS_PHASEPROF): armed — separate "
                   "program variant, NOT a headline measurement")
-        if args.xla_trace is not None:
-            print(f"XLA trace capture (TTS_XLA_TRACE): {args.xla_trace} "
-                  "(steady-state dispatch window)")
     if args.tier in ("dist", "dist_mesh"):
         # Raw steal-hierarchy knob; the RESOLVED per-link-class periods
         # and quanta are printed with the results and recorded in the
@@ -1089,8 +1084,8 @@ def main(argv=None) -> int:
     if args.problem == "profile":
         # `tts profile <run-args>`: the same run command with the phase
         # clocks forced on; the decomposition table prints with the
-        # results (add --xla-trace DIR inside <run-args> to also bank a
-        # steady-state XLA capture).
+        # results (add --profile DIR inside <run-args> to also write a
+        # profiler trace of the search).
         rest = [a for a in args.rest if a != "--"]
         if not rest:
             parser.error(

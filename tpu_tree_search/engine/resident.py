@@ -55,7 +55,7 @@ from ..pool import SoAPool
 from ..problems.base import INF_BOUND, Problem
 from ..problems.nqueens import NQueensProblem
 from ..problems.pfsp.problem import PFSPProblem
-from .device import DeviceOffloader, bucket_size, warmup
+from .device import DeviceOffloader, bucket_size, drain, warmup
 from .results import Diagnostics, PhaseStats, SearchResult
 
 
@@ -199,6 +199,7 @@ class _ResidentProgram:
         (obs/phases.py — clock reads fenced by ``lax.optimization_barrier``
         at the pop/eval/compact/push boundaries); when both are off the
         carry is exactly the 7-tuple above."""
+        import jax
         import jax.numpy as jnp
         from jax import lax
 
@@ -236,15 +237,19 @@ class _ResidentProgram:
                     ph, "loop", pool_vals, pool_aux, size
                 )
                 t_cycle0 = ph[obs_phases.TPREV]
-            cnt = jnp.minimum(size, M)
-            start = size - cnt
-            start2 = jnp.clip(start, 0, C - M)
-            idx = start2 + jnp.arange(M, dtype=jnp.int32)
-            valid = (idx >= start) & (idx < size)
-            vals8_c = lax.dynamic_slice(pool_vals, (start2, 0), (M, n))
-            vals_c = vals8_c.astype(jnp.int32)
-            aux_c = lax.dynamic_slice(pool_aux, (start2,), (M,)).astype(jnp.int32)
-            size = size - cnt
+            # XLA scopes (tts_pop, tts_push) name the loop's own ops in a
+            # profiler trace; they are op metadata only.
+            with jax.named_scope("tts_pop"):
+                cnt = jnp.minimum(size, M)
+                start = size - cnt
+                start2 = jnp.clip(start, 0, C - M)
+                idx = start2 + jnp.arange(M, dtype=jnp.int32)
+                valid = (idx >= start) & (idx < size)
+                vals8_c = lax.dynamic_slice(pool_vals, (start2, 0), (M, n))
+                vals_c = vals8_c.astype(jnp.int32)
+                aux_c = lax.dynamic_slice(pool_aux, (start2,),
+                                          (M,)).astype(jnp.int32)
+                size = size - cnt
             if phaseprof:
                 ph, (vals8_c, vals_c, aux_c, size, valid) = obs_phases.boundary(
                     ph, "pop", vals8_c, vals_c, aux_c, size, valid
@@ -270,19 +275,21 @@ class _ResidentProgram:
                     vals_c, aux_c, valid, best
                 )
                 fits = tree_inc <= S  # survivor-budget overflow counter
-                rows_cast = rows_mk.astype(vals_dt)
-                aux_cast = aux_mk.astype(aux_dt)
-                G_mk = offs_mk.shape[0]
-                Mtn = Mn // G_mk
-                for ti in range(G_mk):
-                    dst = size + offs_mk[ti]
-                    pool_vals = lax.dynamic_update_slice(
-                        pool_vals, rows_cast[ti * Mtn:(ti + 1) * Mtn],
-                        (dst, jnp.int32(0))
-                    )
-                    pool_aux = lax.dynamic_update_slice(
-                        pool_aux, aux_cast[ti * Mtn:(ti + 1) * Mtn], (dst,)
-                    )
+                with jax.named_scope("tts_push"):
+                    rows_cast = rows_mk.astype(vals_dt)
+                    aux_cast = aux_mk.astype(aux_dt)
+                    G_mk = offs_mk.shape[0]
+                    Mtn = Mn // G_mk
+                    for ti in range(G_mk):
+                        dst = size + offs_mk[ti]
+                        pool_vals = lax.dynamic_update_slice(
+                            pool_vals, rows_cast[ti * Mtn:(ti + 1) * Mtn],
+                            (dst, jnp.int32(0))
+                        )
+                        pool_aux = lax.dynamic_update_slice(
+                            pool_aux, aux_cast[ti * Mtn:(ti + 1) * Mtn],
+                            (dst,)
+                        )
                 size = size + tree_inc
                 if phaseprof:
                     ph, (pool_vals, pool_aux, size) = obs_phases.boundary(
@@ -395,7 +402,9 @@ class _ResidentProgram:
                 pool_aux = pool_aux.at[dest].set(caux, mode="drop")
                 return pool_vals, pool_aux
 
-            pool_vals, pool_aux = lax.cond(fits, small, big, pool_vals, pool_aux)
+            with jax.named_scope("tts_push"):
+                pool_vals, pool_aux = lax.cond(fits, small, big, pool_vals,
+                                               pool_aux)
             size = size + tree_inc
             if phaseprof:
                 # The cond ran exactly one branch: charge its time to the
@@ -860,24 +869,31 @@ def resident_search(
     pool = SoAPool(problem.node_fields())
     diagnostics = Diagnostics()
     phases: list[PhaseStats] = []
-    t0 = time.perf_counter()
+    # The search's host steps as profiler spans (docs/OBSERVABILITY.md
+    # leg 2), each carrying the search's number.
+    search = ev.search_seq()
+
+    def span(name: str, **args) -> ev.span:
+        return ev.span(name, args={"search": search, **args})
 
     # -- phase 1: host warm-up (or checkpoint restore) ---------------------
-    if resume_from is not None:
-        saved = ckpt.load(resume_from, problem)
-        pool.push_back_bulk(saved.batch)
-        tree1, sol1 = saved.tree, saved.sol
-        # Keep the tighter incumbent: the resumed run may supply a better one
-        # (e.g. ub=1 after a ub=0 checkpoint).
-        best = min(best, saved.best)
-        # A resumed frontier can exceed the warm-up-sized pool: grow the
-        # capacity so the whole frontier plus one fan-out fits.
-        capacity = max(capacity, pool.size + 2 * M * n)
-    else:
-        pool.push_back(index_batch(problem.root(), 0))
-        target = m if warmup_target is None else warmup_target
-        tree1, sol1, best = warmup(problem, pool, best, target)
-    t1 = time.perf_counter()
+    with span("tts.warmup"):
+        t0 = time.perf_counter()
+        if resume_from is not None:
+            saved = ckpt.load(resume_from, problem)
+            pool.push_back_bulk(saved.batch)
+            tree1, sol1 = saved.tree, saved.sol
+            # Keep the tighter incumbent: the resumed run may supply a
+            # better one (e.g. ub=1 after a ub=0 checkpoint).
+            best = min(best, saved.best)
+            # A resumed frontier can exceed the warm-up-sized pool: grow
+            # the capacity so the whole frontier plus one fan-out fits.
+            capacity = max(capacity, pool.size + 2 * M * n)
+        else:
+            pool.push_back(index_batch(problem.root(), 0))
+            target = m if warmup_target is None else warmup_target
+            tree1, sol1, best = warmup(problem, pool, best, target)
+        t1 = time.perf_counter()
     phases.append(PhaseStats(t1 - t0, tree1, sol1))
     ev.counter("explored", tree=tree1, sol=sol1, phase=1)
 
@@ -899,9 +915,11 @@ def resident_search(
     )
     ctl = AdaptiveK(k_value, target=band) if k_auto else None
     depth = resolve_pipeline_depth()
-    program = _make_program(problem, m, M, ctl.K if ctl else k_value,
-                            capacity, device)
-    state = program.init_state(pool.as_batch(), best)
+    with span("tts.program"):
+        program = _make_program(problem, m, M, ctl.K if ctl else k_value,
+                                capacity, device)
+    with span("tts.upload"):
+        state = program.init_state(pool.as_batch(), best)
     pool.clear()
     diagnostics.host_to_device += 1
     tree2 = 0
@@ -935,9 +953,6 @@ def resident_search(
     qt = obs_quality.tracker(problem)
     n_disp = 0  # completed-dispatch sequence (flight-recorder registry)
     queue = DispatchQueue(depth)
-    # Steady-state XLA capture (`tts profile` / --xla-trace): opens after
-    # the first consumed dispatch (compile excluded), closes with phase 2.
-    xwin = obs_phases.XlaTraceWindow("resident")
 
     def obs_result() -> dict | None:
         parts = {}
@@ -955,7 +970,10 @@ def resident_search(
         # or stalled pool is a zero-cycle no-op (see pipeline.py).
         nonlocal state
         t_enq = ev.now_us()
-        with guard_of(program).step():
+        # The FIFO consumes in enqueue order: this is dispatch number
+        # (consumed + in flight + 1), the number its tts.wait carries.
+        seq = n_disp + len(queue) + 1
+        with span("tts.dispatch", dispatch=seq), guard_of(program).step():
             out = program.step(state)
         state = program.carry(out)
         queue.push(out, t_enq)
@@ -964,8 +982,9 @@ def resident_search(
         nonlocal tree2, sol2, size, best, ctr_total, ph_total, prev_best
         nonlocal n_disp, cycles_total
         t_wait = ev.now_us()
-        tree_inc, sol_inc, cycles, size, best, ctr = \
-            program.read_scalars(out)
+        with span("tts.wait", dispatch=n_disp + 1):
+            tree_inc, sol_inc, cycles, size, best, ctr = \
+                program.read_scalars(out)
         phb = program.read_phase_block(out)
         tree2 += tree_inc
         sol2 += sol_inc
@@ -976,7 +995,6 @@ def resident_search(
             ctr_total = obs_counters.merge_host(ctr_total, ctr)
         if phb is not None:
             ph_total = obs_phases.merge_host(ph_total, phb)
-        xwin.on_dispatch(n_disp)
         fr.heartbeat("resident", seq=n_disp, cycles=cycles, size=size,
                      best=best, tree=tree2, sol=sol2, depth=depth,
                      K=program.K, inflight=len(queue),
@@ -1056,7 +1074,6 @@ def resident_search(
             break
         if controller.after_step(tree1 + tree2, sol1 + sol2):
             drain_queue()  # no-op if the cutoff save already drained
-            xwin.close()
             t2 = time.perf_counter()
             phases.append(PhaseStats(t2 - t1, tree2, sol2))
             ev.emit("checkpoint", args={"cutoff": True})
@@ -1093,7 +1110,9 @@ def resident_search(
             # cached program (same pool state arrays — capacity does not
             # depend on K; at most len(ladder) compiles ever happen).
             drain_queue()
-            program = _make_program(problem, m, M, ctl.K, capacity, device)
+            with span("tts.program"):
+                program = _make_program(problem, m, M, ctl.K, capacity,
+                                        device)
             ev.emit("k_resize", args={"K": program.K})
             last_ready = time.monotonic()
             if size < m:
@@ -1107,7 +1126,8 @@ def resident_search(
             drain_queue()  # stalled speculative dispatches are no-ops too
             t_fb = ev.now_us()
             fb_tree0, fb_sol0 = tree2, sol2
-            batch, size, best = program.residual(state)
+            with span("tts.download"):
+                batch, size, best = program.residual(state)
             diagnostics.device_to_host += 1
             pool.reset_from(batch)
             if offloader is None:
@@ -1131,7 +1151,8 @@ def resident_search(
             diagnostics.host_to_device += offloader.diagnostics.host_to_device
             diagnostics.device_to_host += offloader.diagnostics.device_to_host
             offloader.diagnostics = Diagnostics()
-            state = program.init_state(pool.as_batch(), best)
+            with span("tts.upload"):
+                state = program.init_state(pool.as_batch(), best)
             pool.clear()
             diagnostics.host_to_device += 1
             # The re-upload is a sanctioned host round trip; the next
@@ -1143,19 +1164,18 @@ def resident_search(
             ev.complete("overflow_fallback", t_fb, args={
                 "tree": tree2 - fb_tree0, "sol": sol2 - fb_sol0,
             })
-    xwin.close()
-    batch, size, best = program.residual(state)
+    with span("tts.download"):
+        batch, size, best = program.residual(state)
     diagnostics.device_to_host += 1
     pool.reset_from(batch)
-    t2 = time.perf_counter()
-    phases.append(PhaseStats(t2 - t1, tree2, sol2))
-    _emit_device_explored(ctr_total, tree2, sol2, fb_tree, fb_sol)
 
     # -- phase 3: host drain ----------------------------------------------
-    from .device import drain
-
-    tree3, sol3, best = drain(problem, pool, best)
-    t3 = time.perf_counter()
+    with span("tts.drain"):
+        t2 = time.perf_counter()
+        tree3, sol3, best = drain(problem, pool, best)
+        t3 = time.perf_counter()
+    phases.append(PhaseStats(t2 - t1, tree2, sol2))
+    _emit_device_explored(ctr_total, tree2, sol2, fb_tree, fb_sol)
     phases.append(PhaseStats(t3 - t2, tree3, sol3))
     ev.counter("explored", tree=tree3, sol=sol3, phase=3)
     if qt is not None:

@@ -26,9 +26,8 @@ Closed-loop legs (same doc):
   * ``phases`` — on-device per-phase cycle clocks (``TTS_PHASEPROF=1`` /
     ``tts profile``): a barrier-fenced clock block in the resident loop
     carry decomposing the chunk cycle into pop/eval/compact/push/
-    overflow (+ mesh balance), plus the steady-state XLA trace window
-    (``TTS_XLA_TRACE``). A separate cache-keyed program variant — never
-    the headline program.
+    overflow (+ mesh balance). A separate cache-keyed program variant —
+    never the headline program.
 
 Knobs: ``TTS_OBS=1`` (everything), ``TTS_OBS=host`` (host events only —
 device programs untouched), off by default with zero hot-loop cost.

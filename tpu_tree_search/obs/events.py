@@ -18,9 +18,11 @@ cross-thread ordering requirement — events carry monotonic timestamps
 (``time.perf_counter_ns``) and the merge sorts.
 
 Cost model: every emit is gated on ``enabled()`` — one global read — so the
-disabled path is a few nanoseconds per call site. Call sites are host-side
-control points (per dispatch / steal / round), never per node or per cycle;
-the on-device hot loop is covered by ``counters`` instead.
+disabled path is a few nanoseconds per call site; a ``span`` also enters a
+profiler annotation, under a microsecond when no profiler session is
+active. Call sites are host-side control points (per dispatch / steal /
+round), never per node or per cycle; the on-device hot loop is covered by
+``counters`` instead.
 
 Event shape (Chrome-trace-event aligned, so export is a dump not a
 translation): ``ph`` is the Chrome phase — ``"i"`` instant, ``"X"`` complete
@@ -30,7 +32,9 @@ is the host id, ``tid`` the worker/communicator track.
 
 from __future__ import annotations
 
+import itertools
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -206,8 +210,15 @@ def counter(name: str, wid: int = 0, host: int = 0, **values) -> None:
 
 
 class span:
-    """``with span("steal", wid=3):`` — emits one complete event covering
-    the block. Usable when tracing is off (no-op)."""
+    """``with span("tts.wait", args={"search": 3}):`` — the one scoped span.
+
+    It always enters a ``jax.profiler.TraceAnnotation`` of the same name,
+    with ``args`` as the annotation's stats, so the span lands in a
+    profiler trace on the device trace's clock (nearly free when no
+    profiler session is active). When ``TTS_OBS`` is on it also records a
+    Chrome ``"X"`` event covering the block. The annotation is taken only
+    where JAX is already loaded: host-only modules (``fleet/``) that use
+    spans never import it."""
 
     def __init__(self, name: str, cat: str = "tts", wid: int = 0,
                  host: int = 0, args: dict | None = None):
@@ -217,12 +228,58 @@ class span:
         self.host = host
         self.args = args
         self._t0 = 0.0
+        self._ann = None
 
     def __enter__(self):
+        profiler = sys.modules.get("jax.profiler")
+        if profiler is not None:
+            self._ann = profiler.TraceAnnotation(self.name,
+                                                 **(self.args or {}))
+            self._ann.__enter__()
         self._t0 = now_us()
         return self
 
     def __exit__(self, *exc):
         complete(self.name, self._t0, cat=self.cat, wid=self.wid,
                  host=self.host, args=self.args)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+            self._ann = None
         return False
+
+
+# -- search sequence numbers ------------------------------------------------
+# Every span of one search carries the search's number, so a trace of
+# back-to-back searches on one thread tells them apart.
+
+_SEARCHES = itertools.count(1)
+
+
+def search_seq() -> int:
+    """The number of the search this thread runs: the one an enclosing
+    ``search_span`` bound, else a fresh one."""
+    seq = getattr(_JOB_CTX, "search", None)
+    return seq if seq is not None else next(_SEARCHES)
+
+
+class search_span(span):
+    """``with search_span("tts.search"):`` — the span of one whole search.
+    Binds a fresh search number to this thread for the spans inside it
+    (an enclosing search span's number wins) and carries it as
+    ``search``."""
+
+    def __init__(self, name: str):
+        super().__init__(name)
+        self._prev = None
+
+    def __enter__(self):
+        self._prev = getattr(_JOB_CTX, "search", None)
+        _JOB_CTX.search = search_seq()
+        self.args = {"search": _JOB_CTX.search}
+        return super().__enter__()
+
+    def __exit__(self, *exc):
+        try:
+            return super().__exit__(*exc)
+        finally:
+            _JOB_CTX.search = self._prev

@@ -57,7 +57,6 @@ from __future__ import annotations
 
 import functools
 import os
-import threading
 import time
 
 import numpy as np
@@ -218,82 +217,6 @@ def decomp(totals: dict) -> dict:
         "dominant": dom[0] if dom else None,
         "dominant_share": round(dom[1], 4) if dom else None,
     }
-
-
-# -- XLA profiler capture (`tts profile` / --xla-trace) ----------------------
-
-#: Dispatch boundaries to skip before starting the XLA trace: the first
-#: dispatch carries the while-loop compile, the second may still hit
-#: autotuning caches — the window opens at steady state.
-TRACE_SKIP_DISPATCHES = 1
-
-
-def xla_trace_dir() -> str | None:
-    """``TTS_XLA_TRACE=<dir>`` — arm a steady-state XLA profiler capture
-    around the dispatch window (CLI: ``--xla-trace DIR``)."""
-    return os.environ.get("TTS_XLA_TRACE") or None
-
-
-class XlaTraceWindow:
-    """Steady-state ``jax.profiler.start_trace``/``stop_trace`` bracket.
-
-    The engines call ``on_dispatch(seq)`` once per consumed dispatch and
-    ``close()`` when phase 2 ends: the trace opens after
-    ``TRACE_SKIP_DISPATCHES`` completed dispatches (warmup + while-loop
-    compile excluded) and closes before the residual download — so the
-    capture is the steady-state dispatch window, not the session.  The
-    jax profiler is process-global: only one window can be active (the
-    dist_mesh virtual-host threads share one; extras are no-ops).
-    """
-
-    _active_lock = threading.Lock()
-    _active: "XlaTraceWindow | None" = None
-
-    def __init__(self, tier: str, out_dir: str | None = None):
-        self.tier = tier
-        self.dir = out_dir if out_dir is not None else xla_trace_dir()
-        self.started = False
-        self._owner = False
-        if self.dir:
-            with XlaTraceWindow._active_lock:
-                if XlaTraceWindow._active is None:
-                    XlaTraceWindow._active = self
-                    self._owner = True
-
-    def on_dispatch(self, seq: int) -> None:
-        if (not self._owner or self.started
-                or seq < TRACE_SKIP_DISPATCHES + 1):
-            return
-        import jax
-
-        try:
-            os.makedirs(self.dir, exist_ok=True)
-            jax.profiler.start_trace(self.dir)
-            self.started = True
-            from . import events as ev
-
-            ev.emit("xla_trace", args={"dir": self.dir, "tier": self.tier,
-                                       "after_dispatch": seq - 1})
-        except Exception:  # noqa: BLE001 — capture must never fail a run
-            self._release()
-
-    def close(self) -> None:
-        if self.started:
-            import jax
-
-            try:
-                jax.profiler.stop_trace()
-            except Exception:  # noqa: BLE001 — see on_dispatch
-                pass
-            self.started = False
-        self._release()
-
-    def _release(self) -> None:
-        if self._owner:
-            with XlaTraceWindow._active_lock:
-                if XlaTraceWindow._active is self:
-                    XlaTraceWindow._active = None
-            self._owner = False
 
 
 # -- compiled-program contracts (`tts check`, analysis/contracts.py) --------

@@ -423,6 +423,12 @@ def resolve(problem, M: int, device=None, mp_axis: str | None = None,
 # in-kernel cycle epilogue: prune -> rank -> shift-compact -> emit
 # ---------------------------------------------------------------------------
 
+#: Trace regions inside the cycle kernels (``jax.named_scope`` lowers to
+#: Mosaic ``trace_start``/``trace_stop``): the tile bound, and the prune +
+#: compaction + emit epilogue (docs/OBSERVABILITY.md leg 7).
+BOUND_SCOPE = "tts_bound"
+COMPACT_SCOPE = "tts_compact"
+
 
 def _scalar_lanes(tree_inc, sol_inc, best):
     """(1, 128) int32 scalar output row: lanes 0/1/2 = tree_inc / sol_inc /
@@ -570,11 +576,14 @@ def _mega_nqueens_kernel(board_ref, depth_ref, valid_ref, best_ref,
     depth = depth_ref[:, 0].astype(jnp.int32)  # (M,)
     valid = valid_ref[:, 0] != 0
     best = best_ref[0]
-    labels = PK._nqueens_tile_labels(board, depth, N=N, g=g)
+    with jax.named_scope(BOUND_SCOPE):
+        labels = PK._nqueens_tile_labels(board, depth, N=N, g=g)
     # The `_NQueensResident` evaluate fold: swap position is the depth.
     keep = labels & valid[:, None] & (depth < N)[:, None]
     sol_inc = jnp.sum(valid & (depth == N), dtype=jnp.int32)
-    rows, caux, tree_inc = _compact_push(board, depth, depth, keep, n=N, M=M)
+    with jax.named_scope(COMPACT_SCOPE):
+        rows, caux, tree_inc = _compact_push(board, depth, depth, keep,
+                                             n=N, M=M)
     out_vals_ref[:] = rows
     out_aux_ref[:] = caux
     scal_ref[:] = _scalar_lanes(tree_inc, sol_inc, best)
@@ -589,10 +598,12 @@ def _mega_lb1_kernel(prmu_ref, limit1_ref, valid_ref, best_ref,
     valid = valid_ref[:, 0] != 0
     best = best_ref[0]
     ptm = ptm_ref[:].astype(jnp.float32)
-    lb = PK._lb1_tile_lb(prmu, limit1, ptm, heads_ref[:], tails_ref[:],
-                         scan_ref, n=n, m=m, bf16=bf16)
-    rows, caux, tree_inc, sol_inc, best = _pfsp_epilogue(
-        prmu, limit1, valid, best, lb, n=n, M=M)
+    with jax.named_scope(BOUND_SCOPE):
+        lb = PK._lb1_tile_lb(prmu, limit1, ptm, heads_ref[:], tails_ref[:],
+                             scan_ref, n=n, m=m, bf16=bf16)
+    with jax.named_scope(COMPACT_SCOPE):
+        rows, caux, tree_inc, sol_inc, best = _pfsp_epilogue(
+            prmu, limit1, valid, best, lb, n=n, M=M)
     out_vals_ref[:] = rows
     out_aux_ref[:] = caux
     scal_ref[:] = _scalar_lanes(tree_inc, sol_inc, best)
@@ -609,13 +620,15 @@ def _mega_lb2_kernel(prmu_ref, limit1_ref, valid_ref, best_ref,
     valid = valid_ref[:, 0] != 0
     best = best_ref[0]
     ptm = ptm_ref[:].astype(jnp.float32)
-    lb = PK._lb2_tile_lb(
-        prmu, limit1, ptm, heads_ref[:],
-        p0_ref, p1_ref, lag_ref, t0_ref, t1_ref, msel0_ref, msel1_ref,
-        jorder_ref, scan_ref, n=n, m=m, P=P, pg=pg, bf16=bf16,
-    ).astype(jnp.int32)
-    rows, caux, tree_inc, sol_inc, best = _pfsp_epilogue(
-        prmu, limit1, valid, best, lb, n=n, M=M)
+    with jax.named_scope(BOUND_SCOPE):
+        lb = PK._lb2_tile_lb(
+            prmu, limit1, ptm, heads_ref[:],
+            p0_ref, p1_ref, lag_ref, t0_ref, t1_ref, msel0_ref, msel1_ref,
+            jorder_ref, scan_ref, n=n, m=m, P=P, pg=pg, bf16=bf16,
+        ).astype(jnp.int32)
+    with jax.named_scope(COMPACT_SCOPE):
+        rows, caux, tree_inc, sol_inc, best = _pfsp_epilogue(
+            prmu, limit1, valid, best, lb, n=n, M=M)
     out_vals_ref[:] = rows
     out_aux_ref[:] = caux
     scal_ref[:] = _scalar_lanes(tree_inc, sol_inc, best)
@@ -674,10 +687,13 @@ def _mega_nqueens_tiled_kernel(board_ref, depth_ref, valid_ref, best_ref,
     depth = depth_ref[:, 0].astype(jnp.int32)
     valid = valid_ref[:, 0] != 0
     best = best_ref[0]
-    labels = PK._nqueens_tile_labels(board, depth, N=N, g=g)
+    with jax.named_scope(BOUND_SCOPE):
+        labels = PK._nqueens_tile_labels(board, depth, N=N, g=g)
     keep = labels & valid[:, None] & (depth < N)[:, None]
     sol_inc = jnp.sum(valid & (depth == N), dtype=jnp.int32)
-    rows, caux, cnt = _compact_push(board, depth, depth, keep, n=N, M=Mt)
+    with jax.named_scope(COMPACT_SCOPE):
+        rows, caux, cnt = _compact_push(board, depth, depth, keep, n=N,
+                                        M=Mt)
     offs = carry_ref[0]
     sol_cum = carry_ref[1] + sol_inc
     out_vals_ref[:] = rows
@@ -711,9 +727,11 @@ def _mega_lb1_tiled_kernel(prmu_ref, limit1_ref, valid_ref, best_ref,
         # Phase 0: evaluate this tile's bounds into the stash and fold its
         # leaf-min into the global incumbent — no tile may prune before
         # every tile's leaves have been folded (PFSP exactness rule).
-        lb = PK._lb1_tile_lb(prmu, limit1, ptm_ref[:].astype(jnp.float32),
-                             heads_ref[:], tails_ref[:], scan_ref,
-                             n=n, m=m, bf16=bf16)
+        with jax.named_scope(BOUND_SCOPE):
+            lb = PK._lb1_tile_lb(prmu, limit1,
+                                 ptm_ref[:].astype(jnp.float32),
+                                 heads_ref[:], tails_ref[:], scan_ref,
+                                 n=n, m=m, bf16=bf16)
         lb_ref[i] = lb
         carry_ref[2] = jnp.minimum(
             carry_ref[2], _pfsp_leaf_min(limit1, valid, lb, n=n, M=Mt))
@@ -723,8 +741,9 @@ def _mega_lb1_tiled_kernel(prmu_ref, limit1_ref, valid_ref, best_ref,
         # Phase 1: re-stream the tile (bounds come back from the stash,
         # not recomputed) and prune/compact against the final incumbent.
         lb = lb_ref[i]
-        rows, caux, cnt, sol_inc, best = _pfsp_epilogue(
-            prmu, limit1, valid, carry_ref[2], lb, n=n, M=Mt)
+        with jax.named_scope(COMPACT_SCOPE):
+            rows, caux, cnt, sol_inc, best = _pfsp_epilogue(
+                prmu, limit1, valid, carry_ref[2], lb, n=n, M=Mt)
         offs = carry_ref[0]
         sol_cum = carry_ref[1] + sol_inc
         out_vals_ref[:] = rows
@@ -758,11 +777,13 @@ def _mega_lb2_tiled_kernel(prmu_ref, limit1_ref, valid_ref, best_ref,
 
     @pl.when(p == 0)
     def _sweep():
-        lb = PK._lb2_tile_lb(
-            prmu, limit1, ptm_ref[:].astype(jnp.float32), heads_ref[:],
-            p0_ref, p1_ref, lag_ref, t0_ref, t1_ref, msel0_ref, msel1_ref,
-            jorder_ref, scan_ref, n=n, m=m, P=P, pg=pg, bf16=bf16,
-        ).astype(jnp.int32)
+        with jax.named_scope(BOUND_SCOPE):
+            lb = PK._lb2_tile_lb(
+                prmu, limit1, ptm_ref[:].astype(jnp.float32), heads_ref[:],
+                p0_ref, p1_ref, lag_ref, t0_ref, t1_ref, msel0_ref,
+                msel1_ref, jorder_ref, scan_ref, n=n, m=m, P=P, pg=pg,
+                bf16=bf16,
+            ).astype(jnp.int32)
         lb_ref[i] = lb
         carry_ref[2] = jnp.minimum(
             carry_ref[2], _pfsp_leaf_min(limit1, valid, lb, n=n, M=Mt))
@@ -770,8 +791,9 @@ def _mega_lb2_tiled_kernel(prmu_ref, limit1_ref, valid_ref, best_ref,
     @pl.when(p == 1)
     def _emit():
         lb = lb_ref[i]
-        rows, caux, cnt, sol_inc, best = _pfsp_epilogue(
-            prmu, limit1, valid, carry_ref[2], lb, n=n, M=Mt)
+        with jax.named_scope(COMPACT_SCOPE):
+            rows, caux, cnt, sol_inc, best = _pfsp_epilogue(
+                prmu, limit1, valid, carry_ref[2], lb, n=n, M=Mt)
         offs = carry_ref[0]
         sol_cum = carry_ref[1] + sol_inc
         out_vals_ref[:] = rows
@@ -873,6 +895,7 @@ def _nqueens_cycle_call(N: int, g: int, M: int, interpret: bool,
     return pl.pallas_call(
         partial(_mega_nqueens_kernel, N=N, g=g, M=M),
         out_shape=shapes,
+        name="tts_cycle_nqueens",
         grid=(1,),
         in_specs=_chunk_specs(M, N, backend),
         out_specs=out_specs,
@@ -887,6 +910,7 @@ def _nqueens_tiled_call(N: int, g: int, M: int, mt: int, interpret: bool):
     return pl.pallas_call(
         partial(_mega_nqueens_tiled_kernel, N=N, g=g, Mt=mt),
         out_shape=shapes,
+        name="tts_cycle_nqueens",
         grid=(M // mt,),
         in_specs=_tiled_chunk_specs(mt, N, two_phase=False),
         out_specs=out_specs,
@@ -905,6 +929,7 @@ def _lb1_cycle_call(n: int, m: int, M: int, bf16: bool, interpret: bool,
     return pl.pallas_call(
         partial(kernel, n=n, m=m, M=M, bf16=bf16),
         out_shape=shapes,
+        name="tts_cycle_lb1",
         grid=(1,),
         in_specs=_chunk_specs(M, n, backend) + [
             PK._bs((n, m), full, backend=backend),
@@ -927,6 +952,7 @@ def _lb1_tiled_call(n: int, m: int, M: int, mt: int, bf16: bool,
     return pl.pallas_call(
         partial(_mega_lb1_tiled_kernel, n=n, m=m, Mt=mt, bf16=bf16),
         out_shape=shapes,
+        name="tts_cycle_lb1",
         grid=(2, G),
         in_specs=_tiled_chunk_specs(mt, n, two_phase=True) + [
             pl.BlockSpec((n, m), full, memory_space=pltpu.VMEM),
@@ -953,6 +979,7 @@ def _lb2_cycle_call(n: int, m: int, P: int, M: int, pg: int, bf16: bool,
     return pl.pallas_call(
         partial(kernel, n=n, m=m, P=P, M=M, pg=pg, bf16=bf16),
         out_shape=shapes,
+        name="tts_cycle_lb2",
         grid=(1,),
         in_specs=_chunk_specs(M, n, backend) + [
             bs((n, m), full),
@@ -987,6 +1014,7 @@ def _lb2_tiled_call(n: int, m: int, P: int, M: int, mt: int, pg: int,
         partial(_mega_lb2_tiled_kernel, n=n, m=m, P=P, Mt=mt, pg=pg,
                 bf16=bf16),
         out_shape=shapes,
+        name="tts_cycle_lb2",
         grid=(2, G),
         in_specs=_tiled_chunk_specs(mt, n, two_phase=True) + [
             pl.BlockSpec((n, m), full, memory_space=pltpu.VMEM),
@@ -1179,6 +1207,7 @@ def _eval_nqueens_call(N: int, g: int, B: int, mt: int, interpret: bool,
     return pl.pallas_call(
         partial(_eval_nqueens_kernel, N=N, g=g),
         out_shape=jax.ShapeDtypeStruct((B, N), jnp.int32),
+        name="tts_eval_nqueens",
         grid=(B // mt,),
         in_specs=[bs((mt, N), tm), bs((mt, 1), tm)],
         out_specs=bs((mt, N), tm),
@@ -1197,6 +1226,7 @@ def _eval_lb1_call(n: int, m: int, B: int, mt: int, bf16: bool,
     return pl.pallas_call(
         partial(kernel, n=n, m=m, bf16=bf16),
         out_shape=jax.ShapeDtypeStruct((B, n), jnp.int32),
+        name="tts_eval_lb1",
         grid=(B // mt,),
         in_specs=[
             bs((mt, n), tm),
@@ -1225,6 +1255,7 @@ def _eval_lb2_call(n: int, m: int, P: int, B: int, mt: int, pg: int,
     return pl.pallas_call(
         partial(kernel, n=n, m=m, P=P, pg=pg, bf16=bf16),
         out_shape=jax.ShapeDtypeStruct((B, n), jnp.int32),
+        name="tts_eval_lb2",
         grid=(B // mt,),
         in_specs=[
             bs((mt, n), tm),

@@ -379,6 +379,7 @@ def _nqueens_call(N: int, g: int, B: int, tile: int, interpret: bool,
     return pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct((B, N), jnp.uint8),
+        name="tts_eval_nqueens",
         grid=grid,
         in_specs=[
             _bs((tile, N), lambda i: (i, 0), backend=backend),
@@ -559,7 +560,7 @@ def _lb1_kernel(
 @lru_cache(maxsize=None)
 def _lb1_family_call(kernel_fn, n: int, m: int, B: int, tile: int,
                      interpret: bool, bf16: bool = False,
-                     backend: str = "tpu"):
+                     backend: str = "tpu", name: str = "tts_eval_lb1"):
     """Shared pallas_call factory for the lb1-shaped kernels (lb1 / lb1_d):
     same operand layout — (prmu, limit1, ptm, heads, tails) -> (B, n) —
     same tiling, same scan scratch (TPU; the gpu flavor passes a
@@ -569,6 +570,7 @@ def _lb1_family_call(kernel_fn, n: int, m: int, B: int, tile: int,
     return pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct((B, n), jnp.int32),
+        name=name,
         grid=grid,
         in_specs=[
             _bs((tile, n), lambda i: (i, 0), backend=backend),
@@ -602,8 +604,9 @@ def _lb1_family_bounds(
     if Bp != B:
         prmu = jnp.pad(prmu, ((0, Bp - B), (0, 0)))
         limit1 = jnp.pad(limit1, ((0, Bp - B),))
+    name = "tts_eval_lb1_d" if kernel_name == "lb1d" else "tts_eval_lb1"
     out = _lb1_family_call(kernel_fn, n, m, Bp, tile, interpret, bf16,
-                           backend)(
+                           backend, name)(
         prmu.astype(jnp.int32),
         limit1.astype(jnp.int32)[:, None],
         ptm_t.astype(jnp.int32),
@@ -809,6 +812,7 @@ def _lb2_call(n: int, m: int, P: int, B: int, tile: int, interpret: bool,
     return pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct((B, n), jnp.int32),
+        name="tts_eval_lb2",
         grid=grid,
         in_specs=[
             bs((tile, n), lambda i: (i, 0)),
@@ -1018,6 +1022,7 @@ def _lb2_self_call(n: int, m: int, P: int, R: int, tile: int, interpret: bool,
     return pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct((R, 1), jnp.int32),
+        name="tts_eval_lb2_self",
         grid=grid,
         in_specs=[
             bs((tile, n), lambda i: (i, 0)),

@@ -228,9 +228,6 @@ def _host_loop(
     sizes = np.zeros(D, dtype=np.int32)
     n_disp = 0  # completed-dispatch sequence (flight-recorder registry)
     queue = DispatchQueue(depth)
-    # Steady-state XLA capture: the jax profiler is process-global, so
-    # only one virtual host's window arms (XlaTraceWindow's active guard).
-    xwin = obs_phases.XlaTraceWindow("dist_mesh")
     last_ready = time.monotonic()
 
     def enqueue() -> None:
@@ -256,7 +253,6 @@ def _host_loop(
             ctr_total = obs_counters.merge_host(ctr_total, ctr)
         if phb is not None:
             ph_total = obs_phases.merge_host(ph_total, phb)
-        xwin.on_dispatch(n_disp)
         fr.heartbeat("dist_mesh", host=me, seq=n_disp, cycles=cy,
                      size=int(sizes.sum()), best=int(best), tree=tree2,
                      sol=sol2, depth=depth, K=program.K,
@@ -518,7 +514,6 @@ def _host_loop(
 
     # -- phase 3: local residual drain --------------------------------------
     drain_queue()  # remaining speculative dispatches are no-ops by now
-    xwin.close()
     batch = program.residual_batch(state)
     diagnostics.device_to_host += 1
     pool.reset_from(batch)

@@ -639,7 +639,6 @@ def mesh_resident_search(
     qt = obs_quality.tracker(problem)
     n_disp = 0  # completed-dispatch sequence (flight-recorder registry)
     queue = DispatchQueue(depth)
-    xwin = obs_phases.XlaTraceWindow("mesh")
 
     def obs_result() -> dict | None:
         parts = {}
@@ -672,7 +671,6 @@ def mesh_resident_search(
             ctr_total = obs_counters.merge_host(ctr_total, ctr)
         if phb is not None:
             ph_total = obs_phases.merge_host(ph_total, phb)
-        xwin.on_dispatch(n_disp)
         fr.heartbeat("mesh", seq=n_disp, cycles=cy, size=int(sizes.sum()),
                      best=best, tree=tree2, sol=sol2, depth=depth,
                      K=program.K, inflight=len(queue),
@@ -739,7 +737,6 @@ def mesh_resident_search(
             break
         if controller.after_step(tree1 + tree2, sol1 + sol2):
             drain_queue()  # no-op if the cutoff save already drained
-            xwin.close()
             t2 = time.perf_counter()
             phases.append(PhaseStats(t2 - t1, tree2, sol2))
             ev.emit("checkpoint", args={"cutoff": True})
@@ -827,7 +824,6 @@ def mesh_resident_search(
             prev_sizes = None
             continue
         prev_sizes = sizes
-    xwin.close()
     batch = program.residual_batch(state)
     diagnostics.device_to_host += 1
     pool.reset_from(batch)

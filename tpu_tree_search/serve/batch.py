@@ -412,12 +412,12 @@ class BatchExecutor:
             job.quality.observe(best, sl.n_disp, sl.tree)
         res = self._result(sl, best, True, prog)
         self._release_slot(i, ctx, job, problem)
-        sched.registry.transition(job, "done", result=result_record(res))
-        ckpt = sched._checkpoint_path(job)
-        for p in (ckpt, job.checkpoint):
+        stale = (sched._checkpoint_path(job), job.checkpoint)
+        sched.registry.transition(job, "done", result=result_record(res),
+                                  checkpoint=None)
+        for p in stale:
             if p and os.path.exists(p):
                 os.remove(p)
-        sched.registry.update(job, checkpoint=None)
         # The retired carry stays in states[i] as frozen ballast
         # (size < m fails its cond) until the next splice replaces it.
 
@@ -431,12 +431,12 @@ class BatchExecutor:
         job = sl.job
         res = self._result(sl, best, False, prog)
         self._release_slot(i, ctx, job, problem)
-        sched.registry.transition(job, "done", result=result_record(res))
-        ckpt = sched._checkpoint_path(job)
-        for p in (ckpt, job.checkpoint):
+        stale = (sched._checkpoint_path(job), job.checkpoint)
+        sched.registry.transition(job, "done", result=result_record(res),
+                                  checkpoint=None)
+        for p in stale:
             if p and os.path.exists(p):
                 os.remove(p)
-        sched.registry.update(job, checkpoint=None)
         ctx["states"][i] = prog.empty_slot()  # still live: must freeze
 
     def _cut(self, i: int, ctx, best: int, kind: str) -> None:

@@ -437,11 +437,14 @@ class Scheduler:
             # always leaves the budget unexhausted (the max_steps cutoff
             # wins the same dispatch boundary), so it can never be
             # mistaken for the cutoff and silently truncate a result.
-            self.registry.transition(job, "done", result=result_record(res))
-            for p in (ckpt, job.checkpoint):
+            # The record turns done with its checkpoint already consumed:
+            # a reader never sees a finished job that still names one.
+            stale = (ckpt, job.checkpoint)
+            self.registry.transition(job, "done", result=result_record(res),
+                                     checkpoint=None)
+            for p in stale:
                 if p and os.path.exists(p):
                     os.remove(p)
-            self.registry.update(job, checkpoint=None)
             return
         has_ckpt = os.path.exists(ckpt)
         if job.cancel_requested:
