@@ -181,6 +181,37 @@ def test_tamper_pair_axis_serialized(monkeypatch):
                for f in findings)
 
 
+def test_tamper_engine_stitch_behind_the_pushing_cycle(monkeypatch):
+    """Write a pool from XLA again behind the armed TPU cycle: the push
+    contract must name the dynamic_update_slice; the gpu flavor's cell,
+    which stitches by design, stays green."""
+    import jax
+
+    from tpu_tree_search.ops import megakernel as MK
+
+    real = MK.make_cycle
+
+    def tampered(*a, **kw):
+        cycle = real(*a, **kw)
+
+        def stitched(pool_vals, pool_aux, size, *rest):
+            out = cycle(pool_vals, pool_aux, size, *rest)
+            pv = jax.lax.dynamic_update_slice(out[0], out[0][:8], (size, 0))
+            return (pv,) + tuple(out[1:])
+
+        return stitched
+
+    monkeypatch.setattr(MK, "make_cycle", tampered)
+    cell = program_audit.Cell("nqueens", megakernel="force", mt="16")
+    art = program_audit.trace_cell(cell)
+    msgs = contracts.run_one("megakernel-push-in-kernel", art, cell)
+    assert msgs and "dynamic_update_slice" in msgs[0], msgs
+    monkeypatch.setattr(MK, "make_cycle", real)
+    gpu = program_audit.Cell("nqueens", megakernel="force", backend="gpu")
+    art = program_audit.trace_cell(gpu)
+    assert contracts.run_one("megakernel-push-in-kernel", art, gpu) == []
+
+
 def test_tamper_fingerprint_drift():
     """An op histogram differing from the committed baseline must fail
     with the named cell and a per-op diff."""

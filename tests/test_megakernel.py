@@ -23,6 +23,7 @@ from tpu_tree_search.engine.sequential import sequential_search
 from tpu_tree_search.ops import megakernel as MK
 from tpu_tree_search.ops import pfsp_device as PD
 from tpu_tree_search.problems import NQueensProblem, PFSPProblem
+from tpu_tree_search.problems.base import INF_BOUND
 
 
 def _ptm(seed: int, jobs: int = 7, machines: int = 5) -> np.ndarray:
@@ -357,6 +358,178 @@ def test_mt_knob_flip_rebuilds_and_reset_hits_cache(monkeypatch):
     monkeypatch.delenv("TTS_MEGAKERNEL_MT", raising=False)
     c = _make_program(prob, 5, M, 4, capacity, dev)
     assert c is a  # cache hit — the unset-knob build really is the same
+
+
+# -- the in-kernel push: pool layout and pool rows after every dispatch ----
+
+@pytest.mark.parametrize("n,slot", [(4, (8, 16)), (8, (8, 16)),
+                                    (15, (16, 8)), (20, (32, 4)),
+                                    (64, (64, 2)), (65, (128, 1)),
+                                    (130, (256, 1))])
+def test_push_pack_slots(n, slot):
+    """A node takes a power-of-two slot of at least 8 lanes, as many to a
+    128-lane pool row as fit; past 64 values it takes whole rows."""
+    assert MK.push_pack(n) == slot
+
+
+def test_capacity_budget_charges_the_stored_value_row():
+    """The default pool capacity's ~1 GiB budget charges a value row at
+    the slot width the TPU cycle's pool stores it in (ta111: 500 int16
+    jobs in a 512-lane slot, plus two int16 scalars), not at n."""
+    from tpu_tree_search.engine.resident import resolve_capacity
+
+    prob = PFSPProblem(inst=111, lb="lb1", ub=1)
+    capacity, _ = resolve_capacity(prob, 1024, None)
+    assert capacity == (1 << 30) // (512 * 2 + 4 + 2 * (2 + 4))
+
+
+def _warm_frontier(prob, target: int, copies: int = 1):
+    """A host warm-up frontier of about ``target`` nodes, or ``copies``
+    copies of the root when ``target`` is 0."""
+    from tpu_tree_search.engine.device import warmup
+    from tpu_tree_search.pool import SoAPool
+
+    if not target:
+        return {k: np.repeat(v, copies, axis=0)
+                for k, v in prob.root().items()}
+    pool = SoAPool(prob.node_fields())
+    pool.push_back_bulk(prob.root())
+    warmup(prob, pool, INF_BOUND, target)
+    return pool.as_batch()
+
+
+def _trail(mk, env, monkeypatch, *, frontier, M: int = 64,
+           steps: int = 6, poison: int | None = None):
+    """Dispatch a resident program one cycle at a time (K=1) from
+    ``frontier`` and record, after each dispatch, the counts and the pool
+    rows [0, size) as nodes.  ``poison`` first fills every pool row at and
+    past ``size`` with that value."""
+    from tpu_tree_search.engine.resident import (_make_program, node_rows,
+                                                 resolve_capacity)
+
+    for k in ("TTS_MEGAKERNEL", "TTS_MEGAKERNEL_MT"):
+        monkeypatch.delenv(k, raising=False)
+    for k, v in env.items():
+        monkeypatch.setenv(k, v)
+    prob = mk()
+    n = prob.child_slots
+    capacity, M = resolve_capacity(prob, M, 8192)
+    prog = _make_program(prob, 1, M, 1, capacity, jax.devices()[0])
+    state = prog.init_state(frontier(prob), INF_BOUND)
+    W = prog.row_width
+    if poison is not None:
+        size = int(state[2])
+        vals = np.array(state[0])
+        vals.reshape(-1, W)[size:] = poison  # every node slot past size
+        state = (jnp.asarray(vals),
+                 state[1].at[size:].set(poison)) + tuple(state[2:])
+    trail = []
+    for _ in range(steps):
+        out = prog.step(state)
+        tree, sol, cycles, size, best, _ = prog.read_scalars(out)
+        state = prog.carry(out)
+        vals = node_rows(np.asarray(state[0]), (n,), W)[:size]
+        aux = np.asarray(state[1])[:size]
+        trail.append(((tree, sol, best, size), vals, aux))
+        if cycles == 0:
+            break
+    return prog, trail
+
+
+def _assert_same_trail(on, off):
+    assert len(on) == len(off)
+    for (c_on, v_on, a_on), (c_off, v_off, a_off) in zip(on, off):
+        assert c_on == c_off
+        np.testing.assert_array_equal(v_on, v_off)
+        np.testing.assert_array_equal(a_on, a_off)
+
+
+@pytest.mark.parametrize("narrow", ["auto", "0"])
+@pytest.mark.parametrize("mt", [None, "16"])
+@pytest.mark.parametrize("family", ["nqueens", "pfsp-lb1", "pfsp-lb2"])
+def test_push_pool_rows_match_off_every_dispatch(family, mt, narrow,
+                                                 monkeypatch):
+    """The kernel's push lands the pool rows [0, size) — values and aux —
+    and tree/sol/best bit-identical to the fused-jnp resident after every
+    dispatch, single-tile and on a G=4 streamed grid, with narrow host
+    storage on and off."""
+    monkeypatch.setenv("TTS_NARROW", narrow)
+    mk = _mk_problem(family)
+    frontier = lambda prob: _warm_frontier(prob, 70)  # noqa: E731
+    _, off = _trail(mk, {"TTS_MEGAKERNEL": "0"}, monkeypatch,
+                    frontier=frontier)
+    env = {"TTS_MEGAKERNEL": "force"}
+    if mt:
+        env["TTS_MEGAKERNEL_MT"] = mt
+    prog, on = _trail(mk, env, monkeypatch, frontier=frontier)
+    assert prog.megakernel.enabled and prog.megakernel.tiled == bool(mt)
+    assert prog.row_pack > 1  # nodes share a pool row
+    _assert_same_trail(on, off)
+
+
+#: (frontier nodes, root copies, tile width, what the first cycle holds)
+_PUSH_EDGES = {
+    # One parent in a G=8 grid: every tile but the first pushes nothing.
+    "zero-survivor-tiles": (0, 1, "8"),
+    # 64 depth-0 parents: each of the four tiles fills all Mt*n = 128 rows.
+    "full-tiles": (0, 64, "16"),
+    # 67 roots: the pop leaves size 3, so tile 0's 256 survivors start 3
+    # rows into a row tile and cross two chunk boundaries mid-tile.
+    "chunk-crossing": (0, 67, "32"),
+    # 1064 roots: the writes start at node 1000, 24 short of an aux tile.
+    "aux-tile-crossing": (0, 1064, "32"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_PUSH_EDGES))
+def test_push_edge_cases_match_off(case, monkeypatch):
+    """The push's edges on N=8 (no pruning, so every safe child of a
+    depth-0 parent survives): empty tiles, full tiles, a live prefix
+    that crosses chunk boundaries inside a tile, and one that crosses
+    an aux tile — pool rows and counts equal the off build's."""
+    target, copies, mt = _PUSH_EDGES[case]
+    mk = _mk_problem("nqueens")
+    frontier = lambda prob: _warm_frontier(prob, target, copies)  # noqa: E731
+    _, off = _trail(mk, {"TTS_MEGAKERNEL": "0"}, monkeypatch,
+                    frontier=frontier, steps=3)
+    prog, on = _trail(mk, {"TTS_MEGAKERNEL": "force",
+                           "TTS_MEGAKERNEL_MT": mt},
+                      monkeypatch, frontier=frontier, steps=3)
+    assert prog.megakernel.grid == 64 // int(mt)
+    _assert_same_trail(on, off)
+
+
+@pytest.mark.parametrize("family", ["nqueens", "pfsp-lb1", "pfsp-lb2"])
+def test_push_ordering_poisoned_headroom(family, monkeypatch):
+    """The one new way to corrupt the pool is a tile's chunk landing after
+    the next tile's: poison every row past size, run one cycle on a G=4
+    grid (the interpret mode lands a DMA only when it is waited on), and
+    every row in [old size, new size) must equal the stitch path's row."""
+    mk = _mk_problem(family)
+    frontier = lambda prob: _warm_frontier(prob, 70)  # noqa: E731
+    _, off = _trail(mk, {"TTS_MEGAKERNEL": "0"}, monkeypatch,
+                    frontier=frontier, steps=1, poison=77)
+    _, on = _trail(mk, {"TTS_MEGAKERNEL": "force", "TTS_MEGAKERNEL_MT": "16"},
+                   monkeypatch, frontier=frontier, steps=1, poison=77)
+    (counts, vals, aux), = on
+    assert counts[0] > 0  # the cycle pushed rows
+    _assert_same_trail(on, off)
+
+
+@pytest.mark.parametrize("family", ["nqueens", "pfsp-lb1"])
+def test_batched_engine_armed_streamed_matches_sequential(family,
+                                                          monkeypatch):
+    """B=2 batched program with the streamed push armed in every slot:
+    each tenant lands the sequential goldens."""
+    monkeypatch.setenv("TTS_MEGAKERNEL", "force")
+    monkeypatch.setenv("TTS_MEGAKERNEL_MT", "16")
+    mk = _mk_problem(family)
+    opt = sequential_search(mk()).best
+    seq = sequential_search(mk(), initial_best=opt)
+    for res in batched_search(mk(), n_jobs=3, B=2, m=4, M=64, K=8,
+                              initial_best=opt):
+        assert res.megakernel == "on", res.megakernel_reason
+        assert _counts(res) == _counts(seq)
 
 
 # -- the Megacore evaluation-only split -------------------------------------

@@ -84,6 +84,49 @@ def test_multi_counter_parity():
     assert (res.explored_tree, res.explored_sol) == (2056, 92)
 
 
+@pytest.mark.parametrize("mt", [None, "128"])
+def test_push_rows_counts_the_kernel_push(mt, monkeypatch):
+    """With the TPU cycle armed, ``push_rows`` is what the kernel's push
+    wrote: whole chunks of nodes covering each tile's live prefix from the
+    8-row pool tile it starts in — at least the nodes pushed, at most a
+    chunk and a row tile more per tile — and not the constant M*n
+    reservation per cycle it charged while XLA stitched."""
+    import jax
+    import numpy as np
+
+    from tpu_tree_search.engine.resident import _make_program, resolve_capacity
+    from tpu_tree_search.ops import megakernel as MK
+
+    monkeypatch.setenv("TTS_OBS", "1")
+    monkeypatch.setenv("TTS_MEGAKERNEL", "force")
+    if mt:
+        monkeypatch.setenv("TTS_MEGAKERNEL_MT", mt)
+    prob = NQueensProblem(N=8)
+    capacity, M = resolve_capacity(prob, 512, 8192)
+    prog = _make_program(prob, 1, M, 4, capacity, jax.devices()[0])
+    assert prog.obs and prog.megakernel.enabled
+    G, n = prog.megakernel.grid, prob.child_slots
+    chunk = MK._chunk_nodes(prog.row_pack)
+    tile = 8 * prog.row_pack  # nodes of an 8-row pool tile
+    state = prog.init_state({k: np.repeat(v, 9, axis=0)
+                             for k, v in prob.root().items()}, 0)
+    total = cycles_total = 0
+    for _ in range(6):
+        out = prog.step(state)
+        tree, _sol, cycles, _size, _best, ctr = prog.read_scalars(out)
+        state = prog.carry(out)
+        rows = int(ctr[counters.IDX["push_rows"]])
+        pushed = int(ctr[counters.IDX["pushed"]])
+        assert pushed == tree
+        assert rows % chunk == 0
+        assert pushed <= rows <= pushed + cycles * G * (chunk + tile - 1)
+        total += rows
+        cycles_total += cycles
+        if not cycles:
+            break
+    assert 0 < total < cycles_total * M * n
+
+
 # -- zero-cost disabled path (routed through the contract registry) --------
 # The byte-identity and cache-key claims are Contracts (obs/counters.py,
 # engine/resident.py) checked over the whole knob matrix by `tts check`;

@@ -91,8 +91,12 @@ def test_lb1_bounds_compiles_ta014(chip):
 @pytest.mark.parametrize("fam", ["lb1", "nqueens"])
 def test_megakernel_cycle_compiles_at_auto_tile(fam, chip):
     """The one-kernel cycle at the chunk size and pool tile ``auto``
-    resolves on a TPU for ta014 lb1 (M=1024) and N=14 (M=50000)."""
+    resolves on a TPU for ta014 lb1 (M=1024) and N=14 (M=50000), pushing
+    into pools laid out as the program allocates them: the kernel takes
+    both pools in place (donated, aliased to its outputs) and the program
+    copies neither."""
     from tpu_tree_search.cli import resolve_chunk_size
+    from tpu_tree_search.engine.resident import resolve_capacity
     from tpu_tree_search.ops import megakernel as MK
     from tpu_tree_search.problems import NQueensProblem, PFSPProblem
 
@@ -100,19 +104,33 @@ def test_megakernel_cycle_compiles_at_auto_tile(fam, chip):
         prob = PFSPProblem(inst=14, lb="lb1", ub=1)
         M = resolve_chunk_size(None, "pfsp", "device", "resident",
                                backend="tpu")
+        vdt = adt = jnp.int8
     else:
         prob = NQueensProblem(N=14)
         M = resolve_chunk_size(None, "nqueens", "device", "resident",
                                backend="tpu")
+        vdt, adt = jnp.uint8, jnp.int8
     dec = MK.resolve(prob, M, chip)
     assert dec.enabled and dec.auto and not dec.interpret, dec
     assert dec.grid > 1  # the streamed form is what auto picks here
     n = prob.child_slots
+    capacity, M = resolve_capacity(prob, M, None)
+    W, kq, rows = MK.push_layout(dec, n, capacity)
+    # kq nodes to a 128-lane row, in slots of the next power of two
+    assert (W, kq) == ((32, 4) if fam == "lb1" else (16, 8))
+    assert rows % 1024 == 0 and rows >= capacity + MK.PUSH_SLACK
     cycle = MK.make_cycle(prob, M, chip, dec)
-    txt = _compile_text(
-        cycle, chip,
+    sh = SingleDeviceSharding(chip)
+    args = [jax.ShapeDtypeStruct(s, d, sharding=sh) for s, d in (
+        ((rows // kq, 128), vdt), ((rows,), adt), ((), jnp.int32),
         ((M, n), jnp.int32), ((M,), jnp.int32), ((M,), jnp.bool_),
-        ((), jnp.int32),
-    )
+        ((), jnp.int32))]
+    txt = jax.jit(cycle, donate_argnums=(0, 1)).lower(*args).compile() \
+        .as_text()
     assert "tpu_custom_call" in txt
     assert f"%tts_cycle_{fam}" in txt
+    assert "output_to_operand_aliasing" in txt
+    pool = f"[{rows // kq},128]"
+    assert not [ln for ln in txt.splitlines()
+                if " copy(" in ln and pool in ln.split("=")[1][:40]], \
+        "the program copies the value pool"

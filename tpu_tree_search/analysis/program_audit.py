@@ -118,6 +118,9 @@ class Cell:
     # pins the streamed pool-tile axis so the grid form (grid = M/Mt > 1)
     # gets its own audited cell.
     mt: str | None = None
+    # None = TTS_KERNEL_BACKEND unset (keys stay byte-stable); "gpu" pins
+    # the Triton flavor of the armed cycle, whose rows XLA still stitches.
+    backend: str | None = None
 
     @property
     def key(self) -> str:
@@ -128,6 +131,8 @@ class Cell:
             s += f"|mk={self.megakernel}"
         if self.mt is not None:
             s += f"|mt={self.mt}"
+        if self.backend is not None:
+            s += f"|kb={self.backend}"
         return s
 
     def env(self) -> dict[str, str]:
@@ -142,6 +147,8 @@ class Cell:
             e["TTS_MEGAKERNEL"] = self.megakernel
         if self.mt is not None:
             e["TTS_MEGAKERNEL_MT"] = self.mt
+        if self.backend is not None:
+            e["TTS_KERNEL_BACKEND"] = self.backend
         return e
 
 
@@ -198,6 +205,12 @@ def matrix_cells(families=None, compact=None, obs=None, phaseprof=None,
             out.append(Cell(fam, "auto", (obs or OBS_AXIS)[0],
                             (phaseprof or PHASEPROF_AXIS)[0], pb,
                             megakernel="force", mt="16"))
+        # The gpu flavor of the armed cycle (single tile, its rows stitched
+        # by XLA — megakernel-push-in-kernel pins both flavors' paths).
+        if fam == "nqueens":
+            out.append(Cell(fam, "auto", (obs or OBS_AXIS)[0],
+                            (phaseprof or PHASEPROF_AXIS)[0], pb,
+                            megakernel="force", backend="gpu"))
     return out
 
 

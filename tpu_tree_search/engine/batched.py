@@ -13,13 +13,14 @@ cycle/explored counters), and one dispatch advances every live slot.
 Two design rules keep the batch bit-identical to solo execution:
 
   * **Unrolled slots, not vmap.**  The body applies the resident
-    engine's own per-instance body (``loop_fns``) to each slot and masks
-    the result with that slot's own cond (``jnp.where(live, new, old)``).
-    A frozen slot (terminated, stalled, or empty) discards every update
-    — its cycle counter stays put — so each slot executes *exactly* the
-    cycle sequence its solo program would, in the same order, with the
-    same reductions.  vmap would rebuild the math with a batch axis and
-    forfeit the B=1 jaxpr identity that pins this claim.
+    engine's own per-instance body (``loop_fns``) to each slot under that
+    slot's own cond (``lax.cond(live, body, identity)``).  A frozen slot
+    (terminated, stalled, or empty) runs no cycle — its carry, cycle
+    counter included, passes through untouched, and its pools are neither
+    read nor copied — so each slot executes *exactly* the cycle sequence
+    its solo program would, in the same order, with the same reductions.
+    vmap would rebuild the math with a batch axis and forfeit the B=1
+    jaxpr identity that pins this claim.
   * **Admission is a transfer, not a trace.**  ``make_slot`` builds a
     slot's carry leaves on the host (zero-padded to pool capacity) and
     `jax.device_put`s them; the jit cache key is (avals, statics), and
@@ -101,7 +102,6 @@ class _BatchedProgram:
     def _build(self):
         import jax
         import jax.numpy as jnp
-        from functools import partial
         from jax import lax
 
         cond1, body1 = self.inner.loop_fns()
@@ -124,13 +124,8 @@ class _BatchedProgram:
                 return live
 
             def body(carry):
-                out = []
-                for slot in carry:
-                    live = cond1(slot)
-                    new = body1(slot)
-                    out.append(jax.tree_util.tree_map(
-                        partial(jnp.where, live), new, slot))
-                return tuple(out)
+                return tuple(lax.cond(cond1(slot), body1, lambda s: s, slot)
+                             for slot in carry)
 
         def step(*flat):
             zero = jnp.int32(0)
@@ -156,16 +151,14 @@ class _BatchedProgram:
         (contract `batch-splice-no-recompile`)."""
         import jax
 
-        C = self.capacity
         k = 0
         if frontier is not None:
             k = int(np.asarray(frontier[self.inner.size_field]).shape[0])
         leaves = []
-        for name, dtype, shape in self.inner.pool_fields:
-            dt = np.dtype(dtype)
-            buf = np.zeros((C,) + tuple(shape), dtype=dt)
-            if k:
-                buf[:k] = np.asarray(frontier[name]).astype(dt, copy=False)
+        for i, (name, dtype, shape) in enumerate(self.inner.pool_fields):
+            rows = (np.asarray(frontier[name]) if k
+                    else np.zeros((0,) + shape, dtype))
+            buf = self.inner.pack_rows(i, rows, self.inner.pool_rows)
             leaves.append(jax.device_put(buf, self.device))
         leaves.append(jax.device_put(np.int32(k), self.device))
         leaves.append(jax.device_put(np.int32(best), self.device))
@@ -183,9 +176,9 @@ class _BatchedProgram:
         guarantee (jit cache key = avals + statics)."""
         import jax
 
-        C = self.capacity
-        out = [jax.ShapeDtypeStruct((C,) + tuple(shape), np.dtype(dtype))
-               for _name, dtype, shape in self.inner.pool_fields]
+        out = [jax.ShapeDtypeStruct(full, np.dtype(dtype))
+               for (_name, dtype, _shape), full in zip(
+                   self.inner.pool_fields, self.inner.pool_shapes())]
         out.append(jax.ShapeDtypeStruct((), np.int32))
         out.append(jax.ShapeDtypeStruct((), np.int32))
         return out

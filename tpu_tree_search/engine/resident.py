@@ -104,11 +104,21 @@ def _compact_ids(keep, S: int, mode: str | None = None):
     return compact_ids(keep, S, mode)
 
 
+def node_rows(host: np.ndarray, shape: tuple, width: int) -> np.ndarray:
+    """A pool array's leading rows as nodes: the value pool's rows split
+    into ``width``-lane slots, one node each, with their dead lanes
+    dropped (``shape`` is the field's node shape from ``pool_fields``;
+    `megakernel.push_layout`)."""
+    return host.reshape(-1, width)[:, : shape[0]] if shape else host
+
+
 class _ResidentProgram:
     """Compiled device-resident step for one (problem, m, M, K, capacity).
 
     Pool layout (both problems): ``vals`` (C, n) — the permutation rows —
-    plus one scalar ``aux`` column (C,) (PFSP: limit1; N-Queens: depth).
+    plus one scalar ``aux`` column (C,) (PFSP: limit1; N-Queens: depth);
+    where the TPU cycle pushes, ``vals`` holds several nodes to a
+    lane-dense row (`pool_shapes`).
     Subclasses provide the chunk evaluator and the swap position.
     """
 
@@ -167,6 +177,13 @@ class _ResidentProgram:
 
         self.megakernel = MK.resolve(problem, M, self.device,
                                      mp_axis=mp_axis, mp_size=mp_size)
+        # The pool as the device holds it: the TPU cycle pushes whole
+        # 128-lane rows by DMA, so where it is armed the value pool holds
+        # `row_pack` nodes to a row, each in a `row_width`-lane slot, and
+        # the pools carry slack nodes (MK.push_layout); elsewhere
+        # (capacity, n) exactly.
+        self.row_width, self.row_pack, self.pool_rows = MK.push_layout(
+            self.megakernel, n, capacity)
         # Kernel-backend seam (TTS_KERNEL_BACKEND, ops/backend.py): the
         # flavor this build actually runs — "jnp" when neither the cycle
         # nor the evaluator routes to a Pallas kernel — and whether those
@@ -182,16 +199,18 @@ class _ResidentProgram:
             self.megakernel.interpret if self.megakernel.enabled
             else PK._default_interpret(self.kernel_backend))
         self._step = self._build()
+        self._fill = self._build_fill()
 
     def _evaluator_uses_kernels(self) -> bool:
         from ..ops import pallas_kernels as PK
 
         return PK.use_pallas(self.device)
 
-    def loop_fns(self, K: int | None = None):
+    def loop_fns(self, K: int | None = None, sharded: bool = False):
         """(cond, body) of the K-cycle device loop over the carry
         ``(pool_vals, pool_aux, size, best, tree, sol, cycles)`` — reused by
-        the single-device step and, per shard, by the mesh-resident tier.
+        the single-device step and, per shard (``sharded``), by the
+        mesh-resident tier.
         With ``self.obs`` the carry gains one trailing ``(NSLOTS,)`` int32
         counter block (obs/counters.py), accumulated per cycle and harvested
         at the dispatch boundary; with ``self.phaseprof`` a final
@@ -213,6 +232,7 @@ class _ResidentProgram:
         phaseprof = self.phaseprof
         S = self.S
         mode = self.compact
+        W, kq = self.row_width, self.row_pack
         vals_dt = self.pool_fields[0][1]
         aux_dt = self.pool_fields[1][1]
         evaluate = self._make_eval()
@@ -222,7 +242,7 @@ class _ResidentProgram:
             from ..ops import megakernel as MK
 
             mk_cycle = MK.make_cycle(self.problem, M, self.device,
-                                     self.megakernel)
+                                     self.megakernel, sharded=sharded)
 
         # tts-lint: traced (returned to lax.while_loop via loop_fns)
         def body(carry):
@@ -245,7 +265,20 @@ class _ResidentProgram:
                 start2 = jnp.clip(start, 0, C - M)
                 idx = start2 + jnp.arange(M, dtype=jnp.int32)
                 valid = (idx >= start) & (idx < size)
-                vals8_c = lax.dynamic_slice(pool_vals, (start2, 0), (M, n))
+                if kq == 1:
+                    vals8_c = lax.dynamic_slice(pool_vals, (start2, 0),
+                                                (M, W))
+                else:
+                    # kq nodes to a pool row: slice the rows that hold
+                    # the chunk, split them into node slots, and slice
+                    # the chunk out of those.
+                    L = -(-M // kq) + 1
+                    blk = lax.dynamic_slice(pool_vals, (start2 // kq, 0),
+                                            (L, kq * W))
+                    vals8_c = lax.dynamic_slice(
+                        blk.reshape(L * kq, W), (start2 % kq, 0), (M, W))
+                if W != n:
+                    vals8_c = vals8_c[:, :n]
                 vals_c = vals8_c.astype(jnp.int32)
                 aux_c = lax.dynamic_slice(pool_aux, (start2,),
                                           (M,)).astype(jnp.int32)
@@ -257,39 +290,18 @@ class _ResidentProgram:
 
             if mk_cycle is not None:
                 # Armed one-kernel cycle (ops/megakernel.py): bound + prune
-                # + shift-compact + emit run inside ONE pallas_call; the
-                # engine only writes the compacted rows back into the
-                # reserved Mn headroom (rows past tree_inc are dead by the
-                # pool contract). On the streamed path (grid > 1) each of
-                # the G tiles owns an (Mt*n)-row block compacted to its own
-                # front; the blocks are stitched with G overlapping
-                # dynamic_update_slice writes at the kernel's carried
-                # offsets — written in tile order, so each write's garbage
-                # tail is overwritten by the next tile's rows and the live
-                # prefix is exactly the dense-mode global order (single-
-                # tile: G == 1, offs == [0], one full-width write as
-                # before). The phase profiler reports the collapse
-                # honestly: everything lands in `eval`, and the
-                # pop+eval+...+overflow == total telescope still holds.
-                rows_mk, aux_mk, offs_mk, tree_inc, sol_inc, best = mk_cycle(
-                    vals_c, aux_c, valid, best
-                )
+                # + shift-compact + push run inside ONE pallas_call, which
+                # places the survivors at [size, size + tree_inc) of the
+                # pools in their own dtypes (TPU: by DMA into the aliased
+                # pools; the gpu flavor stitches its single tile in XLA).
+                # The engine only advances size. The phase profiler
+                # reports the collapse honestly: everything lands in
+                # `eval`, and the pop+eval+...+overflow == total telescope
+                # still holds.
+                pool_vals, pool_aux, tree_inc, sol_inc, best, push_rows = (
+                    mk_cycle(pool_vals, pool_aux, size, vals_c, aux_c, valid,
+                             best))
                 fits = tree_inc <= S  # survivor-budget overflow counter
-                with jax.named_scope("tts_push"):
-                    rows_cast = rows_mk.astype(vals_dt)
-                    aux_cast = aux_mk.astype(aux_dt)
-                    G_mk = offs_mk.shape[0]
-                    Mtn = Mn // G_mk
-                    for ti in range(G_mk):
-                        dst = size + offs_mk[ti]
-                        pool_vals = lax.dynamic_update_slice(
-                            pool_vals, rows_cast[ti * Mtn:(ti + 1) * Mtn],
-                            (dst, jnp.int32(0))
-                        )
-                        pool_aux = lax.dynamic_update_slice(
-                            pool_aux, aux_cast[ti * Mtn:(ti + 1) * Mtn],
-                            (dst,)
-                        )
                 size = size + tree_inc
                 if phaseprof:
                     ph, (pool_vals, pool_aux, size) = obs_phases.boundary(
@@ -301,11 +313,10 @@ class _ResidentProgram:
                     tree + tree_inc, sol + sol_inc, cycles + 1,
                 )
                 if obs:
-                    # push_rows: the megakernel always shift-compacts the
-                    # whole Mn reservation.
+                    # push_rows: the pool rows the cycle's push wrote.
                     ctr = obs_counters.update(
                         ctr, cnt, n, tree_inc, sol_inc, fits, size,
-                        jnp.int32(Mn),
+                        push_rows,
                     )
                     out = out + (ctr,)
                 if phaseprof:
@@ -468,22 +479,64 @@ class _ResidentProgram:
 
     # -- state layout: (pool..., size, best, tree_inc, sol_inc, cycles) ----
 
+    def pool_shapes(self) -> list[tuple[int, ...]]:
+        """The device shape of each ``pool_fields`` array: ``pool_rows``
+        nodes, the value pool ``row_pack`` nodes to a row of
+        ``row_width``-lane slots (lanes past the node's own are dead;
+        `megakernel.push_layout`)."""
+        kq, W = self.row_pack, self.row_width
+        return [(self.pool_rows // kq, kq * W) if shape else (self.pool_rows,)
+                for _name, _dt, shape in self.pool_fields]
+
+    def pack_rows(self, field: int, rows: np.ndarray, nodes: int) -> np.ndarray:
+        """Host node rows of pool field ``field`` laid out as the first
+        ``nodes`` nodes of its device array (`pool_shapes`), zero past
+        them."""
+        _name, dtype, shape = self.pool_fields[field]
+        k = rows.shape[0]
+        if not shape:
+            buf = np.zeros(nodes, dtype)
+            buf[:k] = rows
+            return buf
+        kq, W = self.row_pack, self.row_width
+        buf = np.zeros((nodes, W), dtype)
+        buf[:k, : shape[0]] = rows
+        return buf.reshape(nodes // kq, kq * W)
+
+    def _build_fill(self):
+        """``fill(*heads) -> pools``: the zeroed pools with each field's
+        packed leading rows written in, as one program."""
+        import jax
+        import jax.numpy as jnp
+        from jax import lax
+
+        shapes = self.pool_shapes()
+        dtypes = [dt for _name, dt, _shape in self.pool_fields]
+
+        def fill(*heads):
+            return tuple(
+                lax.dynamic_update_slice(jnp.zeros(sh, dt), h, (0,) * len(sh))
+                for sh, dt, h in zip(shapes, dtypes, heads))
+
+        return jax.jit(fill)
+
     def init_state(self, frontier: dict, best: int):
         import jax
         import jax.numpy as jnp
 
-        C = self.capacity
         k = frontier[self.size_field].shape[0] if frontier else 0
+        # One upload of the frontier's rows and one program that lays
+        # them into zeroed pools. The upload is sized to a power of two so
+        # that a new frontier size seldom compiles `fill` anew.
+        nodes = min(max(128, 1 << max(k - 1, 0).bit_length()),
+                    self.pool_rows)
+        heads = [
+            self.pack_rows(i, np.asarray(frontier[name]) if k
+                           else np.zeros((0,) + shape), nodes)
+            for i, (name, _dt, shape) in enumerate(self.pool_fields)]
         with jax.default_device(self.device):
-            pools = []
-            for name, dtype, shape in self.pool_fields:
-                buf = jnp.zeros((C,) + shape, dtype=dtype)
-                if k:
-                    rows = jnp.asarray(frontier[name]).astype(dtype)
-                    buf = buf.at[:k].set(rows)
-                pools.append(buf)
             return (
-                *pools,
+                *self._fill(*heads),
                 jnp.int32(k),
                 jnp.int32(best),
             )
@@ -538,9 +591,12 @@ class _ResidentProgram:
         # one padded transfer; the overflow fallback passes larger sizes.
         batch = {}
         fields = self.problem.node_fields()
-        for (name, _, _), buf in zip(self.pool_fields, pools):
-            host = np.asarray(buf[: max(size, 1)])[:size]
-            batch[name] = host.astype(fields[name][1])
+        for (name, _, shape), buf in zip(self.pool_fields, pools):
+            lead = max(size, 1)
+            if shape:  # the value pool's rows hold row_pack nodes each
+                lead = -(-lead // self.row_pack)
+            host = node_rows(np.asarray(buf[:lead]), shape, self.row_width)
+            batch[name] = host[:size].astype(fields[name][1])
         return self.derive_fields(batch), size, best
 
     def snapshot(self, state) -> tuple[dict, int, int]:
@@ -551,8 +607,9 @@ class _ResidentProgram:
         best = int(best)
         fields = self.problem.node_fields()
         batch = {}
-        for (name, _, _), buf in zip(self.pool_fields, pools):
-            batch[name] = np.asarray(buf)[:size].astype(fields[name][1])
+        for (name, _, shape), buf in zip(self.pool_fields, pools):
+            host = node_rows(np.asarray(buf), shape, self.row_width)
+            batch[name] = host[:size].astype(fields[name][1])
         return self.derive_fields(batch), size, best
 
 
@@ -759,8 +816,8 @@ def _make_program(
 
 def default_capacity(M: int, child_slots: int, node_bytes: int) -> int:
     """Pool capacity heuristic: at least two full chunk fan-outs of headroom,
-    capped by a ~1 GiB HBM budget. Correctness never depends on it (overflow
-    falls back to host offload cycles)."""
+    capped by a ~1 GiB HBM budget of ``node_bytes`` a node. Correctness
+    never depends on it (overflow falls back to host offload cycles)."""
     want = max(2 * M * child_slots, 1 << 21)
     budget = (1 << 30) // max(1, node_bytes)
     return max(4 * M, min(want, budget))
@@ -770,11 +827,16 @@ def resolve_capacity(problem: Problem, M: int, capacity: int | None) -> tuple[in
     """Shared (capacity, M) resolution for the resident tiers: apply the
     default_capacity heuristic when unset, then clamp M so one chunk fan-out
     always fits in half the pool."""
+    from ..ops.megakernel import push_pack
+
     n = problem.child_slots
     if capacity is None:
+        # A node's bytes as the pools may store it: a value row in the
+        # slot the TPU cycle's lane-dense pool gives it (`push_pack`, at
+        # most twice its own width).
         fields = problem.node_fields()
         node_bytes = sum(
-            int(np.prod(shape, dtype=np.int64)) * dt.itemsize + 4
+            (push_pack(shape[0])[0] if shape else 1) * dt.itemsize + 4
             for shape, dt in fields.values()
         )
         capacity = default_capacity(M, n, node_bytes)

@@ -16,27 +16,33 @@ bounds are evaluated with the same tile math as the standalone kernels
 `ops/pallas_kernels.py` — shared helpers, so the bound values are the
 already-pinned-exact kernel values), pruning, the LSB-first binary-shift
 survivor compaction of `ops/compaction.shift_compact`, and the push all
-happen against the tile in VMEM, and only the compacted child rows leave.
+happen against the tile in VMEM, and only the compacted child rows leave:
+on a TPU the kernel DMAs them, in the pool dtypes, straight into the
+donated pool (`_push_emit`).
 
 Tiling (``TTS_MEGAKERNEL_MT``, auto-resolved like `_auto_compact`):
 
-* ``Mt == M`` (one tile) keeps the original pool-resident form verbatim:
-  ``grid=(1,)``, no streaming, the whole chunk lives in VMEM.
+* ``Mt == M`` (one tile): ``grid=(1,)``, no streaming, the whole chunk
+  lives in VMEM.
 * ``Mt < M`` streams ``G = M//Mt`` tiles.  Survivor compaction becomes
   two-phase across tiles: each tile dense-ranks its own survivors in VMEM
   (`_compact_push` at width Mt), and an SMEM carry accumulates the
   cross-tile survivor offset so push destinations stay collision-free and
   the concatenation of tiles is exactly the dense-mode global
-  (parent, slot) order of `ops/compaction.py`.  The engine stitches the
-  per-tile blocks with G overlapping `dynamic_update_slice` writes at the
-  carried offsets — bit-identical to the single-tile emit.
+  (parent, slot) order of `ops/compaction.py`.  Each tile writes its
+  live prefix at ``size + offset`` itself, in tile order (the ordering
+  rule above `_PushRefs`) — bit-identical to the single-tile push.
 * The PFSP families need the incumbent fold over ALL leaves before any
   tile's keep test (`best = min(best, leaf bounds)` is global), so their
   grid is ``(2, G)``: phase 0 streams every tile, evaluates bounds into a
   per-tile VMEM stash and folds the global leaf-min; phase 1 re-streams
   the tiles (bounds are NOT recomputed — they are read back from the
-  stash) and runs prune/compact/emit against the final incumbent.
-  N-Queens has no bound pruning and keeps the single sweep ``grid=(G,)``.
+  stash) and runs prune/compact/emit against the final incumbent (at
+  G == 1 too).  N-Queens has no bound pruning and keeps the single sweep
+  ``grid=(G,)``.
+* The gpu flavor (Triton: no DMA into an aliased pool, no sequential
+  grid) is single-tile only and hands its (M*n, n) rows back for XLA to
+  stitch into the pool (`make_cycle`).
 * The cross-tile carry forces sequential grid order, so the full cycle
   kernels declare ``dimension_semantics=("arbitrary", ...)``; the
   evaluation-only pass has no carry and ships as a separate
@@ -123,6 +129,81 @@ def _pool_itemsize(fam: str, n: int) -> int:
 #: into a kernel module (the packages import each other lazily).
 _INF_BOUND = 2**31 - 1
 
+#: Nodes one push DMA writes, at least: the TPU cycle issues a tile's live
+#: prefix in chunks, so the bytes written follow the survivors, not the
+#: M*n reservation.  A chunk is also at least 32 pool rows (the 8-bit
+#: staging tile), so with many nodes to a pool row it holds more nodes
+#: (`_chunk_nodes`).
+PUSH_CHUNK = 128
+
+#: Row quantum of a DMA into the value pool: Mosaic tiles the 8/16-bit
+#: pool layouts by 8 rows, and a DMA starts on a whole tile.
+_ROW_TILE = 8
+
+#: The aux pool is written through its lane-dense (rows/128, 128) view,
+#: in whole (8, 128) tiles: 1024 nodes per DMA, starting on a tile.
+_AUX_TILE = _ROW_TILE * 128
+
+#: Nodes past ``size + M*n`` a push may touch (its last chunk and aux tile
+#: are whole; a chunk is at most 512 nodes): the armed TPU pool carries
+#: this much slack past the capacity, so the while-cond's
+#: ``size + M*n <= C`` stays the one rule.
+PUSH_SLACK = _AUX_TILE
+
+
+def push_pack(n: int) -> tuple[int, int]:
+    """(slot width ``W``, nodes per pool row ``kq``) of the value pool the
+    TPU cycle pushes into.  A node of ``n`` values takes a slot of ``W``
+    lanes — ``n`` rounded up to a power of two (at least 8) — and
+    ``kq = 128 // W`` nodes share one 128-lane row; past 64 values a node
+    takes whole 128-lane rows alone (``kq`` 1)."""
+    if n > 64:
+        return PK._r128(n), 1
+    w = max(8, 1 << (n - 1).bit_length())
+    return w, 128 // w
+
+
+def push_layout(decision, n: int, capacity: int) -> tuple[int, int, int]:
+    """(slot width ``W``, nodes per row ``kq``, node rows) of the device
+    pool a resident program allocates; the value pool's array is
+    ``(rows // kq, kq * W)``.
+
+    The TPU cycle pushes its survivors into the pool by DMA, and Mosaic
+    slices a DMA destination only on whole (8, 128) tiles: a (C, n) pool
+    with n < 128 cannot be sliced by row at all.  So where that cycle is
+    armed the value pool is lane-dense: `push_pack`'s ``kq`` nodes to a
+    128-lane row, each in a ``W``-lane slot whose lanes past ``n`` are
+    dead — ``W/n`` times the bytes of a compact (C, n) pool (16/15 for
+    N=15, 32/20 for a 20-job PFSP instance).  Both pools get
+    :data:`PUSH_SLACK` nodes past the capacity, rounded to whole aux tiles
+    so the aux pool has its (rows/128, 128) view.  Interpret mode runs the
+    same layout.  Every other build (off, refused, the gpu flavor) stores
+    (capacity, n)."""
+    if not (decision.enabled and decision.backend == "tpu"):
+        return n, 1, capacity
+    width, kq = push_pack(n)
+    return width, kq, PK._round_up(capacity + PUSH_SLACK, _AUX_TILE)
+
+
+def _chunk_nodes(kq: int) -> int:
+    """Nodes of one value chunk: PUSH_CHUNK, or 32 pool rows where more
+    than 4 nodes share a row."""
+    return max(PUSH_CHUNK, 32 * kq)
+
+
+def _push_sizes(mt: int, n: int) -> tuple[int, int, int]:
+    """Static node counts of one tile's push: the compaction's output rows
+    ``rc`` (the Mt*n reservation plus one row tile of ``8*kq`` nodes, for
+    a destination that starts inside its tile; packed-int8 aligned), the
+    value staging nodes ``rs`` (whole chunks) and the aux staging rows
+    ``ra`` (whole aux tiles, for a destination up to 1023 nodes into its
+    tile)."""
+    kq = push_pack(n)[1]
+    rc = PK._round_up(mt * n + _ROW_TILE * kq, 32)
+    rs = PK._round_up(rc, _chunk_nodes(kq))
+    ra = _ROW_TILE * -(-(_AUX_TILE - 1 + rc) // _AUX_TILE)
+    return rc, rs, ra
+
 
 def megakernel_mode() -> str:
     """The TTS_MEGAKERNEL knob: ``auto`` (default — TPU + per-tile VMEM
@@ -202,8 +283,27 @@ def _native_kind(device) -> str | None:
     return b.kind if (b.native and b.kind in ("tpu", "gpu")) else None
 
 
+def _push_bytes(mt: int, n: int, pool_itemsize: int) -> int:
+    """VMEM of one tile's in-kernel push: the value and aux staging (pool
+    dtypes; the aux charged at int32, its widest), the two head tiles, the
+    double-buffered (1, 128) scalar block, the one-hot operands of the aux
+    lane-dense transform and of one chunk's row packing (f32 and their
+    bf16 copies)."""
+    r8, r128 = PK._r8, PK._r128
+    rc, rs, ra = _push_sizes(mt, n)
+    w, kq = push_pack(n)
+    ch = _chunk_nodes(kq)
+    row = kq * w  # lanes of one pool row
+    staging = ((rs // kq + _ROW_TILE) * row * pool_itemsize
+               + (ra + 8) * 128 * 4)
+    lanes = (r8(ra) * r128(rc) + rc * 128) * 6
+    pack = (ch // kq * r128(ch) + 2 * ch * 128) * 6 if kq > 1 else 0
+    return staging + lanes + pack + 2 * 128 * 4
+
+
 def _mega_pool_bytes(M: int, n: int, pool_itemsize: int = 4,
-                     mt: int | None = None, lb_stash: bool = False) -> int:
+                     mt: int | None = None, lb_stash: bool = False,
+                     push: bool = True) -> int:
     """The cycle's VMEM charge on top of the bound kernels' own
     `_model_bytes` model (the ``extra_bytes`` the feasibility gate adds).
 
@@ -214,16 +314,18 @@ def _mega_pool_bytes(M: int, n: int, pool_itemsize: int = 4,
     operands are all live inside one grid step.
 
     Tiled (``mt < M``): the same intermediates at tile width Mt, PLUS a 2x
-    double-buffer charge on every streamed block (the pipelined grid
-    prefetches tile i+1's HBM->VMEM copies under tile i's compute — in
-    and out blocks both carry two live buffers), PLUS, with ``lb_stash``
-    (the PFSP two-phase grid), the (G, Mt, n) int32 bound stash that holds
-    phase 0's evaluations for phase 1 — the one charge that scales with M,
-    not Mt, and therefore the one that can still refuse a shape.
+    double-buffer charge on every streamed in block (the pipelined grid
+    prefetches tile i+1's HBM->VMEM copies under tile i's compute), PLUS,
+    with ``lb_stash`` (the PFSP two-phase grid), the (G, Mt, n) int32
+    bound stash that holds phase 0's evaluations for phase 1 — the one
+    charge that scales with M, not Mt, and therefore the one that can
+    still refuse a shape.
 
-    ``pool_itemsize`` charges the pool-dtype tiles (the popped values
-    entering) at their storage width; the in-kernel intermediates stay
-    int32/f32 regardless."""
+    ``push`` (the TPU flavor) charges the in-kernel push's staging
+    (`_push_bytes`) — it replaced the streamed (Mt*n, n) int32 out blocks —
+    and, single-tile too, the PFSP stash (its grid is two-phase there as
+    well).  ``pool_itemsize`` charges the pool-dtype tiles at their
+    storage width; the in-kernel intermediates stay int32/f32."""
     r8, r128 = PK._r8, PK._r128
     if mt is not None and mt >= M:
         mt = None
@@ -236,20 +338,24 @@ def _mega_pool_bytes(M: int, n: int, pool_itemsize: int = 4,
         # popped pool tile + its narrow copy, keep plane, scalar lanes
         io = (2 * r8(M) * r128(n) * pool_itemsize
               + r8(M) * r128(n) * 4 + 128 * 4)
-        return sel + flat + cols + tri + io
+        total = sel + flat + cols + tri + io
+        if push:
+            total += _push_bytes(M, n, pool_itemsize)
+            if lb_stash:
+                total += r8(M) * r128(n) * 4
+        return total
     G = M // mt
     Mtn = mt * n
     sel = r8(Mtn) * r128(mt) * 6
     flat = 3 * r8(Mtn) * r128(n) * 4
     cols = 4 * r8(Mtn) * 128 * 4
     tri = r8(mt) * r128(mt) * 4 + r8(n) * r128(n) * 4
-    # Streamed blocks are double-buffered by the pipelined grid: two live
-    # copies of each in block (pool tile + narrow copy + keep plane +
-    # lanes) and each out block (compacted rows + aux column + scalar row).
+    # Streamed in blocks are double-buffered by the pipelined grid: two
+    # live copies of each (pool tile + narrow copy + keep plane + lanes).
     stream_in = 2 * (2 * r8(mt) * r128(n) * pool_itemsize
                      + r8(mt) * r128(n) * 4 + 128 * 4)
-    stream_out = 2 * (r8(Mtn) * r128(n) * 4 + r8(Mtn) * 128 * 4 + 128 * 4)
-    total = sel + flat + cols + tri + stream_in + stream_out
+    total = sel + flat + cols + tri + stream_in
+    total += _push_bytes(mt, n, pool_itemsize)
     if lb_stash:
         total += G * r8(mt) * r128(n) * 4
     return total
@@ -279,7 +385,8 @@ def _fits(problem, fam: str, M: int, n: int, mt: int | None = None,
     t = mt or M
     itemsize = _pool_itemsize(fam, n) if narrow_enabled() else 4
     extra = _mega_pool_bytes(M, n, itemsize, mt=mt,
-                             lb_stash=(fam != "nqueens"))
+                             lb_stash=(fam != "nqueens"),
+                             push=(backend == "tpu"))
     if fam == "nqueens":
         need = PK._model_bytes(t, n, 1, extra, 3)
     elif fam == "lb1":
@@ -424,38 +531,29 @@ def resolve(problem, M: int, device=None, mp_axis: str | None = None,
 # ---------------------------------------------------------------------------
 
 #: Trace regions inside the cycle kernels (``jax.named_scope`` lowers to
-#: Mosaic ``trace_start``/``trace_stop``): the tile bound, and the prune +
-#: compaction + emit epilogue (docs/OBSERVABILITY.md leg 7).
+#: Mosaic ``trace_start``/``trace_stop``): the tile bound, the prune +
+#: compaction epilogue, and the push of the compacted rows into the pool
+#: (docs/OBSERVABILITY.md leg 7).
 BOUND_SCOPE = "tts_bound"
 COMPACT_SCOPE = "tts_compact"
+EMIT_SCOPE = "tts_emit"
 
 
-def _scalar_lanes(tree_inc, sol_inc, best):
-    """(1, 128) int32 scalar output row: lanes 0/1/2 = tree_inc / sol_inc /
-    best (Mosaic wants a full lane register, not three scalars)."""
+def _scalar_lanes(tree_inc, sol_inc, best, pushed=0):
+    """(1, 128) int32 scalar output row: lanes 0/1/2/3 = tree_inc /
+    sol_inc / best / pool rows the push wrote (Mosaic wants a full lane
+    register, not four scalars)."""
     lane = jax.lax.broadcasted_iota(jnp.int32, (1, 128), 1)
     return jnp.where(
         lane == 0, tree_inc,
-        jnp.where(lane == 1, sol_inc, jnp.where(lane == 2, best, 0)),
+        jnp.where(lane == 1, sol_inc,
+                  jnp.where(lane == 2, best,
+                            jnp.where(lane == 3, pushed, 0))),
     )
 
 
-def _tile_scalar_lanes(offs, cnt, sol_cum, best):
-    """(1, 128) int32 PER-TILE scalar row of the streamed grid: lanes
-    0/1/2/3 = cross-tile survivor offset before this tile / this tile's
-    survivor count / cumulative sol_inc through this tile / incumbent.
-    The engine reads the last tile's row for the cycle scalars and the
-    offset column for the stitch destinations."""
-    lane = jax.lax.broadcasted_iota(jnp.int32, (1, 128), 1)
-    return jnp.where(
-        lane == 0, offs,
-        jnp.where(lane == 1, cnt,
-                  jnp.where(lane == 2, sol_cum,
-                            jnp.where(lane == 3, best, 0))),
-    )
-
-
-def _compact_push(vals, aux, d, keep, *, n: int, M: int):
+def _compact_push(vals, aux, d, keep, *, n: int, M: int, shift=None,
+                  width: int | None = None):
     """Survivor compaction entirely in VMEM, as one-hot MXU matmuls.
 
     Survivor ranks are exclusive prefix counts (triangular matmuls over
@@ -473,13 +571,27 @@ def _compact_push(vals, aux, d, keep, *, n: int, M: int):
     beyond ``tree_inc`` zero (dead by the pool contract — the engine
     advances ``size`` by tree_inc only).  On the streamed path this runs
     per tile at ``M = Mt``; the cross-tile offset carry makes the
-    concatenation of tiles the dense-mode global order."""
+    concatenation of tiles the dense-mode global order.
+
+    The TPU push passes ``shift`` (its destination's node offset inside
+    an 8-row pool tile, traced) and ``width`` (the pool's slot width):
+    output row ``k`` then holds survivor ``k - shift`` over
+    `_push_sizes`' ``rc`` rows — rows below ``shift`` empty, for the pool
+    nodes already there — and the value rows come ``width`` lanes wide,
+    lanes past ``n`` zero: one pool slot each, still with nothing
+    reshaped."""
     i32, f32 = jnp.int32, jnp.float32
     Mn = M * n
+    R = Mn if shift is None else _push_sizes(M, n)[0]
+    W = n if width is None else width
     keep_f = keep.astype(f32)  # (M, n)
     # Every operand value of the big selection matmuls (0/1 masks, lane
     # ranks < n, job or column indices < n) is exact in bf16 when n < 256.
     small = n < 256
+
+    def ranks(shape):
+        r = jax.lax.broadcasted_iota(i32, shape, 0)
+        return (r if shift is None else r - shift).astype(f32)
 
     # Exclusive prefix counts, within the row along lanes and across
     # parents.  Counts are < 2^24, so f32 accumulation is exact.
@@ -499,11 +611,19 @@ def _compact_push(vals, aux, d, keep, *, n: int, M: int):
     )[0:1]  # (1, M)
     offs_row = PK._hp_dot(cnt_row, (rm < cm).astype(f32))  # (1, M)
 
-    k = jax.lax.broadcasted_iota(i32, (Mn, M), 0).astype(f32)
+    k = ranks((R, M))
     sel = ((k >= offs_row) & (k < offs_row + cnt_row)).astype(f32)
-    par = PK._hp_dot(sel, vals.astype(f32), bf16=small)   # (Mn, n)
-    pkeep = PK._hp_dot(sel, keep_f, bf16=small)           # (Mn, n)
-    plane = PK._hp_dot(sel, lane, bf16=small)             # (Mn, n)
+    vals_f = vals.astype(f32)
+    if W != n:
+        # The parents placed at the pool's row width by a one-hot (n, W)
+        # matmul: (M, n) and (M, W) rows take the same vregs up to 128
+        # lanes, so the selection matmul below costs what it did.
+        place = (jax.lax.broadcasted_iota(i32, (n, W), 0)
+                 == jax.lax.broadcasted_iota(i32, (n, W), 1)).astype(f32)
+        vals_f = PK._hp_dot(vals_f, place, bf16=small)
+    par = PK._hp_dot(sel, vals_f, bf16=small)             # (R, W)
+    pkeep = PK._hp_dot(sel, keep_f, bf16=small)           # (R, n)
+    plane = PK._hp_dot(sel, lane, bf16=small)             # (R, n)
     # Per-parent scalars in lanes 0/1/2 of one side table: offset, swap
     # position, child aux.  Offsets may exceed 2^8: full precision.
     li = jax.lax.broadcasted_iota(i32, (M, 8), 1)
@@ -512,8 +632,8 @@ def _compact_push(vals, aux, d, keep, *, n: int, M: int):
                                jnp.where(li == 2,
                                          (aux + 1).astype(f32)[:, None],
                                          0.0)))
-    pside = PK._hp_dot(sel, side)  # (Mn, 8)
-    lo = jax.lax.broadcasted_iota(i32, (Mn, 8), 1)
+    pside = PK._hp_dot(sel, side)  # (R, 8)
+    lo = jax.lax.broadcasted_iota(i32, (R, 8), 1)
     poffs = jnp.sum(jnp.where(lo == 0, pside, 0.0), axis=1, keepdims=True)
     pd = jnp.sum(jnp.where(lo == 1, pside, 0.0), axis=1,
                  keepdims=True).astype(i32)
@@ -522,18 +642,20 @@ def _compact_push(vals, aux, d, keep, *, n: int, M: int):
 
     # The surviving slot j of output row k: the kept slot whose rank
     # within its parent is k - offs[p].
-    kc = jax.lax.broadcasted_iota(i32, (Mn, 1), 0).astype(f32)
-    jl = jax.lax.broadcasted_iota(i32, (Mn, n), 1)
+    kc = ranks((R, 1))
+    jl = jax.lax.broadcasted_iota(i32, (R, n), 1)
     hit = (pkeep > 0.5) & (plane == kc - poffs)
-    j = jnp.sum(jnp.where(hit, jl, 0), axis=1, keepdims=True)  # (Mn, 1)
+    j = jnp.sum(jnp.where(hit, jl, 0), axis=1, keepdims=True)  # (R, 1)
+    jw = jl if W == n else jax.lax.broadcasted_iota(i32, (R, W), 1)
     par = par.astype(i32)
-    v_d = jnp.sum(jnp.where(jl == pd, par, 0), axis=1, keepdims=True)
-    v_j = jnp.sum(jnp.where(jl == j, par, 0), axis=1, keepdims=True)
-    rows = jnp.where(jl == pd, v_j, jnp.where(jl == j, v_d, par))
+    v_d = jnp.sum(jnp.where(jw == pd, par, 0), axis=1, keepdims=True)
+    v_j = jnp.sum(jnp.where(jw == j, par, 0), axis=1, keepdims=True)
+    rows = jnp.where(jw == pd, v_j, jnp.where(jw == j, v_d, par))
     return rows, caux, tree_inc
 
 
-def _pfsp_epilogue(prmu, limit1, valid, best, lb, *, n: int, M: int):
+def _pfsp_epilogue(prmu, limit1, valid, best, lb, *, n: int, M: int,
+                   shift=None, width: int | None = None):
     """The `_PFSPResident` evaluate fold (open/leaf/incumbent/keep — the
     unstaged branch; see the staged-equivalence note in `make_cycle`) +
     compaction.  ``lb`` int32 per child slot; swap position and child
@@ -549,7 +671,8 @@ def _pfsp_epilogue(prmu, limit1, valid, best, lb, *, n: int, M: int):
     sol_inc = jnp.sum(leaf, dtype=i32)
     best = jnp.minimum(best, jnp.min(jnp.where(leaf, lb, i32(_INF_BOUND))))
     keep = open_ & (~leaf) & (lb < best)
-    rows, caux, tree_inc = _compact_push(prmu, limit1, pdepth, keep, n=n, M=M)
+    rows, caux, tree_inc = _compact_push(prmu, limit1, pdepth, keep, n=n, M=M,
+                                         shift=shift, width=width)
     return rows, caux, tree_inc, sol_inc, best
 
 
@@ -565,7 +688,8 @@ def _pfsp_leaf_min(limit1, valid, lb, *, n: int, M: int):
 
 
 # ---------------------------------------------------------------------------
-# family cycle kernels — single tile (grid=(1,), pool resident)
+# gpu flavor — single tile, rows handed back to XLA (Triton has no DMA
+# into an aliased pool; the engine-side stitch lives in `make_cycle`)
 # ---------------------------------------------------------------------------
 
 
@@ -589,62 +713,26 @@ def _mega_nqueens_kernel(board_ref, depth_ref, valid_ref, best_ref,
     scal_ref[:] = _scalar_lanes(tree_inc, sol_inc, best)
 
 
-def _mega_lb1_kernel(prmu_ref, limit1_ref, valid_ref, best_ref,
-                     ptm_ref, heads_ref, tails_ref,
-                     out_vals_ref, out_aux_ref, scal_ref, scan_ref,
-                     *, n: int, m: int, M: int, bf16: bool):
-    prmu = prmu_ref[:].astype(jnp.int32)
-    limit1 = limit1_ref[:, 0].astype(jnp.int32)
-    valid = valid_ref[:, 0] != 0
-    best = best_ref[0]
-    ptm = ptm_ref[:].astype(jnp.float32)
-    with jax.named_scope(BOUND_SCOPE):
-        lb = PK._lb1_tile_lb(prmu, limit1, ptm, heads_ref[:], tails_ref[:],
-                             scan_ref, n=n, m=m, bf16=bf16)
-    with jax.named_scope(COMPACT_SCOPE):
-        rows, caux, tree_inc, sol_inc, best = _pfsp_epilogue(
-            prmu, limit1, valid, best, lb, n=n, M=M)
-    out_vals_ref[:] = rows
-    out_aux_ref[:] = caux
-    scal_ref[:] = _scalar_lanes(tree_inc, sol_inc, best)
-
-
-def _mega_lb2_kernel(prmu_ref, limit1_ref, valid_ref, best_ref,
-                     ptm_ref, heads_ref,
-                     p0_ref, p1_ref, lag_ref, t0_ref, t1_ref,
-                     msel0_ref, msel1_ref, jorder_ref,
-                     out_vals_ref, out_aux_ref, scal_ref, scan_ref,
-                     *, n: int, m: int, P: int, M: int, pg: int, bf16: bool):
-    prmu = prmu_ref[:].astype(jnp.int32)
-    limit1 = limit1_ref[:, 0].astype(jnp.int32)
-    valid = valid_ref[:, 0] != 0
-    best = best_ref[0]
-    ptm = ptm_ref[:].astype(jnp.float32)
-    with jax.named_scope(BOUND_SCOPE):
-        lb = PK._lb2_tile_lb(
-            prmu, limit1, ptm, heads_ref[:],
-            p0_ref, p1_ref, lag_ref, t0_ref, t1_ref, msel0_ref, msel1_ref,
-            jorder_ref, scan_ref, n=n, m=m, P=P, pg=pg, bf16=bf16,
-        ).astype(jnp.int32)
-    with jax.named_scope(COMPACT_SCOPE):
-        rows, caux, tree_inc, sol_inc, best = _pfsp_epilogue(
-            prmu, limit1, valid, best, lb, n=n, M=M)
-    out_vals_ref[:] = rows
-    out_aux_ref[:] = caux
-    scal_ref[:] = _scalar_lanes(tree_inc, sol_inc, best)
-
-
 def _mega_lb1_kernel_gpu(prmu_ref, limit1_ref, valid_ref, best_ref,
                          ptm_ref, heads_ref, tails_ref,
                          out_vals_ref, out_aux_ref, scal_ref,
                          *, n: int, m: int, M: int, bf16: bool):
-    """The lb1 cycle without its scan scratch — the Triton flavor
-    (`pallas_kernels._front_scan` unrolls statically where the TPU kernel
-    staged; the epilogue is shift/select math, backend-neutral)."""
-    _mega_lb1_kernel(prmu_ref, limit1_ref, valid_ref, best_ref,
-                     ptm_ref, heads_ref, tails_ref,
-                     out_vals_ref, out_aux_ref, scal_ref, None,
-                     n=n, m=m, M=M, bf16=bf16)
+    """The lb1 cycle without a scan scratch (`pallas_kernels._front_scan`
+    unrolls statically where the TPU kernel staged; the epilogue is
+    shift/select math, backend-neutral)."""
+    prmu = prmu_ref[:].astype(jnp.int32)
+    limit1 = limit1_ref[:, 0].astype(jnp.int32)
+    valid = valid_ref[:, 0] != 0
+    ptm = ptm_ref[:].astype(jnp.float32)
+    with jax.named_scope(BOUND_SCOPE):
+        lb = PK._lb1_tile_lb(prmu, limit1, ptm, heads_ref[:], tails_ref[:],
+                             None, n=n, m=m, bf16=bf16)
+    with jax.named_scope(COMPACT_SCOPE):
+        rows, caux, tree_inc, sol_inc, best = _pfsp_epilogue(
+            prmu, limit1, valid, best_ref[0], lb, n=n, M=M)
+    out_vals_ref[:] = rows
+    out_aux_ref[:] = caux
+    scal_ref[:] = _scalar_lanes(tree_inc, sol_inc, best)
 
 
 def _mega_lb2_kernel_gpu(prmu_ref, limit1_ref, valid_ref, best_ref,
@@ -654,163 +742,27 @@ def _mega_lb2_kernel_gpu(prmu_ref, limit1_ref, valid_ref, best_ref,
                          out_vals_ref, out_aux_ref, scal_ref,
                          *, n: int, m: int, P: int, M: int, pg: int,
                          bf16: bool):
-    _mega_lb2_kernel(prmu_ref, limit1_ref, valid_ref, best_ref,
-                     ptm_ref, heads_ref,
-                     p0_ref, p1_ref, lag_ref, t0_ref, t1_ref,
-                     msel0_ref, msel1_ref, jorder_ref,
-                     out_vals_ref, out_aux_ref, scal_ref, None,
-                     n=n, m=m, P=P, M=M, pg=pg, bf16=bf16)
-
-
-# ---------------------------------------------------------------------------
-# family cycle kernels — streamed (grid over pool tiles, SMEM offset carry)
-# TPU-only: the cross-tile carry needs the sequential grid; `resolve`
-# refuses tiled streaming on the gpu flavor.
-# ---------------------------------------------------------------------------
-#
-# SMEM carry layout (persists across sequential grid steps):
-#   [0] cross-tile survivor offset   [1] cumulative sol_inc
-#   [2] globally folded incumbent (PFSP phase 0)
-
-
-def _mega_nqueens_tiled_kernel(board_ref, depth_ref, valid_ref, best_ref,
-                               out_vals_ref, out_aux_ref, scal_ref,
-                               carry_ref, *, N: int, g: int, Mt: int):
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _seed():
-        carry_ref[0] = 0
-        carry_ref[1] = 0
-
-    board = board_ref[:].astype(jnp.int32)  # (Mt, N)
-    depth = depth_ref[:, 0].astype(jnp.int32)
+    prmu = prmu_ref[:].astype(jnp.int32)
+    limit1 = limit1_ref[:, 0].astype(jnp.int32)
     valid = valid_ref[:, 0] != 0
-    best = best_ref[0]
+    ptm = ptm_ref[:].astype(jnp.float32)
     with jax.named_scope(BOUND_SCOPE):
-        labels = PK._nqueens_tile_labels(board, depth, N=N, g=g)
-    keep = labels & valid[:, None] & (depth < N)[:, None]
-    sol_inc = jnp.sum(valid & (depth == N), dtype=jnp.int32)
+        lb = PK._lb2_tile_lb(
+            prmu, limit1, ptm, heads_ref[:],
+            p0_ref, p1_ref, lag_ref, t0_ref, t1_ref, msel0_ref, msel1_ref,
+            jorder_ref, None, n=n, m=m, P=P, pg=pg, bf16=bf16,
+        ).astype(jnp.int32)
     with jax.named_scope(COMPACT_SCOPE):
-        rows, caux, cnt = _compact_push(board, depth, depth, keep, n=N,
-                                        M=Mt)
-    offs = carry_ref[0]
-    sol_cum = carry_ref[1] + sol_inc
+        rows, caux, tree_inc, sol_inc, best = _pfsp_epilogue(
+            prmu, limit1, valid, best_ref[0], lb, n=n, M=M)
     out_vals_ref[:] = rows
     out_aux_ref[:] = caux
-    scal_ref[:] = jnp.broadcast_to(
-        _tile_scalar_lanes(offs, cnt, sol_cum, best), (8, 128))
-    carry_ref[0] = offs + cnt
-    carry_ref[1] = sol_cum
+    scal_ref[:] = _scalar_lanes(tree_inc, sol_inc, best)
 
 
-def _mega_lb1_tiled_kernel(prmu_ref, limit1_ref, valid_ref, best_ref,
-                           ptm_ref, heads_ref, tails_ref,
-                           out_vals_ref, out_aux_ref, scal_ref,
-                           scan_ref, lb_ref, carry_ref,
-                           *, n: int, m: int, Mt: int, bf16: bool):
-    p = pl.program_id(0)
-    i = pl.program_id(1)
-
-    @pl.when((p == 0) & (i == 0))
-    def _seed():
-        carry_ref[0] = 0
-        carry_ref[1] = 0
-        carry_ref[2] = best_ref[0]
-
-    prmu = prmu_ref[:].astype(jnp.int32)
-    limit1 = limit1_ref[:, 0].astype(jnp.int32)
-    valid = valid_ref[:, 0] != 0
-
-    @pl.when(p == 0)
-    def _sweep():
-        # Phase 0: evaluate this tile's bounds into the stash and fold its
-        # leaf-min into the global incumbent — no tile may prune before
-        # every tile's leaves have been folded (PFSP exactness rule).
-        with jax.named_scope(BOUND_SCOPE):
-            lb = PK._lb1_tile_lb(prmu, limit1,
-                                 ptm_ref[:].astype(jnp.float32),
-                                 heads_ref[:], tails_ref[:], scan_ref,
-                                 n=n, m=m, bf16=bf16)
-        lb_ref[i] = lb
-        carry_ref[2] = jnp.minimum(
-            carry_ref[2], _pfsp_leaf_min(limit1, valid, lb, n=n, M=Mt))
-
-    @pl.when(p == 1)
-    def _emit():
-        # Phase 1: re-stream the tile (bounds come back from the stash,
-        # not recomputed) and prune/compact against the final incumbent.
-        lb = lb_ref[i]
-        with jax.named_scope(COMPACT_SCOPE):
-            rows, caux, cnt, sol_inc, best = _pfsp_epilogue(
-                prmu, limit1, valid, carry_ref[2], lb, n=n, M=Mt)
-        offs = carry_ref[0]
-        sol_cum = carry_ref[1] + sol_inc
-        out_vals_ref[:] = rows
-        out_aux_ref[:] = caux
-        scal_ref[:] = jnp.broadcast_to(
-            _tile_scalar_lanes(offs, cnt, sol_cum, best), (8, 128))
-        carry_ref[0] = offs + cnt
-        carry_ref[1] = sol_cum
-
-
-def _mega_lb2_tiled_kernel(prmu_ref, limit1_ref, valid_ref, best_ref,
-                           ptm_ref, heads_ref,
-                           p0_ref, p1_ref, lag_ref, t0_ref, t1_ref,
-                           msel0_ref, msel1_ref, jorder_ref,
-                           out_vals_ref, out_aux_ref, scal_ref,
-                           scan_ref, lb_ref, carry_ref,
-                           *, n: int, m: int, P: int, Mt: int, pg: int,
-                           bf16: bool):
-    p = pl.program_id(0)
-    i = pl.program_id(1)
-
-    @pl.when((p == 0) & (i == 0))
-    def _seed():
-        carry_ref[0] = 0
-        carry_ref[1] = 0
-        carry_ref[2] = best_ref[0]
-
-    prmu = prmu_ref[:].astype(jnp.int32)
-    limit1 = limit1_ref[:, 0].astype(jnp.int32)
-    valid = valid_ref[:, 0] != 0
-
-    @pl.when(p == 0)
-    def _sweep():
-        with jax.named_scope(BOUND_SCOPE):
-            lb = PK._lb2_tile_lb(
-                prmu, limit1, ptm_ref[:].astype(jnp.float32), heads_ref[:],
-                p0_ref, p1_ref, lag_ref, t0_ref, t1_ref, msel0_ref,
-                msel1_ref, jorder_ref, scan_ref, n=n, m=m, P=P, pg=pg,
-                bf16=bf16,
-            ).astype(jnp.int32)
-        lb_ref[i] = lb
-        carry_ref[2] = jnp.minimum(
-            carry_ref[2], _pfsp_leaf_min(limit1, valid, lb, n=n, M=Mt))
-
-    @pl.when(p == 1)
-    def _emit():
-        lb = lb_ref[i]
-        with jax.named_scope(COMPACT_SCOPE):
-            rows, caux, cnt, sol_inc, best = _pfsp_epilogue(
-                prmu, limit1, valid, carry_ref[2], lb, n=n, M=Mt)
-        offs = carry_ref[0]
-        sol_cum = carry_ref[1] + sol_inc
-        out_vals_ref[:] = rows
-        out_aux_ref[:] = caux
-        scal_ref[:] = jnp.broadcast_to(
-            _tile_scalar_lanes(offs, cnt, sol_cum, best), (8, 128))
-        carry_ref[0] = offs + cnt
-        carry_ref[1] = sol_cum
-
-
-# ---------------------------------------------------------------------------
-# pallas_call factories
-# ---------------------------------------------------------------------------
-
-
-def _cycle_out(M: int, n: int, backend: str = "tpu"):
-    """Single-tile out plumbing (grid=(1,) — the pool tile IS the grid)."""
+def _gpu_out(M: int, n: int):
+    """The gpu flavor's out plumbing: the (Mn, n) compacted rows, the
+    (Mn, 1) aux column and the scalar row, whole (grid=(1,))."""
     Mn = M * n
     full = lambda i: (0, 0)
     shapes = (
@@ -819,169 +771,71 @@ def _cycle_out(M: int, n: int, backend: str = "tpu"):
         jax.ShapeDtypeStruct((1, 128), jnp.int32),
     )
     specs = (
-        PK._bs((Mn, n), full, backend=backend),
-        PK._bs((Mn, 1), full, backend=backend),
-        PK._bs((1, 128), full, backend=backend),
+        PK._bs((Mn, n), full, backend="gpu"),
+        PK._bs((Mn, 1), full, backend="gpu"),
+        PK._bs((1, 128), full, backend="gpu"),
     )
     return shapes, specs
 
 
-def _chunk_specs(M: int, n: int, backend: str = "tpu"):
+def _gpu_chunk_specs(M: int, n: int):
     full = lambda i: (0, 0)
     return [
-        PK._bs((M, n), full, backend=backend),   # vals
-        PK._bs((M, 1), full, backend=backend),   # aux
-        PK._bs((M, 1), full, backend=backend),   # valid
-        PK._bs((1,), lambda i: (0,), space="smem", backend=backend),  # best
-    ]
-
-
-def _tiled_out(M: int, n: int, mt: int, two_phase: bool):
-    """Streamed out plumbing: each tile owns its (Mt*n)-row block of the
-    (M*n, n) reservation plus one (8, 128) block of the (G*8, 128)
-    per-tile scalar output.  On the two-phase PFSP grid the out index map pins every
-    phase-0 step to block 0 (``p * i``): no block boundary is crossed
-    before the first real write at step (1, 0), so the phase-0 sweep never
-    flushes an unwritten buffer over the output."""
-    G = M // mt
-    Mtn = mt * n
-    if two_phase:
-        tm = lambda p, i: (p * i, 0)
-    else:
-        tm = lambda i: (i, 0)
-    shapes = (
-        jax.ShapeDtypeStruct((M * n, n), jnp.int32),
-        jax.ShapeDtypeStruct((M * n, 1), jnp.int32),
-        jax.ShapeDtypeStruct((G * 8, 128), jnp.int32),
-    )
-    specs = (
-        pl.BlockSpec((Mtn, n), tm, memory_space=pltpu.VMEM),
-        pl.BlockSpec((Mtn, 1), tm, memory_space=pltpu.VMEM),
-        # One full (8, 128) int32 tile per grid step: Mosaic refuses a
-        # (1, 128) block of a (G, 128) array (second-minor block dim must
-        # be a multiple of 8). Each tile's scalar row is broadcast over
-        # its 8 sublanes; `make_cycle` reads sublane 0.
-        pl.BlockSpec((8, 128), tm, memory_space=pltpu.VMEM),
-    )
-    return shapes, specs
-
-
-def _tiled_chunk_specs(mt: int, n: int, two_phase: bool):
-    """Streamed in plumbing: the (i)-th grid step's BlockSpec index maps
-    fetch pool tile i's rows HBM->VMEM — the pipelined grid prefetches
-    tile i+1 under tile i's compute (the double buffer).  The two-phase
-    PFSP grid re-fetches each tile in phase 1 (bounds are stashed; node
-    fields are cheaper to re-stream than to hold for the whole sweep)."""
-    if two_phase:
-        tile = lambda p, i: (i, 0)
-        smem = lambda p, i: (0,)
-    else:
-        tile = lambda i: (i, 0)
-        smem = lambda i: (0,)
-    return [
-        pl.BlockSpec((mt, n), tile, memory_space=pltpu.VMEM),   # vals
-        pl.BlockSpec((mt, 1), tile, memory_space=pltpu.VMEM),   # aux
-        pl.BlockSpec((mt, 1), tile, memory_space=pltpu.VMEM),   # valid
-        pl.BlockSpec((1,), smem, memory_space=pltpu.SMEM),      # best
+        PK._bs((M, n), full, backend="gpu"),   # vals
+        PK._bs((M, 1), full, backend="gpu"),   # aux
+        PK._bs((M, 1), full, backend="gpu"),   # valid
+        PK._bs((1,), lambda i: (0,), space="smem", backend="gpu"),  # best
     ]
 
 
 @lru_cache(maxsize=None)
-def _nqueens_cycle_call(N: int, g: int, M: int, interpret: bool,
-                        backend: str = "tpu"):
-    # The N-Queens cycle body holds no scratch, so the gpu flavor reuses it
-    # verbatim — only the specs/params change spelling.
-    shapes, out_specs = _cycle_out(M, N, backend)
+def _nqueens_gpu_call(N: int, g: int, M: int, interpret: bool):
+    shapes, out_specs = _gpu_out(M, N)
     return pl.pallas_call(
         partial(_mega_nqueens_kernel, N=N, g=g, M=M),
         out_shape=shapes,
         name="tts_cycle_nqueens",
         grid=(1,),
-        in_specs=_chunk_specs(M, N, backend),
+        in_specs=_gpu_chunk_specs(M, N),
         out_specs=out_specs,
-        compiler_params=PK._compiler_params(backend=backend),
+        compiler_params=PK._compiler_params(backend="gpu"),
         interpret=interpret,
     )
 
 
 @lru_cache(maxsize=None)
-def _nqueens_tiled_call(N: int, g: int, M: int, mt: int, interpret: bool):
-    shapes, out_specs = _tiled_out(M, N, mt, two_phase=False)
-    return pl.pallas_call(
-        partial(_mega_nqueens_tiled_kernel, N=N, g=g, Mt=mt),
-        out_shape=shapes,
-        name="tts_cycle_nqueens",
-        grid=(M // mt,),
-        in_specs=_tiled_chunk_specs(mt, N, two_phase=False),
-        out_specs=out_specs,
-        scratch_shapes=[pltpu.SMEM((4,), jnp.int32)],
-        compiler_params=PK._compiler_params(),
-        interpret=interpret,
-    )
-
-
-@lru_cache(maxsize=None)
-def _lb1_cycle_call(n: int, m: int, M: int, bf16: bool, interpret: bool,
-                    backend: str = "tpu"):
+def _lb1_gpu_call(n: int, m: int, M: int, bf16: bool, interpret: bool):
     full = lambda i: (0, 0)
-    shapes, out_specs = _cycle_out(M, n, backend)
-    kernel = _mega_lb1_kernel_gpu if backend == "gpu" else _mega_lb1_kernel
+    shapes, out_specs = _gpu_out(M, n)
     return pl.pallas_call(
-        partial(kernel, n=n, m=m, M=M, bf16=bf16),
+        partial(_mega_lb1_kernel_gpu, n=n, m=m, M=M, bf16=bf16),
         out_shape=shapes,
         name="tts_cycle_lb1",
         grid=(1,),
-        in_specs=_chunk_specs(M, n, backend) + [
-            PK._bs((n, m), full, backend=backend),
-            PK._bs((1, m), full, backend=backend),
-            PK._bs((1, m), full, backend=backend),
+        in_specs=_gpu_chunk_specs(M, n) + [
+            PK._bs((n, m), full, backend="gpu"),
+            PK._bs((1, m), full, backend="gpu"),
+            PK._bs((1, m), full, backend="gpu"),
         ],
         out_specs=out_specs,
-        scratch_shapes=PK._scratch(backend, pltpu.VMEM((n, M, m), jnp.int32)),
-        compiler_params=PK._compiler_params(backend=backend),
+        compiler_params=PK._compiler_params(backend="gpu"),
         interpret=interpret,
     )
 
 
 @lru_cache(maxsize=None)
-def _lb1_tiled_call(n: int, m: int, M: int, mt: int, bf16: bool,
-                    interpret: bool):
-    G = M // mt
-    full = lambda p, i: (0, 0)
-    shapes, out_specs = _tiled_out(M, n, mt, two_phase=True)
-    return pl.pallas_call(
-        partial(_mega_lb1_tiled_kernel, n=n, m=m, Mt=mt, bf16=bf16),
-        out_shape=shapes,
-        name="tts_cycle_lb1",
-        grid=(2, G),
-        in_specs=_tiled_chunk_specs(mt, n, two_phase=True) + [
-            pl.BlockSpec((n, m), full, memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, m), full, memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, m), full, memory_space=pltpu.VMEM),
-        ],
-        out_specs=out_specs,
-        scratch_shapes=[pltpu.VMEM((n, mt, m), jnp.int32),
-                        pltpu.VMEM((G, mt, n), jnp.int32),
-                        pltpu.SMEM((4,), jnp.int32)],
-        compiler_params=PK._compiler_params(ndims=2),
-        interpret=interpret,
-    )
-
-
-@lru_cache(maxsize=None)
-def _lb2_cycle_call(n: int, m: int, P: int, M: int, pg: int, bf16: bool,
-                    interpret: bool, backend: str = "tpu"):
+def _lb2_gpu_call(n: int, m: int, P: int, M: int, pg: int, bf16: bool,
+                  interpret: bool):
     full = lambda i: (0, 0)
     full3 = lambda i: (0, 0, 0)
-    bs = partial(PK._bs, backend=backend)
-    shapes, out_specs = _cycle_out(M, n, backend)
-    kernel = _mega_lb2_kernel_gpu if backend == "gpu" else _mega_lb2_kernel
+    bs = partial(PK._bs, backend="gpu")
+    shapes, out_specs = _gpu_out(M, n)
     return pl.pallas_call(
-        partial(kernel, n=n, m=m, P=P, M=M, pg=pg, bf16=bf16),
+        partial(_mega_lb2_kernel_gpu, n=n, m=m, P=P, M=M, pg=pg, bf16=bf16),
         out_shape=shapes,
         name="tts_cycle_lb2",
         grid=(1,),
-        in_specs=_chunk_specs(M, n, backend) + [
+        in_specs=_gpu_chunk_specs(M, n) + [
             bs((n, m), full),
             bs((1, m), full),
             # Per-pair table layout matches `_lb2_call` exactly — see the
@@ -996,44 +850,537 @@ def _lb2_cycle_call(n: int, m: int, P: int, M: int, pg: int, bf16: bool,
             bs((P, n, n), full3),
         ],
         out_specs=out_specs,
-        scratch_shapes=PK._scratch(backend, pltpu.VMEM((n, M, m), jnp.int32)),
-        compiler_params=PK._compiler_params(backend=backend),
+        compiler_params=PK._compiler_params(backend="gpu"),
         interpret=interpret,
     )
 
 
+# ---------------------------------------------------------------------------
+# TPU flavor — the pool tiles stream through VMEM and the kernel pushes
+# each tile's survivors into the donated pool by DMA
+# ---------------------------------------------------------------------------
+#
+# One grid step per pool tile (``grid=(G,)``; PFSP ``(2, G)``, see the
+# module docstring); the single-tile form is the same kernel at G == 1.
+# Each emit step compacts its tile at the pool's slot width, packs the
+# live prefix chunk by chunk into the pool's rows (``kq`` nodes to a
+# 128-lane row, `push_pack`), stages it (and the aux column, laid out
+# lane-dense) in the pool dtypes, and writes it at ``size + offs`` in
+# whole chunks: value rows from the 8-row tile (``8*kq`` nodes) the
+# destination starts in, aux in (8, 128) tiles.  The nodes of those first
+# tiles that are already in the pool (live nodes below ``size``, or the
+# previous tile's tail) are read once per cycle at the first step and then
+# carried in VMEM from tile to tile, merged in before the stage.  Nodes
+# past the live prefix land in the reserved M*n headroom (+ PUSH_SLACK):
+# dead by the pool contract.  Ordering rule: a tile's last chunk and the
+# next tile's first share a tile of the pool, so each emit waits for the
+# previous tile's DMAs before it stages or issues anything.
+#
+# SMEM carry (persists across the sequential grid steps):
+#   [0] survivors pushed so far this cycle (the next tile's offset)
+#   [1] cumulative sol_inc          [2] folded incumbent (PFSP phase 0)
+#   [3] pool nodes written           [4]/[5] value/aux DMAs in flight
+#   [6]/[7] first value row / first aux-view row of the DMAs in flight
+# DMA semaphores: [0] value chunks, [1] aux tiles, [2]/[3] head reads.
+
+_CARRY = 8
+
+
+@dataclasses.dataclass(frozen=True)
+class _PushRefs:
+    size: object      # SMEM (1,): the pool size after the pop
+    pv: object        # ANY (rows/kq, kq*W): the value pool, aliased in place
+    pa: object        # ANY (rows/128, 128): the aux pool's view, aliased
+    stage_v: object   # VMEM (rs/kq, kq*W), pool value dtype
+    stage_a: object   # VMEM (ra, 128), pool aux dtype
+    head_v: object    # VMEM (8, kq*W): the row tile the next write starts in
+    head_a: object    # VMEM (8, 128): the aux tile the next write starts in
+    sems: object      # DMA semaphores (4,)
+    carry: object     # SMEM (_CARRY,) int32
+    kq: int           # nodes per pool row (`push_pack`)
+
+    @property
+    def quantum(self) -> int:
+        """Nodes of one 8-row pool tile, the start quantum of a DMA."""
+        return _ROW_TILE * self.kq
+
+    @property
+    def chunk(self) -> int:
+        return _chunk_nodes(self.kq)
+
+    @property
+    def chunk_rows(self) -> int:
+        return self.chunk // self.kq
+
+
+def _vals_chunk(r: _PushRefs, row0, k: int):
+    cr = r.chunk_rows
+    start = pl.multiple_of(row0 + k * cr, _ROW_TILE)
+    return pltpu.make_async_copy(
+        r.stage_v.at[pl.ds(k * cr, cr)],
+        r.pv.at[pl.ds(start, cr)], r.sems.at[0])
+
+
+def _aux_chunk(r: _PushRefs, arow0, k: int):
+    start = pl.multiple_of(arow0 + k * _ROW_TILE, _ROW_TILE)
+    return pltpu.make_async_copy(
+        r.stage_a.at[pl.ds(k * _ROW_TILE, _ROW_TILE)],
+        r.pa.at[pl.ds(start, _ROW_TILE)], r.sems.at[1])
+
+
+def _head_reads(r: _PushRefs):
+    """The pool tiles the cycle's first write starts in (value and aux
+    nodes below ``size`` there are live and go back unchanged)."""
+    s = r.size[0]
+    row0 = pl.multiple_of(s // r.quantum * _ROW_TILE, _ROW_TILE)
+    arow0 = pl.multiple_of(s // _AUX_TILE * _ROW_TILE, _ROW_TILE)
+    return (
+        pltpu.make_async_copy(r.pv.at[pl.ds(row0, _ROW_TILE)], r.head_v,
+                              r.sems.at[2]),
+        pltpu.make_async_copy(r.pa.at[pl.ds(arow0, _ROW_TILE)], r.head_a,
+                              r.sems.at[3]),
+    )
+
+
+def _push_seed(r: _PushRefs):
+    """First grid step: zero the carry and start the head reads (waited
+    at the first emit, so they overlap the first tile's bound)."""
+    for s in range(_CARRY):
+        r.carry[s] = 0
+    for c in _head_reads(r):
+        c.start()
+
+
+def _push_shift(r: _PushRefs):
+    """This tile's first destination node, modulo its 8-row pool tile."""
+    return (r.size[0] + r.carry[0]) % r.quantum
+
+
+def _push_wait(r: _PushRefs):
+    """Wait for the DMAs the previous emit left in flight."""
+    nv, na = r.carry[4], r.carry[5]
+    row0, arow0 = r.carry[6], r.carry[7]
+    for k in range(r.stage_v.shape[0] // r.chunk_rows):
+        @pl.when(k < nv)
+        def _():
+            _vals_chunk(r, row0, k).wait()
+
+    for k in range(r.stage_a.shape[0] // _ROW_TILE):
+        @pl.when(k < na)
+        def _():
+            _aux_chunk(r, arow0, k).wait()
+
+    r.carry[4] = 0
+    r.carry[5] = 0
+
+
+def _aux_lanes(caux, delta, *, ra: int, bf16: bool):
+    """The (rc, 1) aux column laid out for the aux pool's lane-dense
+    (rows/128, 128) view: row ``k`` lands at flat position ``k + delta``
+    of an (ra, 128) block.  Two one-hot MXU operands do it without a
+    reshape — ``A[i, k] = [(k + delta) // 128 == i]`` and
+    ``B[k, l] = caux[k] * [(k + delta) % 128 == l]`` — and each output
+    element sums exactly one aux value or none (exact in bf16 below 256)."""
+    i32, f32 = jnp.int32, jnp.float32
+    rc = caux.shape[0]
+    pos = jax.lax.broadcasted_iota(i32, (ra, rc), 1) + delta
+    a = (jnp.right_shift(pos, 7)
+         == jax.lax.broadcasted_iota(i32, (ra, rc), 0)).astype(f32)
+    posc = jax.lax.broadcasted_iota(i32, (rc, 128), 0) + delta
+    lane = jax.lax.broadcasted_iota(i32, (rc, 128), 1)
+    b = jnp.where(jnp.bitwise_and(posc, 127) == lane, caux.astype(f32), 0.0)
+    return PK._hp_dot(a, b, bf16=bf16).astype(i32)
+
+
+def _pack_rows(x, *, kq: int, rows: int, bf16: bool):
+    """(r, W) node rows laid out as ``rows`` pool rows of ``kq`` slots:
+    node ``k`` goes to row ``k // kq``, lanes ``(k % kq) * W`` on.  Two
+    one-hot matmuls: ``x @ T`` repeats each node in all ``kq`` slots
+    (``T[j, l] = [l % W == j]``), a select keeps it in its own slot, and
+    ``S @ ·`` sums each row's ``kq`` nodes (``S[i, k] = [k // kq == i]``);
+    every output element takes one value (exact in bf16 below 256).
+    ``kq`` 1 is the rows themselves."""
+    if kq == 1:
+        return x
+    i32, f32 = jnp.int32, jnp.float32
+    r, w = x.shape
+    lanes = kq * w
+    sh = w.bit_length() - 1  # W and kq are powers of two
+
+    def iota(shape, d):
+        return jax.lax.broadcasted_iota(i32, shape, d)
+
+    t = jnp.bitwise_and(iota((w, lanes), 1), w - 1) == iota((w, lanes), 0)
+    rep = PK._hp_dot(x.astype(f32), t.astype(f32), bf16=bf16)  # (r, kq*W)
+    own = (jnp.right_shift(iota((r, lanes), 1), sh)
+           == jnp.bitwise_and(iota((r, lanes), 0), kq - 1))
+    rep = jnp.where(own, rep, 0.0)
+    s = (jnp.right_shift(iota((rows, r), 1), kq.bit_length() - 1)
+         == iota((rows, r), 0))
+    return PK._hp_dot(s.astype(f32), rep, bf16=bf16).astype(i32)
+
+
+def _push_emit(r: _PushRefs, rows, caux, cnt, *, first, last, bf16: bool):
+    """Push one tile's compacted survivors into the pool.
+
+    ``rows``/``caux`` come from `_compact_push` with ``shift`` =
+    `_push_shift` at the pool's slot width: row ``k`` is the survivor
+    bound for pool node ``node0 + k`` (``node0`` the first node of the
+    8-row pool tile the destination starts in).  ``first``/``last`` mark
+    the cycle's first and last emit step."""
+    i32 = jnp.int32
+    rc, w = rows.shape
+    kq, ch, cr = r.kq, r.chunk, r.chunk_rows
+    ra = r.stage_a.shape[0]
+    pos = r.size[0] + r.carry[0]  # pool node of this tile's first survivor
+    rv = pos % r.quantum
+    row0 = pos // r.quantum * _ROW_TILE  # its pool row tile
+    ra_off = pos % _AUX_TILE
+    arow0 = pos // _AUX_TILE * _ROW_TILE
+    # The ordering rule (module comment above): the previous tile's
+    # chunks land before this tile refills the staging they read from and
+    # before its first chunk rewrites their tail.
+    _push_wait(r)
+
+    @pl.when(first)
+    def _():
+        for c in _head_reads(r):
+            c.wait()
+
+    lanes = _aux_lanes(caux, ra_off - rv, ra=ra, bf16=bf16)
+    flat = (jax.lax.broadcasted_iota(i32, (_ROW_TILE, 128), 0) * 128
+            + jax.lax.broadcasted_iota(i32, (_ROW_TILE, 128), 1))
+    ahead = jnp.where(flat < ra_off, r.head_a[...].astype(i32),
+                      lanes[:_ROW_TILE])
+    lanes = jnp.concatenate([ahead, lanes[_ROW_TILE:]], axis=0)
+    r.stage_a[...] = lanes.astype(r.stage_a.dtype)
+
+    nv = (rv + cnt + ch - 1) // ch
+    na = (ra_off + cnt + _AUX_TILE - 1) // _AUX_TILE
+    for k in range(r.stage_v.shape[0] // cr):
+        @pl.when(k < nv)
+        def _():
+            packed = _pack_rows(rows[k * ch:min((k + 1) * ch, rc)], kq=kq,
+                                rows=cr, bf16=bf16)
+            if k == 0:
+                # The pool nodes below this tile's first survivor go back
+                # as they were.
+                shape = (_ROW_TILE, packed.shape[1])
+                node = (jax.lax.broadcasted_iota(i32, shape, 0) * kq
+                        + jax.lax.broadcasted_iota(i32, shape, 1) // w)
+                head = jnp.where(node < rv, r.head_v[...].astype(i32),
+                                 packed[:_ROW_TILE])
+                packed = jnp.concatenate([head, packed[_ROW_TILE:]], axis=0)
+            r.stage_v[k * cr:k * cr + packed.shape[0]] = packed.astype(
+                r.stage_v.dtype)
+            _vals_chunk(r, row0, k).start()
+
+    for k in range(ra // _ROW_TILE):
+        @pl.when(k < na)
+        def _():
+            _aux_chunk(r, arow0, k).start()
+
+    r.carry[0] = r.carry[0] + cnt
+    r.carry[3] = r.carry[3] + nv * ch
+    r.carry[4] = nv
+    r.carry[5] = na
+    r.carry[6] = row0
+    r.carry[7] = arow0
+    # The next tile's writes start in the row tile and the aux tile this
+    # tile's live prefix ends in: carry them over as its heads.
+    q = pl.multiple_of((rv + cnt) // r.quantum * _ROW_TILE, _ROW_TILE)
+    r.head_v[...] = r.stage_v[pl.ds(q, _ROW_TILE), :]
+    b = pl.multiple_of((ra_off + cnt) // _AUX_TILE * _ROW_TILE, _ROW_TILE)
+    r.head_a[...] = r.stage_a[pl.ds(b, _ROW_TILE), :]
+
+    @pl.when(last)
+    def _():
+        _push_wait(r)
+
+
+def _mega_nqueens_push_kernel(board_ref, depth_ref, valid_ref, best_ref,
+                              size_ref, pv_in, pa_in, pv_ref, pa_ref,
+                              scal_ref, stage_v, stage_a, head_v, head_a,
+                              sems, carry_ref, *, N: int, g: int, Mt: int,
+                              G: int):
+    del pv_in, pa_in  # aliased to pv_ref / pa_ref
+    w, kq = push_pack(N)
+    r = _PushRefs(size_ref, pv_ref, pa_ref, stage_v, stage_a, head_v,
+                  head_a, sems, carry_ref, kq)
+    i = pl.program_id(0)
+
+    @pl.when(i == 0)
+    def _seed():
+        _push_seed(r)
+
+    board = board_ref[:].astype(jnp.int32)  # (Mt, N)
+    depth = depth_ref[:, 0].astype(jnp.int32)
+    valid = valid_ref[:, 0] != 0
+    best = best_ref[0]
+    with jax.named_scope(BOUND_SCOPE):
+        labels = PK._nqueens_tile_labels(board, depth, N=N, g=g)
+    # The `_NQueensResident` evaluate fold: swap position is the depth.
+    keep = labels & valid[:, None] & (depth < N)[:, None]
+    sol_inc = jnp.sum(valid & (depth == N), dtype=jnp.int32)
+    with jax.named_scope(COMPACT_SCOPE):
+        rows, caux, cnt = _compact_push(board, depth, depth, keep, n=N,
+                                        M=Mt, shift=_push_shift(r),
+                                        width=w)
+    with jax.named_scope(EMIT_SCOPE):
+        _push_emit(r, rows, caux, cnt, first=i == 0, last=i == G - 1,
+                   bf16=N < 256)
+    sol_cum = carry_ref[1] + sol_inc
+    carry_ref[1] = sol_cum
+    scal_ref[:] = _scalar_lanes(carry_ref[0], sol_cum, best, carry_ref[3])
+
+
+def _pfsp_push_cycle(bound, prmu_ref, limit1_ref, valid_ref, best_ref,
+                     r: _PushRefs, scal_ref, lb_ref, *, n: int, Mt: int,
+                     G: int):
+    """The PFSP two-phase grid ``(2, G)``: phase 0 evaluates every tile's
+    bounds into the stash and folds the global leaf-min into the
+    incumbent — no tile may prune before every tile's leaves are folded
+    (PFSP exactness rule); phase 1 re-streams the tiles (bounds come back
+    from the stash, not recomputed), prunes and compacts against the
+    final incumbent, and pushes."""
+    p = pl.program_id(0)
+    i = pl.program_id(1)
+
+    @pl.when((p == 0) & (i == 0))
+    def _seed():
+        _push_seed(r)
+        r.carry[2] = best_ref[0]
+
+    prmu = prmu_ref[:].astype(jnp.int32)
+    limit1 = limit1_ref[:, 0].astype(jnp.int32)
+    valid = valid_ref[:, 0] != 0
+
+    @pl.when(p == 0)
+    def _sweep():
+        with jax.named_scope(BOUND_SCOPE):
+            lb = bound(prmu, limit1)
+        lb_ref[i] = lb
+        r.carry[2] = jnp.minimum(
+            r.carry[2], _pfsp_leaf_min(limit1, valid, lb, n=n, M=Mt))
+
+    @pl.when(p == 1)
+    def _emit():
+        with jax.named_scope(COMPACT_SCOPE):
+            rows, caux, cnt, sol_inc, best = _pfsp_epilogue(
+                prmu, limit1, valid, r.carry[2], lb_ref[i], n=n, M=Mt,
+                shift=_push_shift(r), width=push_pack(n)[0])
+        with jax.named_scope(EMIT_SCOPE):
+            _push_emit(r, rows, caux, cnt, first=i == 0, last=i == G - 1,
+                       bf16=n < 256)
+        sol_cum = r.carry[1] + sol_inc
+        r.carry[1] = sol_cum
+        scal_ref[:] = _scalar_lanes(r.carry[0], sol_cum, best, r.carry[3])
+
+
+def _mega_lb1_push_kernel(prmu_ref, limit1_ref, valid_ref, best_ref,
+                          size_ref, ptm_ref, heads_ref, tails_ref,
+                          pv_in, pa_in, pv_ref, pa_ref, scal_ref,
+                          scan_ref, lb_ref, stage_v, stage_a, head_v, head_a,
+                          sems, carry_ref, *, n: int, m: int, Mt: int,
+                          G: int, bf16: bool):
+    del pv_in, pa_in  # aliased to pv_ref / pa_ref
+
+    def bound(prmu, limit1):
+        return PK._lb1_tile_lb(prmu, limit1, ptm_ref[:].astype(jnp.float32),
+                               heads_ref[:], tails_ref[:], scan_ref,
+                               n=n, m=m, bf16=bf16)
+
+    _pfsp_push_cycle(
+        bound, prmu_ref, limit1_ref, valid_ref, best_ref,
+        _PushRefs(size_ref, pv_ref, pa_ref, stage_v, stage_a, head_v, head_a,
+                  sems, carry_ref, push_pack(n)[1]),
+        scal_ref, lb_ref, n=n, Mt=Mt, G=G)
+
+
+def _mega_lb2_push_kernel(prmu_ref, limit1_ref, valid_ref, best_ref,
+                          size_ref, ptm_ref, heads_ref,
+                          p0_ref, p1_ref, lag_ref, t0_ref, t1_ref,
+                          msel0_ref, msel1_ref, jorder_ref,
+                          pv_in, pa_in, pv_ref, pa_ref, scal_ref,
+                          scan_ref, lb_ref, stage_v, stage_a, head_v, head_a,
+                          sems, carry_ref, *, n: int, m: int, P: int, Mt: int,
+                          pg: int, G: int, bf16: bool):
+    del pv_in, pa_in  # aliased to pv_ref / pa_ref
+
+    def bound(prmu, limit1):
+        return PK._lb2_tile_lb(
+            prmu, limit1, ptm_ref[:].astype(jnp.float32), heads_ref[:],
+            p0_ref, p1_ref, lag_ref, t0_ref, t1_ref, msel0_ref, msel1_ref,
+            jorder_ref, scan_ref, n=n, m=m, P=P, pg=pg, bf16=bf16,
+        ).astype(jnp.int32)
+
+    _pfsp_push_cycle(
+        bound, prmu_ref, limit1_ref, valid_ref, best_ref,
+        _PushRefs(size_ref, pv_ref, pa_ref, stage_v, stage_a, head_v, head_a,
+                  sems, carry_ref, push_pack(n)[1]),
+        scal_ref, lb_ref, n=n, Mt=Mt, G=G)
+
+
+# ---------------------------------------------------------------------------
+# pallas_call factories (TPU flavor)
+# ---------------------------------------------------------------------------
+
+
+def _interpret_mode(interpret: bool, sharded: bool) -> str | None:
+    """How an interpreted push cycle runs: "dma", the TPU interpret mode
+    that models the DMAs and their semaphores (a DMA lands when it is
+    waited on, so a missing wait or an ordering fault shows off-chip too);
+    or "eager" inside the mesh tier's shard_map (``sharded``), where that
+    mode's cross-device barrier deadlocks against the CPU runtime's
+    collectives — the plain interpreter, which lands each DMA as it
+    starts.  None: compiled."""
+    if not interpret:
+        return None
+    return "eager" if sharded else "dma"
+
+
+def _interpret(mode: str | None):
+    if mode is None:
+        return False
+    return pltpu.InterpretParams() if mode == "dma" else True
+
+
+def _push_plumbing(mt: int, n: int, W: int, rows: int, vdt, adt,
+                   two_phase: bool):
+    """(in_specs, out_shape, out_specs, scratch) of a push cycle into a
+    pool of ``rows`` nodes whose value array is ``W`` lanes wide
+    (`push_layout`).
+
+    In: the streamed tile of the popped chunk (the (i)-th grid step's
+    index maps fetch pool tile i HBM->VMEM; the pipelined grid prefetches
+    tile i+1 under tile i's compute; the two-phase PFSP grid re-fetches
+    each tile in phase 1 — node fields are cheaper to re-stream than to
+    hold for the whole sweep), then ``best`` and ``size`` in SMEM.  The
+    two pools go in and out in place (``pl.ANY``, aliased by the caller)
+    with one (1, 128) scalar block, resident across the grid."""
+    if two_phase:
+        tile = lambda p, i: (i, 0)
+        one = lambda p, i: (0,)
+        whole = lambda p, i: (0, 0)
+    else:
+        tile = lambda i: (i, 0)
+        one = lambda i: (0,)
+        whole = lambda i: (0, 0)
+    in_specs = [
+        pl.BlockSpec((mt, n), tile, memory_space=pltpu.VMEM),   # vals
+        pl.BlockSpec((mt, 1), tile, memory_space=pltpu.VMEM),   # aux
+        pl.BlockSpec((mt, 1), tile, memory_space=pltpu.VMEM),   # valid
+        pl.BlockSpec((1,), one, memory_space=pltpu.SMEM),       # best
+        pl.BlockSpec((1,), one, memory_space=pltpu.SMEM),       # size
+    ]
+    kq = push_pack(n)[1]
+    out_shape = (
+        jax.ShapeDtypeStruct((rows // kq, W), vdt),
+        jax.ShapeDtypeStruct((rows // 128, 128), adt),
+        jax.ShapeDtypeStruct((1, 128), jnp.int32),
+    )
+    out_specs = (
+        pl.BlockSpec(memory_space=pl.ANY),
+        pl.BlockSpec(memory_space=pl.ANY),
+        pl.BlockSpec((1, 128), whole, memory_space=pltpu.VMEM),
+    )
+    _, rs, ra = _push_sizes(mt, n)
+    scratch = [
+        pltpu.VMEM((rs // kq, W), vdt),
+        pltpu.VMEM((ra, 128), adt),
+        pltpu.VMEM((_ROW_TILE, W), vdt),
+        pltpu.VMEM((_ROW_TILE, 128), adt),
+        pltpu.SemaphoreType.DMA((4,)),
+        pltpu.SMEM((_CARRY,), jnp.int32),
+    ]
+    return in_specs, out_shape, out_specs, scratch
+
+
 @lru_cache(maxsize=None)
-def _lb2_tiled_call(n: int, m: int, P: int, M: int, mt: int, pg: int,
-                    bf16: bool, interpret: bool):
+def _nqueens_push_call(N: int, g: int, M: int, mt: int, W: int, rows: int,
+                       vdt, adt, interpret: str | None):
+    G = M // mt
+    in_specs, out_shape, out_specs, scratch = _push_plumbing(
+        mt, N, W, rows, vdt, adt, two_phase=False)
+    k = len(in_specs)
+    return pl.pallas_call(
+        partial(_mega_nqueens_push_kernel, N=N, g=g, Mt=mt, G=G),
+        out_shape=out_shape,
+        name="tts_cycle_nqueens",
+        grid=(G,),
+        in_specs=in_specs + [pl.BlockSpec(memory_space=pl.ANY)] * 2,
+        out_specs=out_specs,
+        scratch_shapes=scratch,
+        input_output_aliases={k: 0, k + 1: 1},
+        compiler_params=PK._compiler_params(),
+        interpret=_interpret(interpret),
+    )
+
+
+@lru_cache(maxsize=None)
+def _lb1_push_call(n: int, m: int, M: int, mt: int, bf16: bool, W: int,
+                   rows: int, vdt, adt, interpret: str | None):
+    G = M // mt
+    full = lambda p, i: (0, 0)
+    in_specs, out_shape, out_specs, scratch = _push_plumbing(
+        mt, n, W, rows, vdt, adt, two_phase=True)
+    in_specs = in_specs + [
+        pl.BlockSpec((n, m), full, memory_space=pltpu.VMEM),
+        pl.BlockSpec((1, m), full, memory_space=pltpu.VMEM),
+        pl.BlockSpec((1, m), full, memory_space=pltpu.VMEM),
+    ]
+    k = len(in_specs)
+    return pl.pallas_call(
+        partial(_mega_lb1_push_kernel, n=n, m=m, Mt=mt, G=G, bf16=bf16),
+        out_shape=out_shape,
+        name="tts_cycle_lb1",
+        grid=(2, G),
+        in_specs=in_specs + [pl.BlockSpec(memory_space=pl.ANY)] * 2,
+        out_specs=out_specs,
+        scratch_shapes=[pltpu.VMEM((n, mt, m), jnp.int32),
+                        pltpu.VMEM((G, mt, n), jnp.int32)] + scratch,
+        input_output_aliases={k: 0, k + 1: 1},
+        compiler_params=PK._compiler_params(ndims=2),
+        interpret=_interpret(interpret),
+    )
+
+
+@lru_cache(maxsize=None)
+def _lb2_push_call(n: int, m: int, P: int, M: int, mt: int, pg: int,
+                   bf16: bool, W: int, rows: int, vdt, adt,
+                   interpret: str | None):
     G = M // mt
     full = lambda p, i: (0, 0)
     full3 = lambda p, i: (0, 0, 0)
     smem1 = lambda p, i: (0,)
-    shapes, out_specs = _tiled_out(M, n, mt, two_phase=True)
+    in_specs, out_shape, out_specs, scratch = _push_plumbing(
+        mt, n, W, rows, vdt, adt, two_phase=True)
+    in_specs = in_specs + [
+        pl.BlockSpec((n, m), full, memory_space=pltpu.VMEM),
+        pl.BlockSpec((1, m), full, memory_space=pltpu.VMEM),
+        pl.BlockSpec((P, 1, n), full3, memory_space=pltpu.VMEM),
+        pl.BlockSpec((P, 1, n), full3, memory_space=pltpu.VMEM),
+        pl.BlockSpec((P, 1, n), full3, memory_space=pltpu.VMEM),
+        pl.BlockSpec((P,), smem1, memory_space=pltpu.SMEM),
+        pl.BlockSpec((P,), smem1, memory_space=pltpu.SMEM),
+        pl.BlockSpec((P, 1, m), full3, memory_space=pltpu.VMEM),
+        pl.BlockSpec((P, 1, m), full3, memory_space=pltpu.VMEM),
+        pl.BlockSpec((P, n, n), full3, memory_space=pltpu.VMEM),
+    ]
+    k = len(in_specs)
     return pl.pallas_call(
-        partial(_mega_lb2_tiled_kernel, n=n, m=m, P=P, Mt=mt, pg=pg,
+        partial(_mega_lb2_push_kernel, n=n, m=m, P=P, Mt=mt, pg=pg, G=G,
                 bf16=bf16),
-        out_shape=shapes,
+        out_shape=out_shape,
         name="tts_cycle_lb2",
         grid=(2, G),
-        in_specs=_tiled_chunk_specs(mt, n, two_phase=True) + [
-            pl.BlockSpec((n, m), full, memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, m), full, memory_space=pltpu.VMEM),
-            pl.BlockSpec((P, 1, n), full3, memory_space=pltpu.VMEM),
-            pl.BlockSpec((P, 1, n), full3, memory_space=pltpu.VMEM),
-            pl.BlockSpec((P, 1, n), full3, memory_space=pltpu.VMEM),
-            pl.BlockSpec((P,), smem1, memory_space=pltpu.SMEM),
-            pl.BlockSpec((P,), smem1, memory_space=pltpu.SMEM),
-            pl.BlockSpec((P, 1, m), full3, memory_space=pltpu.VMEM),
-            pl.BlockSpec((P, 1, m), full3, memory_space=pltpu.VMEM),
-            pl.BlockSpec((P, n, n), full3, memory_space=pltpu.VMEM),
-        ],
+        in_specs=in_specs + [pl.BlockSpec(memory_space=pl.ANY)] * 2,
         out_specs=out_specs,
         scratch_shapes=[pltpu.VMEM((n, mt, m), jnp.int32),
-                        pltpu.VMEM((G, mt, n), jnp.int32),
-                        pltpu.SMEM((4,), jnp.int32)],
+                        pltpu.VMEM((G, mt, n), jnp.int32)] + scratch,
+        input_output_aliases={k: 0, k + 1: 1},
         compiler_params=PK._compiler_params(ndims=2),
-        interpret=interpret,
+        interpret=_interpret(interpret),
     )
 
 
@@ -1042,15 +1389,23 @@ def _lb2_tiled_call(n: int, m: int, P: int, M: int, mt: int, pg: int,
 # ---------------------------------------------------------------------------
 
 
-def make_cycle(problem, M: int, device, decision: Decision):
-    """Build ``cycle(vals_c, aux_c, valid, best) -> (rows (Mn, n) i32,
-    caux (Mn,) i32, offs (G,) i32, tree_inc, sol_inc, best)`` — the armed
-    alternate body `engine/resident.py loop_fns` splices in after the pop.
-    ``offs`` carries each tile's cross-tile survivor offset (all-zero on
-    the single-tile path, G == 1): the engine writes tile t's (Mt*n)-row
-    block at ``size + offs[t]``, in tile order, so each write's garbage
-    tail is overwritten by the next tile's rows and the surviving layout
-    is exactly the dense-mode global (parent, slot) order.
+def make_cycle(problem, M: int, device, decision: Decision,
+               sharded: bool = False):
+    """Build ``cycle(pool_vals, pool_aux, size, vals_c, aux_c, valid,
+    best) -> (pool_vals, pool_aux, tree_inc, sol_inc, best, push_rows)``
+    — the armed alternate body `engine/resident.py loop_fns` splices in
+    after the pop (``size`` is the size after it).  The pools come back
+    with the cycle's survivors at nodes ``[size, size + tree_inc)`` in the
+    dense-mode global (parent, slot) order; ``push_rows`` counts the pool
+    nodes the push wrote.  ``sharded``: the cycle runs inside the mesh
+    tier's shard_map (how interpret mode runs it, `_interpret_mode`).
+
+    TPU flavor: the kernel writes the pools itself (aliased, laid out by
+    `push_layout`); the aux pool goes in through its (rows/128, 128) view,
+    a bitcast.  gpu flavor (single tile, no DMA into an aliased pool): the
+    kernel hands back the (Mn, n) int32 rows and aux column and XLA
+    stitches them in at ``size`` in the pool dtypes; ``push_rows`` is the
+    whole Mn reservation it writes.
 
     lb2 note: the kernel always evaluates the UNSTAGED fold, even when the
     two-pass staged evaluator is enabled for the jnp path.  They are
@@ -1059,92 +1414,89 @@ def make_cycle(problem, M: int, device, decision: Decision):
     the staged keep ``open & ~leaf & (lb1 < best) & (lb2 < best)``
     equals the unstaged ``open & ~leaf & (lb2 < best)``.
     """
+    from jax import lax
+
     fam = _family(problem)
     interpret = decision.interpret
-    tiled = decision.grid > 1
     mt = decision.mt or M
-    G = decision.grid
-    # Tiled streaming is TPU-only (resolve refuses it on gpu), so only the
-    # single-tile factories take the flavor.
-    kb = decision.backend
-
-    def _legacy(rows, caux, scal):
-        zero_offs = jnp.zeros((1,), jnp.int32)
-        return (rows, caux[:, 0], zero_offs,
-                scal[0, 0], scal[0, 1], scal[0, 2])
-
-    def _streamed(rows, caux, scal):
-        scal = scal[::8]  # sublane 0 of each tile's (8, 128) block
-        last = scal[G - 1]
-        return (rows, caux[:, 0], scal[:, 0],
-                last[0] + last[1], last[2], last[3])
+    n = int(problem.child_slots)
+    gpu = decision.backend == "gpu"
 
     if fam == "nqueens":
-        if tiled:
-            call = _nqueens_tiled_call(problem.N, problem.g, M, mt,
-                                       interpret)
+        tables = ()
+        if gpu:
+            call = _nqueens_gpu_call(problem.N, problem.g, M, interpret)
         else:
-            call = _nqueens_cycle_call(problem.N, problem.g, M, interpret,
-                                       kb)
-
-        def cycle(vals_c, aux_c, valid, best):
-            rows, caux, scal = call(
-                vals_c, aux_c[:, None], valid.astype(jnp.int32)[:, None],
-                jnp.reshape(best, (1,)),
-            )
-            return (_streamed if tiled else _legacy)(rows, caux, scal)
-
-        return cycle
-
-    t = problem.device_tables()
-    n = problem.jobs
-    m = problem.machines
-    bf16 = bool(getattr(t, "exact_bf16", False))
-    if fam == "lb1":
-        if tiled:
-            call = _lb1_tiled_call(n, m, M, mt, bf16, interpret)
-        else:
-            call = _lb1_cycle_call(n, m, M, bf16, interpret, kb)
-
-        def cycle(vals_c, aux_c, valid, best):
-            rows, caux, scal = call(
-                vals_c, aux_c[:, None], valid.astype(jnp.int32)[:, None],
-                jnp.reshape(best, (1,)),
-                t.ptm_t, t.min_heads[None, :], t.min_tails[None, :],
-            )
-            return (_streamed if tiled else _legacy)(rows, caux, scal)
-
-        return cycle
-
-    # lb2 — Johnson-ordered tables resolved exactly like `pfsp_lb2_bounds`
-    # (device cache when eager, numpy constants under a trace).
-    from . import pfsp_device as PD
-
-    P = t.pairs.shape[0]
-    pg = PD.lb2_kernel_pair_group(P, n)
-    ordered = (t.johnson_ordered_device(pg) if PK._eager_context()
-               else t.johnson_ordered_mp(pg))
-    Pp = ordered.lag_o.shape[0]
-    if tiled:
-        call = _lb2_tiled_call(n, m, Pp, M, mt, pg, bf16, interpret)
+            def push_call(W, rows, vdt, adt, mode):
+                return _nqueens_push_call(problem.N, problem.g, M, mt, W,
+                                          rows, vdt, adt, mode)
     else:
-        call = _lb2_cycle_call(n, m, Pp, M, pg, bf16, interpret, kb)
+        t = problem.device_tables()
+        m = problem.machines
+        bf16 = bool(getattr(t, "exact_bf16", False))
+        if fam == "lb1":
+            tables = (t.ptm_t, t.min_heads[None, :], t.min_tails[None, :])
+            if gpu:
+                call = _lb1_gpu_call(n, m, M, bf16, interpret)
+            else:
+                def push_call(W, rows, vdt, adt, mode):
+                    return _lb1_push_call(n, m, M, mt, bf16, W, rows, vdt,
+                                          adt, mode)
+        else:
+            # lb2 — Johnson-ordered tables resolved exactly like
+            # `pfsp_lb2_bounds` (device cache when eager, numpy constants
+            # under a trace).
+            from . import pfsp_device as PD
 
-    def cycle(vals_c, aux_c, valid, best):
-        rows, caux, scal = call(
-            vals_c, aux_c[:, None], valid.astype(jnp.int32)[:, None],
-            jnp.reshape(best, (1,)),
-            t.ptm_t, t.min_heads[None, :],
-            ordered.p0_o[:, None, :],
-            ordered.p1_o[:, None, :],
-            ordered.lag_o[:, None, :],
-            ordered.tails0,
-            ordered.tails1,
-            ordered.msel0[:, None, :],
-            ordered.msel1[:, None, :],
-            ordered.jorder,
-        )
-        return (_streamed if tiled else _legacy)(rows, caux, scal)
+            P = t.pairs.shape[0]
+            pg = PD.lb2_kernel_pair_group(P, n)
+            ordered = (t.johnson_ordered_device(pg) if PK._eager_context()
+                       else t.johnson_ordered_mp(pg))
+            Pp = ordered.lag_o.shape[0]
+            tables = (
+                t.ptm_t, t.min_heads[None, :],
+                ordered.p0_o[:, None, :], ordered.p1_o[:, None, :],
+                ordered.lag_o[:, None, :], ordered.tails0, ordered.tails1,
+                ordered.msel0[:, None, :], ordered.msel1[:, None, :],
+                ordered.jorder,
+            )
+            if gpu:
+                call = _lb2_gpu_call(n, m, Pp, M, pg, bf16, interpret)
+            else:
+                def push_call(W, rows, vdt, adt, mode):
+                    return _lb2_push_call(n, m, Pp, M, mt, pg, bf16, W, rows,
+                                          vdt, adt, mode)
+
+    def chunk(vals_c, aux_c, valid, best):
+        return (vals_c, aux_c[:, None], valid.astype(jnp.int32)[:, None],
+                jnp.reshape(best, (1,)))
+
+    if gpu:
+        def cycle(pool_vals, pool_aux, size, vals_c, aux_c, valid, best):
+            rows, caux, scal = call(*chunk(vals_c, aux_c, valid, best),
+                                    *tables)
+            with jax.named_scope("tts_push"):
+                pool_vals = lax.dynamic_update_slice(
+                    pool_vals, rows.astype(pool_vals.dtype),
+                    (size, jnp.int32(0)))
+                pool_aux = lax.dynamic_update_slice(
+                    pool_aux, caux[:, 0].astype(pool_aux.dtype), (size,))
+            return (pool_vals, pool_aux, scal[0, 0], scal[0, 1], scal[0, 2],
+                    jnp.int32(M * n))
+
+        return cycle
+
+    mode = _interpret_mode(interpret, sharded)
+
+    def cycle(pool_vals, pool_aux, size, vals_c, aux_c, valid, best):
+        rows = pool_aux.shape[0]
+        call = push_call(pool_vals.shape[1], rows, pool_vals.dtype,
+                         pool_aux.dtype, mode)
+        pool_vals, aux_view, scal = call(
+            *chunk(vals_c, aux_c, valid, best), jnp.reshape(size, (1,)),
+            *tables, pool_vals, jnp.reshape(pool_aux, (rows // 128, 128)))
+        return (pool_vals, jnp.reshape(aux_view, (rows,)), scal[0, 0],
+                scal[0, 1], scal[0, 2], scal[0, 3])
 
     return cycle
 
@@ -1434,3 +1786,68 @@ def _contract_megakernel_single_call(art, cell):
                 f"({[tuple(v.aval.shape) for v in eqn.outvars]})"
             )
     return out
+
+
+def _outer_eqns(jaxpr, out=None):
+    """``(name, eqn)`` of a jaxpr and its sub-jaxprs, short of the bodies
+    of the pallas_calls: the XLA program around the kernels."""
+    from ..analysis.contracts import subjaxprs
+
+    if hasattr(jaxpr, "jaxpr"):  # ClosedJaxpr
+        jaxpr = jaxpr.jaxpr
+    out = [] if out is None else out
+    for eqn in jaxpr.eqns:
+        out.append((eqn.primitive.name, eqn))
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for v in eqn.params.values():
+            for sub in subjaxprs(v):
+                _outer_eqns(sub, out)
+    return out
+
+
+@contract(
+    "megakernel-push-in-kernel",
+    claim="the armed TPU cycle places its survivors itself: outside the "
+          "kernel the loop body holds no dynamic_update_slice on a pool and "
+          "no convert_element_type of an (M*n, ...) array, and the kernel "
+          "aliases both pool operands to its outputs; the gpu flavor keeps "
+          "its single-tile XLA stitch, one dynamic_update_slice per pool",
+    artifact="resident-step",
+    applies=lambda cell: cell is not None
+    and getattr(cell, "megakernel", None) == "force",
+)
+def _contract_megakernel_push_in_kernel(art, cell):
+    prog = art.prog
+    dec = getattr(prog, "megakernel", None)
+    if dec is None or not dec.enabled:
+        return []
+    pools = {tuple(s) for s in prog.pool_shapes()}
+    Mn = prog.M * int(prog.problem.child_slots)
+    eqns = _outer_eqns(art.jaxpr)
+    dus = [e for name, e in eqns if name == "dynamic_update_slice"
+           and tuple(e.invars[0].aval.shape) in pools]
+    out = []
+    if dec.backend == "gpu":
+        if len(dus) != 2:
+            out.append(f"gpu flavor stitches with {len(dus)} pool "
+                       "dynamic_update_slice eqns (expected one per pool)")
+        return out
+    if dus:
+        out.append(f"armed TPU loop body writes a pool with {len(dus)} "
+                   "dynamic_update_slice eqn(s): the kernel must push")
+    wide = [tuple(e.invars[0].aval.shape) for name, e in eqns
+            if name == "convert_element_type" and e.invars[0].aval.ndim
+            and e.invars[0].aval.shape[0] == Mn]
+    if wide:
+        out.append(f"armed TPU loop body converts (M*n, ...) arrays {wide}")
+    calls = [e for name, e in eqns if name == "pallas_call"]
+    want = {tuple(prog.pool_shapes()[0]), (prog.pool_rows // 128, 128)}
+    for e in calls:
+        aliased = {tuple(e.invars[i].aval.shape)
+                   for i, _ in e.params["input_output_aliases"]}
+        if aliased != want:
+            out.append(f"cycle kernel aliases operands {sorted(aliased)}, "
+                       f"not both pools {sorted(want)}")
+    return out
+

@@ -41,7 +41,7 @@ import time
 import numpy as np
 
 from ..engine.device import drain, warmup
-from ..engine.resident import _emit_device_explored, _make_program
+from ..engine.resident import _emit_device_explored, _make_program, node_rows
 from ..engine.results import Diagnostics, PhaseStats, SearchResult
 from ..obs import counters as obs_counters
 from ..obs import events as ev
@@ -146,7 +146,31 @@ class _MeshResidentProgram:
         Mn = M * n
         vals_dt = self.inner.pool_fields[0][1]
         aux_dt = self.inner.pool_fields[1][1]
-        cond, body = self.inner.loop_fns(K)
+        # Per-shard pool arrays as the device holds them (`pool_shapes`:
+        # where the TPU cycle pushes, kq nodes to a lane-dense value row
+        # of W-lane slots, and slack nodes).
+        shape_v, shape_a = self.inner.pool_shapes()
+        W, kq = self.inner.row_width, self.inner.row_pack
+        cond, body = self.inner.loop_fns(K, sharded=True)
+
+        # Node-granular moves on the value pool. With kq nodes to a row
+        # they go through the pool's flat view, where node i is the W
+        # values from i*W on.
+        def front_nodes(pv, k):
+            return pv[: -(-k // kq)]
+
+        def roll_nodes(pv, k):
+            if kq == 1:
+                return jnp.roll(pv, k, axis=0)
+            return jnp.roll(pv.reshape(-1), k * W).reshape(pv.shape)
+
+        def put_nodes(pv, blk, at, k):
+            if kq == 1:
+                return lax.dynamic_update_slice(pv, blk, (at, 0))
+            flat = lax.dynamic_update_slice(
+                pv.reshape(-1), blk.reshape(-1)[: k * W], (at * W,))
+            return flat.reshape(pv.shape)
+
         rounds = self.rounds
         obs = self.inner.obs
         phaseprof = self.inner.phaseprof
@@ -230,14 +254,15 @@ class _MeshResidentProgram:
                     # `Pool_par.chpl:180-191`): the first T rows are a static
                     # slice; rows beyond my_give are garbage the receiver
                     # never marks live.
-                    blk_vals = lax.ppermute(pool_vals[:T], axis, perm)
+                    blk_vals = lax.ppermute(front_nodes(pool_vals, T), axis,
+                                            perm)
                     blk_aux = lax.ppermute(pool_aux[:T], axis, perm)
                     # Remove donated front rows by rolling them to the dead
                     # tail region — gated: the dynamic-shift roll copies the
                     # whole pool, so skip it in the common no-donation case.
                     def _shed(pv, pa):
                         return (
-                            jnp.roll(pv, -my_give, axis=0),
+                            roll_nodes(pv, -my_give),
                             jnp.roll(pa, -my_give, axis=0),
                         )
 
@@ -251,7 +276,7 @@ class _MeshResidentProgram:
                     # same predicate) — an unconditional write could clobber
                     # live rows of a nearly-full pool.
                     def _append(pv, pa):
-                        pv = lax.dynamic_update_slice(pv, blk_vals, (sz, 0))
+                        pv = put_nodes(pv, blk_vals, sz, T)
                         pa = lax.dynamic_update_slice(pa, blk_aux, (sz,))
                         return pv, pa
 
@@ -323,8 +348,8 @@ class _MeshResidentProgram:
         def init(fr_vals, fr_aux, counts, best0):
             # fr_*: (D, F, ...) stride-partitioned warm frontier, small.
             def shard_init(fr_v, fr_a, cnt, b0):
-                pv = jnp.zeros((C, n), vals_dt)
-                pa = jnp.zeros((C,), aux_dt)
+                pv = jnp.zeros(shape_v, vals_dt)
+                pa = jnp.zeros(shape_a, aux_dt)
                 pv = lax.dynamic_update_slice(pv, fr_v[0].astype(vals_dt), (0, 0))
                 pa = lax.dynamic_update_slice(pa, fr_a[0].astype(aux_dt), (0,))
                 return pv, pa, cnt, b0
@@ -345,7 +370,7 @@ class _MeshResidentProgram:
             R = min(2 * m, C)
 
             def shard_res(pv, pa):
-                return pv[None, :R], pa[None, :R]
+                return front_nodes(pv, R)[None], pa[None, :R]
 
             return jax.shard_map(
                 shard_res,
@@ -381,13 +406,20 @@ class _MeshResidentProgram:
         # Stage at the host storage dtypes (TTS_NARROW, problems/base.py):
         # `_init` widens to the device pool dtypes on-chip, so the H2D
         # upload ships narrow bytes.
+        # The value rows go up laid out as the pool holds them
+        # (`pack_rows`: row_pack nodes to a row).
         fields = self.inner.problem.node_fields()
-        fr_v = np.zeros((D, F) + shape_v, dtype=fields[name_v][1])
+        kq = self.inner.row_pack
+        Fv = -(-F // kq) * kq
+        fr_v = np.stack([
+            self.inner.pack_rows(
+                0, b[name_v] if counts[w]
+                else np.zeros((0,) + shape_v, fields[name_v][1]), Fv)
+            for w, b in enumerate(shard_batches)])
         fr_a = np.zeros((D, F), dtype=fields[name_a][1])
         for w, b in enumerate(shard_batches):
             k = counts[w]
             if k:
-                fr_v[w, :k] = b[name_v]
                 fr_a[w, :k] = b[name_a]
         best0 = np.full((D,), best, dtype=np.int32)
         return self._init(fr_v, fr_a, jax.device_put(counts, self._sh_vec), best0)
@@ -444,15 +476,18 @@ class _MeshResidentProgram:
         """Every live node of every shard (saturation-fallback download)."""
         pool_vals, pool_aux, size, _ = state
         sizes = np.asarray(size)
-        rv = np.asarray(pool_vals).reshape(self.D, self.capacity, -1)
-        ra = np.asarray(pool_aux).reshape(self.D, self.capacity)
+        lanes = self.inner.pool_shapes()[0][1]
+        rv = np.asarray(pool_vals).reshape(self.D, -1, lanes)
+        ra = np.asarray(pool_aux).reshape(self.D, self.inner.pool_rows)
         return self._collect(rv, ra, sizes)
 
     def _collect(self, rv, ra, sizes) -> dict:
-        name_v = self.inner.pool_fields[0][0]
+        name_v, _, node_v = self.inner.pool_fields[0]
         name_a = self.inner.pool_fields[1][0]
         fields = self.problem.node_fields()
-        parts_v = [rv[w, : sizes[w]] for w in range(self.D)]
+        width = self.inner.row_width
+        parts_v = [node_rows(rv[w], node_v, width)[: sizes[w]]
+                   for w in range(self.D)]
         parts_a = [ra[w, : sizes[w]] for w in range(self.D)]
         batch = {
             name_v: np.concatenate(parts_v).astype(fields[name_v][1]),

@@ -147,8 +147,11 @@ def compile_stats(problem) -> tuple[int, int]:
 
 def resident_pool_bytes(problem) -> int:
     """Device-resident pool bytes across every program cached on a
-    problem instance — capacity x per-node pool bytes, times the slot
-    (B) / shard (D) count for the batched and mesh programs. Read at
+    problem instance — the pool arrays as the device holds them
+    (`pool_shapes`: capacity x per-node bytes, plus the slot padding of
+    the lane-dense value rows and the slack nodes where the TPU cycle
+    pushes), times the slot (B) / shard (D)
+    count for the batched and mesh programs. Read at
     scrape time for the `tts_serve_pool_bytes{cls}` gauge: the number
     that shrinks when narrow node storage (TTS_NARROW) lands, and the
     per-class HBM footprint an operator sizes co-tenancy against."""
@@ -161,15 +164,14 @@ def resident_pool_bytes(problem) -> int:
         for prog in cache:
             inner = getattr(prog, "inner", prog)
             fields = getattr(inner, "pool_fields", None)
-            cap = getattr(inner, "capacity", None)
-            if fields is None or cap is None:
+            if fields is None:
                 continue
             copies = int(getattr(prog, "B", 0) or getattr(prog, "D", 0) or 1)
-            per_node = sum(
-                int(np.prod(shape, dtype=np.int64)) * np.dtype(dt).itemsize
-                for _name, dt, shape in fields
+            total += copies * sum(
+                int(np.prod(full, dtype=np.int64)) * np.dtype(dt).itemsize
+                for (_name, dt, _shape), full in zip(fields,
+                                                     inner.pool_shapes())
             )
-            total += copies * int(cap) * per_node
     return total
 
 
