@@ -203,6 +203,60 @@ def test_lb2_bf16_gate_refuses_and_falls_back(monkeypatch):
     assert _counts(res) == _counts(seq)
 
 
+def test_lb2_decision_names_its_pair_body(monkeypatch):
+    """An armed lb2 decision names the pair loop it compiles — ``lanes``
+    on the TPU flavor, ``matmul`` on the gpu flavor — and the search
+    result carries it (`SearchResult.lb2_body`, which the banner and the
+    stats line print); the state stays ``on``/``off`` and the other
+    families name no body."""
+    monkeypatch.setenv("TTS_MEGAKERNEL", "force")
+    prob = PFSPProblem(inst=14, lb="lb2", ub=1)
+    dec = MK.resolve(prob, 1024)
+    assert (dec.backend, dec.lb2_body, dec.state) == ("tpu", "lanes", "on")
+    assert MK.resolve(PFSPProblem(inst=14, lb="lb1", ub=1),
+                      1024).lb2_body is None
+    res = resident_search(_mk_problem("pfsp-lb2")(), m=4, M=64, K=8)
+    assert (res.megakernel, res.lb2_body) == ("on", "lanes")
+    res = resident_search(_mk_problem("pfsp-lb1")(), m=4, M=64, K=8)
+    assert (res.megakernel, res.lb2_body) == ("on", None)
+    monkeypatch.setenv("TTS_KERNEL_BACKEND", "gpu")
+    dec = MK.resolve(prob, 1024)
+    assert (dec.backend, dec.lb2_body, dec.state) == ("gpu", "matmul", "on")
+    monkeypatch.setenv("TTS_MEGAKERNEL", "0")
+    dec = MK.resolve(prob, 1024)
+    assert (dec.lb2_body, dec.state) == (None, "off")
+
+
+def test_lb2_lanes_contract_catches_a_matmul_in_the_pair_loop(monkeypatch):
+    """`megakernel-lb2-lanes` holds on the armed lb2 cell of the audit and
+    names a dot_general that a loop of the kernel body picks up."""
+    from tpu_tree_search.analysis import contracts, program_audit
+    from tpu_tree_search.ops import pallas_kernels as PK
+
+    program_audit.load_contracts()
+    cell = program_audit.Cell("pfsp-lb2", pairblock="auto",
+                              megakernel="force")
+    art = program_audit.trace_cell(cell)
+    assert contracts.run_one("megakernel-lb2-lanes", art, cell) == []
+    real = PK._lb2_tile_lb
+
+    def tampered(*args, **kw):
+        lb = real(*args, **kw)
+        eye = jnp.eye(lb.shape[1], dtype=jnp.float32)
+        return jax.lax.fori_loop(
+            0, 2, lambda i, x: jnp.dot(x.astype(jnp.float32), eye)
+            .astype(jnp.int32), lb)
+
+    monkeypatch.setattr(PK, "_lb2_tile_lb", tampered)
+    MK._lb2_push_call.cache_clear()
+    try:
+        art = program_audit.trace_cell(cell)
+    finally:
+        MK._lb2_push_call.cache_clear()
+    msgs = contracts.run_one("megakernel-lb2-lanes", art, cell)
+    assert any("dot_general" in m for m in msgs), msgs
+
+
 def test_family_refusal_lb1d(monkeypatch):
     """lb1_d has no in-kernel bound formulation: force refuses with a
     recorded reason and the search still lands the goldens."""

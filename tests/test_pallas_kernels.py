@@ -89,6 +89,76 @@ def test_lb2_bounds_match_oracle(inst, jobs, machines, bf16):
     )
 
 
+def _lb2_lane_problem(shape: str):
+    if shape == "2m":  # two machines: one Johnson pair
+        rng = np.random.default_rng(29)
+        ptm = rng.integers(1, 100, size=(2, 9)).astype(np.int32)
+        return PFSPProblem(lb="lb2", ub=0, p_times=np.ascontiguousarray(ptm))
+    return PFSPProblem(inst=int(shape[2:]), lb="lb2", ub=1)
+
+
+@pytest.mark.parametrize("shape,entry,tile", [
+    ("ta014", "eval", 128),     # 20x10, P=45 padded to 48
+    ("ta014", "mega", 64),
+    ("ta021", "eval", 64),      # 20x20, P=190 padded to 192
+    ("ta021", "mega", 128),
+    ("ta031", "eval", 128),     # 50x5, P=10 padded to 16
+    ("ta031", "stream", 64),
+    ("2m", "eval", 64),         # P=1
+    ("2m", "stream", 128),
+])
+def test_lb2_lane_body_matches_oracles(shape, entry, tile, monkeypatch):
+    """The TPU lb2 body (`pallas_kernels._lb2_tile_lb`: children as (n, T)
+    slabs with the parents on the lanes, the Johnson order read from SMEM)
+    is bit-equal on every open child slot to the pair-blocked jnp oracle
+    `_lb2_chunk` and to the host bound of the child (`lb2_bound`), through
+    the standalone kernel (``eval``), `megakernel_lb2_bounds` (``mega``)
+    and the Megacore pass (``stream``), at tiles of 64 and 128, with the
+    tables staged through SMEM one pair group at a time.  limit1 runs from
+    -1 to n-2, the pair tables are padded to the pair group, and the tiles
+    hold rows that are not permutations (and pad rows) whose bounds nobody
+    reads."""
+    from tpu_tree_search.ops import megakernel
+    from tpu_tree_search.problems.pfsp import bounds as PB
+
+    monkeypatch.setenv("TTS_TILE_LB2", str(tile))
+    prob = _lb2_lane_problem(shape)
+    t = prob.device_tables()
+    n = prob.jobs
+    P = t.pairs.shape[0]
+    rng = np.random.default_rng(len(shape) * 100 + tile)
+    B = 2 * tile if entry == "stream" else 2 * tile - 8  # a padded tile
+    prmu = np.stack([rng.permutation(n) for _ in range(B)]).astype(np.int32)
+    limit1 = (np.arange(B) % n - 1).astype(np.int32)  # -1 .. n-2
+    bad = rng.choice(B, 6, replace=False)  # rows that are no permutation
+    prmu[bad] = rng.integers(0, n, size=(6, n))
+    pd, ld = jnp.asarray(prmu), jnp.asarray(limit1)
+    if entry == "eval":
+        got = pallas_kernels.pfsp_lb2_bounds(pd, ld, t, interpret=True)
+    elif entry == "mega":
+        got = megakernel.megakernel_lb2_bounds(pd, ld, t, interpret=True)
+    else:
+        got = megakernel.streamed_eval_bounds(prob, pd, ld, mt=tile,
+                                              interpret=True)
+    got = np.asarray(got)
+    want = np.asarray(pfsp_device._lb2_chunk(
+        pd, ld, t.ptm_t, t.min_heads, t.min_tails,
+        t.pairs, t.lags, t.johnson_schedules,
+    ))
+    valid = np.ones(B, bool)
+    valid[bad] = False
+    open_ = (np.arange(n)[None, :] > limit1[:, None]) & valid[:, None]
+    assert np.array_equal(got[open_], want[open_])
+    for i in np.flatnonzero(valid)[[0, 1, n // 2, n - 2]]:
+        li = int(limit1[i])
+        for k in range(li + 1, n):
+            child = prmu[i].copy()
+            child[li + 1], child[k] = child[k], child[li + 1]
+            assert got[i, k] == PB.lb2_bound(
+                prob.lb1_data, prob.lb2_data, child, li + 1, n, 10**9
+            ), (i, k)
+
+
 def test_use_pallas_is_off_on_cpu(monkeypatch):
     monkeypatch.delenv("TTS_PALLAS", raising=False)
     assert pallas_kernels.use_pallas() is False  # tests run on the CPU backend

@@ -12,9 +12,7 @@ results and times come only from ``chip_smoke.py`` on the chip.
 
 The topology is described inside a module fixture — never while a module
 is imported — so every pytest-xdist worker collects the same tests and
-only the worker given this file loads libtpu.  The ta014 lb2 kernel is
-left out: its compile takes about two minutes (``chip_smoke.py`` reports
-it).
+only the worker given this file loads libtpu.
 """
 
 from __future__ import annotations
@@ -88,23 +86,28 @@ def test_lb1_bounds_compiles_ta014(chip):
     assert "%tts_eval_lb1" in txt
 
 
-@pytest.mark.parametrize("fam", ["lb1", "nqueens"])
+@pytest.mark.parametrize("fam", ["lb1", "nqueens", "lb2", "lb2-ta021"])
 def test_megakernel_cycle_compiles_at_auto_tile(fam, chip):
     """The one-kernel cycle at the chunk size and pool tile ``auto``
-    resolves on a TPU for ta014 lb1 (M=1024) and N=14 (M=50000), pushing
-    into pools laid out as the program allocates them: the kernel takes
-    both pools in place (donated, aliased to its outputs) and the program
-    copies neither."""
+    resolves on a TPU for ta014 lb1 and lb2 and ta021 lb2 (M=1024) and
+    N=14 (M=50000), pushing into pools laid out as the program allocates
+    them: the kernel takes both pools in place (donated, aliased to its
+    outputs) and the program copies neither.  lb2 compiles the lane-dense
+    pair loop, its tables streamed through SMEM a pair group at a time:
+    at ta014 on 128-parent tiles (G=8), and no (P, n, n) one-hot order
+    goes into the kernel."""
     from tpu_tree_search.cli import resolve_chunk_size
     from tpu_tree_search.engine.resident import resolve_capacity
     from tpu_tree_search.ops import megakernel as MK
     from tpu_tree_search.problems import NQueensProblem, PFSPProblem
 
-    if fam == "lb1":
-        prob = PFSPProblem(inst=14, lb="lb1", ub=1)
+    if fam != "nqueens":
+        lb, _, inst = fam.partition("-ta")
+        prob = PFSPProblem(inst=int(inst or 14), lb=lb, ub=1)
         M = resolve_chunk_size(None, "pfsp", "device", "resident",
                                backend="tpu")
         vdt = adt = jnp.int8
+        fam = lb
     else:
         prob = NQueensProblem(N=14)
         M = resolve_chunk_size(None, "nqueens", "device", "resident",
@@ -113,11 +116,15 @@ def test_megakernel_cycle_compiles_at_auto_tile(fam, chip):
     dec = MK.resolve(prob, M, chip)
     assert dec.enabled and dec.auto and not dec.interpret, dec
     assert dec.grid > 1  # the streamed form is what auto picks here
+    if fam == "lb2":
+        assert dec.lb2_body == "lanes", dec
+        if prob.machines == 10:
+            assert (M, dec.mt, dec.grid) == (1024, 128, 8)
     n = prob.child_slots
     capacity, M = resolve_capacity(prob, M, None)
     W, kq, rows = MK.push_layout(dec, n, capacity)
     # kq nodes to a 128-lane row, in slots of the next power of two
-    assert (W, kq) == ((32, 4) if fam == "lb1" else (16, 8))
+    assert (W, kq) == ((16, 8) if fam == "nqueens" else (32, 4))
     assert rows % 1024 == 0 and rows >= capacity + MK.PUSH_SLACK
     cycle = MK.make_cycle(prob, M, chip, dec)
     sh = SingleDeviceSharding(chip)
@@ -130,6 +137,8 @@ def test_megakernel_cycle_compiles_at_auto_tile(fam, chip):
     assert "tpu_custom_call" in txt
     assert f"%tts_cycle_{fam}" in txt
     assert "output_to_operand_aliasing" in txt
+    if fam == "lb2":
+        assert f",{n},{n}]" not in txt, "a (P, n, n) operand is in the step"
     pool = f"[{rows // kq},128]"
     assert not [ln for ln in txt.splitlines()
                 if " copy(" in ln and pool in ln.split("=")[1][:40]], \

@@ -848,7 +848,8 @@ def print_results(args, problem, res) -> None:
         if res.megakernel == "on" and res.megakernel_mt:
             form = "tiled" if res.megakernel_tiled else "resident"
             tile = f", {form} Mt={res.megakernel_mt}"
-        print(f"One-kernel cycle: {res.megakernel}{tag}{tile}{why}")
+        body = f", lb2 body {res.lb2_body}" if res.lb2_body else ""
+        print(f"One-kernel cycle: {res.megakernel}{tag}{tile}{body}{why}")
     if res.k_resolved is not None:
         tag = " (auto)" if res.k_auto else ""
         print(f"Dispatch pipeline: depth={res.pipeline_depth}, "
@@ -1012,6 +1013,8 @@ def result_record(args, res) -> dict:
                 if res.megakernel_mt:
                     rec["megakernel_mt"] = res.megakernel_mt
                     rec["megakernel_tiled"] = res.megakernel_tiled
+                if res.lb2_body:
+                    rec["lb2_body"] = res.lb2_body
             if res.roofline is not None:
                 # Phase-profiled runs bank the memory-roofline audit
                 # (obs/roofline.py) — per-phase %-of-memory-bound-peak.

@@ -1156,6 +1156,7 @@ def resident_search(
                 megakernel_reason=program.megakernel.reason,
                 megakernel_mt=program.megakernel.mt or None,
                 megakernel_tiled=program.megakernel.tiled,
+                lb2_body=program.megakernel.lb2_body,
                 kernel_backend=program.kernel_backend,
                 kernel_interpret=program.kernel_interpret,
                 pipeline_depth=depth,
@@ -1259,6 +1260,7 @@ def resident_search(
         megakernel_reason=program.megakernel.reason,
         megakernel_mt=program.megakernel.mt or None,
         megakernel_tiled=program.megakernel.tiled,
+        lb2_body=program.megakernel.lb2_body,
         kernel_backend=program.kernel_backend,
         kernel_interpret=program.kernel_interpret,
         pipeline_depth=depth,

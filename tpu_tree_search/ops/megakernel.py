@@ -56,11 +56,13 @@ Exactness:
   HIGHEST precision — counts are < 2^24, so f32 accumulation is exact;
 * lb1 is the int32 chain of `_lb1_tile_lb` (bit-exact vs `_lb1_chunk` on
   open slots);
-* lb2 rides the max-plus closed form as bf16 MXU matmuls and is only
-  allowed to arm when the instance passes the bf16-exactness gate
-  (`PFSPDeviceTables.exact_bf16`: every processing time < 2^8, so every
-  matmul operand is exactly representable in bf16) — otherwise
-  :func:`resolve` refuses and records why (banner + SearchResult).
+* lb2 is only allowed to arm when the instance passes the bf16-exactness
+  gate (`PFSPDeviceTables.exact_bf16`: every processing time < 2^8, so
+  every matmul operand is exactly representable in bf16) — otherwise
+  :func:`resolve` refuses and records why (banner + SearchResult).  On a
+  TPU its pair loop is int32 scalar-times-slab work on lane-dense child
+  slabs (`pallas_kernels._lb2_tile_lb`); the gpu flavor keeps the
+  max-plus closed form as bf16 MXU matmuls (`_lb2_tile_lb_matmul`).
 
 Routing (`TTS_MEGAKERNEL=auto|0|force`, resolved like the compact auto
 policy): ``auto`` arms only on a real TPU backend and when the per-tile
@@ -246,7 +248,10 @@ class Decision:
     streams the pool through VMEM tile by tile).  ``backend`` is the
     kernel flavor the cycle builds with (`ops/backend.py` — ``gpu`` is
     the Triton lowering, single-tile only: the cross-tile SMEM carry
-    needs the TPU's sequential grid)."""
+    needs the TPU's sequential grid).  ``lb2_body`` names the pair loop an
+    armed lb2 cycle compiles: ``lanes`` on a TPU (`pallas_kernels.
+    _lb2_tile_lb`), ``matmul`` on the gpu flavor; SearchResult.lb2_body
+    carries it to the banner and the stats line."""
 
     enabled: bool
     auto: bool
@@ -255,6 +260,7 @@ class Decision:
     mt: int = 0
     grid: int = 1
     backend: str = "tpu"
+    lb2_body: str | None = None
 
     @property
     def state(self) -> str:
@@ -397,10 +403,14 @@ def _fits(problem, fam: str, M: int, n: int, mt: int | None = None,
         m = problem.machines
         P = problem.lb2_data.pairs.shape[0]
         pg = PD.lb2_kernel_pair_group(P, n)
-        need = PK._model_bytes(
-            t, n, m, extra + PK._lb2_static_extra(n, m, P + (-P) % pg), 3,
-            pair_copies=5, pair_group=pg,
-        )
+        if backend == "gpu":  # the matmul pair body
+            need = PK._model_bytes(
+                t, n, m, extra + PK._lb2_static_extra(n, m, P + (-P) % pg),
+                3, pair_copies=5, pair_group=pg,
+            )
+        else:  # the lane-dense pair body: its tables stream through SMEM
+            need = PK._model_bytes(
+                t, n, m, extra, 3, lane_slabs=PK._lb2_lane_slabs(n, m, pg))
     budget = PK._vmem_budget(backend)
     if need > budget:
         if mt is None:
@@ -445,7 +455,17 @@ def resolve(problem, M: int, device=None, mp_axis: str | None = None,
     and tiled streaming on the gpu flavor, whose cross-tile SMEM carry
     only the TPU's sequential grid can run) hold even under ``force``;
     the remaining gates (native TPU/GPU backend, per-tile memory fit)
-    apply to ``auto`` only."""
+    apply to ``auto`` only.  An armed lb2 decision names its pair body
+    (`Decision.lb2_body`)."""
+    dec = _resolve(problem, M, device, mp_axis, mp_size)
+    if dec.enabled and _family(problem) == "lb2":
+        dec = dataclasses.replace(
+            dec, lb2_body="matmul" if dec.backend == "gpu" else "lanes")
+    return dec
+
+
+def _resolve(problem, M: int, device, mp_axis: str | None,
+             mp_size: int) -> Decision:
     mode = megakernel_mode()
     if mode == "0":
         return Decision(False, False, False, None)
@@ -747,7 +767,7 @@ def _mega_lb2_kernel_gpu(prmu_ref, limit1_ref, valid_ref, best_ref,
     valid = valid_ref[:, 0] != 0
     ptm = ptm_ref[:].astype(jnp.float32)
     with jax.named_scope(BOUND_SCOPE):
-        lb = PK._lb2_tile_lb(
+        lb = PK._lb2_tile_lb_matmul(
             prmu, limit1, ptm, heads_ref[:],
             p0_ref, p1_ref, lag_ref, t0_ref, t1_ref, msel0_ref, msel1_ref,
             jorder_ref, None, n=n, m=m, P=P, pg=pg, bf16=bf16,
@@ -1198,21 +1218,19 @@ def _mega_lb1_push_kernel(prmu_ref, limit1_ref, valid_ref, best_ref,
 
 
 def _mega_lb2_push_kernel(prmu_ref, limit1_ref, valid_ref, best_ref,
-                          size_ref, ptm_ref, heads_ref,
-                          p0_ref, p1_ref, lag_ref, t0_ref, t1_ref,
-                          msel0_ref, msel1_ref, jorder_ref,
+                          size_ref, ptm_ref, heads_ref, slots_ref, pairs_ref,
                           pv_in, pa_in, pv_ref, pa_ref, scal_ref,
                           scan_ref, lb_ref, stage_v, stage_a, head_v, head_a,
-                          sems, carry_ref, *, n: int, m: int, P: int, Mt: int,
-                          pg: int, G: int, bf16: bool):
+                          sems, carry_ref, *lane_scratch, n: int, m: int,
+                          P: int, Mt: int, pg: int, G: int, bf16: bool):
     del pv_in, pa_in  # aliased to pv_ref / pa_ref
+    lanes = PK._Lb2Lanes(slots_ref, pairs_ref, *lane_scratch)
 
     def bound(prmu, limit1):
         return PK._lb2_tile_lb(
             prmu, limit1, ptm_ref[:].astype(jnp.float32), heads_ref[:],
-            p0_ref, p1_ref, lag_ref, t0_ref, t1_ref, msel0_ref, msel1_ref,
-            jorder_ref, scan_ref, n=n, m=m, P=P, pg=pg, bf16=bf16,
-        ).astype(jnp.int32)
+            lanes, scan_ref, n=n, m=m, P=P, pg=pg, bf16=bf16,
+        )
 
     _pfsp_push_cycle(
         bound, prmu_ref, limit1_ref, valid_ref, best_ref,
@@ -1351,21 +1369,12 @@ def _lb2_push_call(n: int, m: int, P: int, M: int, mt: int, pg: int,
                    interpret: str | None):
     G = M // mt
     full = lambda p, i: (0, 0)
-    full3 = lambda p, i: (0, 0, 0)
-    smem1 = lambda p, i: (0,)
     in_specs, out_shape, out_specs, scratch = _push_plumbing(
         mt, n, W, rows, vdt, adt, two_phase=True)
     in_specs = in_specs + [
         pl.BlockSpec((n, m), full, memory_space=pltpu.VMEM),
         pl.BlockSpec((1, m), full, memory_space=pltpu.VMEM),
-        pl.BlockSpec((P, 1, n), full3, memory_space=pltpu.VMEM),
-        pl.BlockSpec((P, 1, n), full3, memory_space=pltpu.VMEM),
-        pl.BlockSpec((P, 1, n), full3, memory_space=pltpu.VMEM),
-        pl.BlockSpec((P,), smem1, memory_space=pltpu.SMEM),
-        pl.BlockSpec((P,), smem1, memory_space=pltpu.SMEM),
-        pl.BlockSpec((P, 1, m), full3, memory_space=pltpu.VMEM),
-        pl.BlockSpec((P, 1, m), full3, memory_space=pltpu.VMEM),
-        pl.BlockSpec((P, n, n), full3, memory_space=pltpu.VMEM),
+        *PK._lb2_lane_specs(P, lambda p, i: (0,)),
     ]
     k = len(in_specs)
     return pl.pallas_call(
@@ -1377,7 +1386,8 @@ def _lb2_push_call(n: int, m: int, P: int, M: int, mt: int, pg: int,
         in_specs=in_specs + [pl.BlockSpec(memory_space=pl.ANY)] * 2,
         out_specs=out_specs,
         scratch_shapes=[pltpu.VMEM((n, mt, m), jnp.int32),
-                        pltpu.VMEM((G, mt, n), jnp.int32)] + scratch,
+                        pltpu.VMEM((G, mt, n), jnp.int32)] + scratch
+        + PK._lb2_lane_scratch(n, m, mt, pg),
         input_output_aliases={k: 0, k + 1: 1},
         compiler_params=PK._compiler_params(ndims=2),
         interpret=_interpret(interpret),
@@ -1453,13 +1463,8 @@ def make_cycle(problem, M: int, device, decision: Decision,
             ordered = (t.johnson_ordered_device(pg) if PK._eager_context()
                        else t.johnson_ordered_mp(pg))
             Pp = ordered.lag_o.shape[0]
-            tables = (
-                t.ptm_t, t.min_heads[None, :],
-                ordered.p0_o[:, None, :], ordered.p1_o[:, None, :],
-                ordered.lag_o[:, None, :], ordered.tails0, ordered.tails1,
-                ordered.msel0[:, None, :], ordered.msel1[:, None, :],
-                ordered.jorder,
-            )
+            tables = (t.ptm_t, t.min_heads[None, :],
+                      *PK.lb2_kernel_tables(ordered, pg, decision.backend))
             if gpu:
                 call = _lb2_gpu_call(n, m, Pp, M, pg, bf16, interpret)
             else:
@@ -1521,34 +1526,10 @@ def _eval_lb1_kernel(prmu_ref, limit1_ref, ptm_ref, heads_ref, tails_ref,
         scan_ref, n=n, m=m, bf16=bf16)
 
 
-def _eval_lb2_kernel(prmu_ref, limit1_ref, ptm_ref, heads_ref,
-                     p0_ref, p1_ref, lag_ref, t0_ref, t1_ref,
-                     msel0_ref, msel1_ref, jorder_ref,
-                     out_ref, scan_ref,
-                     *, n: int, m: int, P: int, pg: int, bf16: bool):
-    out_ref[:] = PK._lb2_tile_lb(
-        prmu_ref[:].astype(jnp.int32), limit1_ref[:, 0].astype(jnp.int32),
-        ptm_ref[:].astype(jnp.float32), heads_ref[:],
-        p0_ref, p1_ref, lag_ref, t0_ref, t1_ref, msel0_ref, msel1_ref,
-        jorder_ref, scan_ref, n=n, m=m, P=P, pg=pg, bf16=bf16,
-    ).astype(jnp.int32)
-
-
 def _eval_lb1_kernel_gpu(prmu_ref, limit1_ref, ptm_ref, heads_ref, tails_ref,
                          out_ref, *, n: int, m: int, bf16: bool):
     _eval_lb1_kernel(prmu_ref, limit1_ref, ptm_ref, heads_ref, tails_ref,
                      out_ref, None, n=n, m=m, bf16=bf16)
-
-
-def _eval_lb2_kernel_gpu(prmu_ref, limit1_ref, ptm_ref, heads_ref,
-                         p0_ref, p1_ref, lag_ref, t0_ref, t1_ref,
-                         msel0_ref, msel1_ref, jorder_ref,
-                         out_ref, *, n: int, m: int, P: int, pg: int,
-                         bf16: bool):
-    _eval_lb2_kernel(prmu_ref, limit1_ref, ptm_ref, heads_ref,
-                     p0_ref, p1_ref, lag_ref, t0_ref, t1_ref,
-                     msel0_ref, msel1_ref, jorder_ref,
-                     out_ref, None, n=n, m=m, P=P, pg=pg, bf16=bf16)
 
 
 @lru_cache(maxsize=None)
@@ -1586,42 +1567,6 @@ def _eval_lb1_call(n: int, m: int, B: int, mt: int, bf16: bool,
             bs((n, m), full),
             bs((1, m), full),
             bs((1, m), full),
-        ],
-        out_specs=bs((mt, n), tm),
-        scratch_shapes=PK._scratch(backend,
-                                   pltpu.VMEM((n, mt, m), jnp.int32)),
-        compiler_params=PK._compiler_params(parallel=True, backend=backend),
-        interpret=interpret,
-    )
-
-
-@lru_cache(maxsize=None)
-def _eval_lb2_call(n: int, m: int, P: int, B: int, mt: int, pg: int,
-                   bf16: bool, interpret: bool, backend: str = "tpu"):
-    tm = lambda i: (i, 0)
-    full = lambda i: (0, 0)
-    full3 = lambda i: (0, 0, 0)
-    smem1 = lambda i: (0,)
-    bs = partial(PK._bs, backend=backend)
-    kernel = _eval_lb2_kernel_gpu if backend == "gpu" else _eval_lb2_kernel
-    return pl.pallas_call(
-        partial(kernel, n=n, m=m, P=P, pg=pg, bf16=bf16),
-        out_shape=jax.ShapeDtypeStruct((B, n), jnp.int32),
-        name="tts_eval_lb2",
-        grid=(B // mt,),
-        in_specs=[
-            bs((mt, n), tm),
-            bs((mt, 1), tm),
-            bs((n, m), full),
-            bs((1, m), full),
-            bs((P, 1, n), full3),
-            bs((P, 1, n), full3),
-            bs((P, 1, n), full3),
-            bs((P,), smem1, space="smem"),
-            bs((P,), smem1, space="smem"),
-            bs((P, 1, m), full3),
-            bs((P, 1, m), full3),
-            bs((P, n, n), full3),
         ],
         out_specs=bs((mt, n), tm),
         scratch_shapes=PK._scratch(backend,
@@ -1672,18 +1617,18 @@ def streamed_eval_bounds(problem, vals, aux, mt: int | None = None,
     ordered = (t.johnson_ordered_device(pg) if PK._eager_context()
                else t.johnson_ordered_mp(pg))
     Pp = ordered.lag_o.shape[0]
-    call = _eval_lb2_call(n, m, Pp, B, mt, pg, bf16, interpret, kb)
+    # The carry-free lb2 pass: the standalone kernel at tile ``mt`` with
+    # every grid axis Megacore-parallel.
+    call = PK._lb2_call(n, m, Pp, B, mt, interpret, bf16, pg, kb,
+                        parallel=True)
     return call(vals_c, aux_c, t.ptm_t, t.min_heads[None, :],
-                ordered.p0_o[:, None, :], ordered.p1_o[:, None, :],
-                ordered.lag_o[:, None, :], ordered.tails0, ordered.tails1,
-                ordered.msel0[:, None, :], ordered.msel1[:, None, :],
-                ordered.jorder)
+                *PK.lb2_kernel_tables(ordered, pg, kb))
 
 
 def megakernel_lb2_bounds(prmu, limit1, tables, interpret: bool | None = None):
-    """The lb2 bound values the megakernel arms with, as a standalone (B, n)
-    call — the bf16 max-plus MXU formulation over the shared
-    `_lb2_tile_lb` body.  The bf16-exactness gate test bit-compares this
+    """The lb2 bound values the TPU megakernel arms with, as a standalone
+    (B, n) call — the shared `_lb2_tile_lb` body with the prologue's bf16
+    one-hot gathers.  The bf16-exactness gate test bit-compares this
     against the f32 pair-blocked oracle (`pfsp_device._lb2_chunk`) on real
     Taillard instances; a mismatch means :func:`resolve`'s gate is wrong
     and the kernel must refuse to arm."""
@@ -1851,3 +1796,58 @@ def _contract_megakernel_push_in_kernel(art, cell):
                        f"not both pools {sorted(want)}")
     return out
 
+
+def _loop_bodies(jaxpr, out=None):
+    """The body jaxprs of every ``scan``/``while`` inside ``jaxpr``, the
+    loops nested in other loops included."""
+    from ..analysis.contracts import prim_eqns, subjaxprs
+
+    out = [] if out is None else out
+    for name, eqn in prim_eqns(jaxpr):
+        if name in ("scan", "while"):
+            for v in eqn.params.values():
+                out.extend(subjaxprs(v))
+    return out
+
+
+@contract(
+    "megakernel-lb2-lanes",
+    claim="the armed TPU lb2 cycle runs the lane-dense pair loop: the "
+          "decision names it (lanes), the kernel takes no (P, n, n) "
+          "one-hot order operand, and no loop inside it holds a "
+          "dot_general or a reduction along the lanes",
+    artifact="resident-step",
+    applies=lambda cell: cell is not None
+    and getattr(cell, "megakernel", None) == "force"
+    and cell.family == "pfsp-lb2",
+)
+def _contract_megakernel_lb2_lanes(art, cell):
+    from ..analysis.contracts import prim_eqns
+
+    prog = art.prog
+    dec = getattr(prog, "megakernel", None)
+    if dec is None or not dec.enabled or dec.backend == "gpu":
+        return []
+    out = []
+    if dec.lb2_body != "lanes":
+        out.append(f"armed TPU lb2 decision names body {dec.lb2_body!r}, "
+                   "not lanes")
+    n = int(prog.problem.child_slots)
+    for name, e in _outer_eqns(art.jaxpr):
+        if name != "pallas_call":
+            continue
+        cubes = [tuple(v.aval.shape) for v in e.invars
+                 if v.aval.ndim == 3 and tuple(v.aval.shape[1:]) == (n, n)]
+        if cubes:
+            out.append(f"cycle kernel takes (P, n, n) operands {cubes}")
+        for body in _loop_bodies(e.params["jaxpr"]):
+            for pname, eqn in prim_eqns(body):
+                if pname == "dot_general":
+                    out.append("a loop in the cycle kernel holds a "
+                               "dot_general")
+                elif pname.startswith("reduce_") and eqn.invars[0].aval.ndim \
+                        and eqn.invars[0].aval.ndim - 1 in eqn.params.get(
+                            "axes", ()):
+                    out.append(f"a loop in the cycle kernel holds a lane "
+                               f"reduction ({pname})")
+    return sorted(set(out))

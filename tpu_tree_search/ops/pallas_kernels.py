@@ -27,6 +27,7 @@ processes (the CI parity path).
 
 from __future__ import annotations
 
+import dataclasses
 import os
 from functools import lru_cache, partial
 
@@ -197,22 +198,25 @@ def _r128(x: int) -> int:
 
 def _model_bytes(t: int, n: int, m: int, extra_bytes: int,
                  tn2_copies: int, pair_copies: int = 0,
-                 pair_group: int = 1) -> int:
+                 pair_group: int = 1, lane_slabs: int = 0) -> int:
     """The kernels' modeled VMEM footprint at batch tile ``t`` — the single
     source of truth shared by the tile chooser and the routing gate.
     ``tn2_copies`` counts the shared (T, n, n)-class f32 live values
-    (one-hot + reshape copies); ``pair_copies`` the per-pair ones (the pair
-    body's u_o/mp0/mp1/cum0/suf1), charged once per member of the unrolled
-    pair group — the extra pair axis of the blocked lb2 kernels
-    (conservative: Mosaic may overlap the unrolled bodies' temporaries, so
-    the model assumes they are all live). ``extra_bytes`` adds
-    tile-independent residents (lb2's per-pair tables)."""
+    (one-hot + reshape copies); ``pair_copies`` the per-pair ones (the
+    matmul pair body's u_o/mp0/mp1/cum0/suf1), charged once per member of
+    the unrolled pair group — the extra pair axis of the blocked lb2
+    kernels (conservative: Mosaic may overlap the unrolled bodies'
+    temporaries, so the model assumes they are all live). ``lane_slabs``
+    counts (n, T) int32 slabs, the parents on the lanes (the TPU lb2 pair
+    loop, `_lb2_lane_slabs`). ``extra_bytes`` adds tile-independent
+    residents (lb2's per-pair tables)."""
     tn2 = (tn2_copies + pair_copies * pair_group) * t * _r8(n) * _r128(n) * 4
     oh_nt = n * _r8(t) * _r128(n) * 4
     scan = n * _r8(t) * _r128(m) * 4
     ptg = t * _r8(n) * _r128(m) * 4
     chains = 2 * m * t * _r128(n) * 4
-    return tn2 + oh_nt + scan + ptg + chains + extra_bytes
+    slabs = lane_slabs * _r8(n) * _r128(t) * 4
+    return tn2 + oh_nt + scan + ptg + chains + slabs + extra_bytes
 
 
 def _vmem_budget(backend: str = "tpu") -> int:
@@ -221,7 +225,8 @@ def _vmem_budget(backend: str = "tpu") -> int:
 
 def _auto_tile(n: int, m: int, default: int, extra_bytes: int = 0,
                tn2_copies: int = 3, pair_copies: int = 0,
-               pair_group: int = 1, backend: str = "tpu") -> int:
+               pair_group: int = 1, backend: str = "tpu",
+               lane_slabs: int = 0) -> int:
     """Shrink the batch tile until the kernel's modeled memory footprint
     fits the backend's budget (`_vmem_limit_bytes`).
 
@@ -234,7 +239,8 @@ def _auto_tile(n: int, m: int, default: int, extra_bytes: int = 0,
     budget = _vmem_budget(backend)
     tile = default
     while tile > 8 and _model_bytes(tile, n, m, extra_bytes, tn2_copies,
-                                    pair_copies, pair_group) > budget:
+                                    pair_copies, pair_group,
+                                    lane_slabs) > budget:
         # Halve, then align down to the sublane quantum (a non-power-of-two
         # env override must not walk below the floor or mis-align the
         # (tile, n) BlockSpec).
@@ -244,14 +250,15 @@ def _auto_tile(n: int, m: int, default: int, extra_bytes: int = 0,
 
 def _auto_tile_fits(n: int, m: int, default: int, extra_bytes: int = 0,
                     tn2_copies: int = 3, pair_copies: int = 0,
-                    pair_group: int = 1, backend: str = "tpu") -> bool:
+                    pair_group: int = 1, backend: str = "tpu",
+                    lane_slabs: int = 0) -> bool:
     """True iff the kernel fits the memory model even at the smallest tile
     — the routing gate: shapes that do not fit must stay on the jnp path
     instead of dying inside a Mosaic VMEM OOM."""
     tile = _auto_tile(n, m, default, extra_bytes, tn2_copies, pair_copies,
-                      pair_group, backend)
+                      pair_group, backend, lane_slabs)
     return _model_bytes(tile, n, m, extra_bytes, tn2_copies, pair_copies,
-                        pair_group) <= _vmem_budget(backend)
+                        pair_group, lane_slabs) <= _vmem_budget(backend)
 
 
 def _lb2_static_extra(n: int, m: int, P: int) -> int:
@@ -264,17 +271,35 @@ def _lb2_static_extra(n: int, m: int, P: int) -> int:
 # Tile defaults: lb1 64 and lb1d 256 are MEASURED on the real v5e
 # (docs/HW_VALIDATION.md; lb1 at 128 compiled >270s — Mosaic compile time
 # grows superlinearly with tile). The lb2 family is not hardware-measured
-# yet, and it is a strictly bigger kernel (190-pair fori_loop, per-pair
-# tables), so its defaults start in the compile-time-safe class lb1 proved
-# (64): a first-window probe that compiles beats a faster tile that times
-# out. scripts/tile_sweep.py re-measures per (kernel, tile) so the
-# defaults can be raised with data.
+# yet; the matmul flavors start in the compile-time-safe class lb1 proved
+# (64). The TPU lb2 kernel ("lb2") puts the tile's parents on the 128
+# lanes of its pair loop (`_lb2_tile_lb`), so its tile is 128: the width
+# that fills them. Its pair loop holds no (T, n, n) value and its tables
+# stream through SMEM; `_lb2_lane_slabs` charges its (n, T) slabs. The Triton
+# flavor keeps the matmul pair body ("lb2_matmul", `_model_key`).
+# scripts/tile_sweep.py re-measures per (kernel, tile) so the defaults can
+# be raised with data.
 _KERNEL_MODEL = {
     "lb1": ("TTS_TILE_LB1", 64, 3, False, 0),
     "lb1d": ("TTS_TILE_LB1D", 256, 3, False, 0),
-    "lb2": ("TTS_TILE_LB2", 64, 3, True, 5),
+    "lb2": ("TTS_TILE_LB2", 128, 3, False, 0),
+    "lb2_matmul": ("TTS_TILE_LB2", 64, 3, True, 5),
     "lb2self": ("TTS_TILE_LB2SELF", 64, 1, True, 5),
 }
+
+
+def _model_key(kernel: str, backend: str) -> str:
+    """The `_KERNEL_MODEL` entry of ``kernel`` on ``backend``: the Triton
+    lb2 kernel keeps the matmul pair body (no SMEM, no scratch there)."""
+    return "lb2_matmul" if kernel == "lb2" and backend == "gpu" else kernel
+
+
+def _lb2_lane_slabs(n: int, m: int, pg: int) -> int:
+    """(n, T) int32 slabs live in the TPU lb2 pair loop: the m staged
+    child-front columns and the n free-job flag slabs (VMEM scratch), two
+    chains per unrolled pair, and the running max, the transposed
+    permutation, the open-slot mask and one temporary."""
+    return m + n + 2 * pg + 4
 
 
 def _resolve_pair_group(kernel: str, n: int, P: int | None,
@@ -292,10 +317,15 @@ def _resolve_pair_group(kernel: str, n: int, P: int | None,
     return 1
 
 
-def _kernel_tile_args(kernel: str, n: int, m: int, P: int | None):
-    env, default, copies, pairwise, pair_copies = _KERNEL_MODEL[kernel]
+def _kernel_tile_args(kernel: str, n: int, m: int, P: int | None,
+                      pg: int, backend: str):
+    key = _model_key(kernel, backend)
+    env, default, copies, pairwise, pair_copies = _KERNEL_MODEL[key]
     extra = _lb2_static_extra(n, m, P) if pairwise else 0
-    return _env_tile(env, default), extra, copies, pair_copies
+    slabs = _lb2_lane_slabs(n, m, pg) if key == "lb2" else 0
+    return dict(default=_env_tile(env, default), extra_bytes=extra,
+                tn2_copies=copies, pair_copies=pair_copies, pair_group=pg,
+                backend=backend, lane_slabs=slabs)
 
 
 def effective_tile(kernel: str, n: int, m: int, P: int | None = None,
@@ -306,22 +336,17 @@ def effective_tile(kernel: str, n: int, m: int, P: int | None = None,
     shared by the feasibility gates, the kernel callers, and
     scripts/tile_sweep.py so the model constants live in exactly one
     place."""
-    default, extra, copies, pair_copies = _kernel_tile_args(kernel, n, m, P)
     pg = _resolve_pair_group(kernel, n, P, pair_group)
-    tile = _auto_tile(n, m, default, extra_bytes=extra, tn2_copies=copies,
-                      pair_copies=pair_copies, pair_group=pg,
-                      backend=backend)
+    tile = _auto_tile(n, m, **_kernel_tile_args(kernel, n, m, P, pg, backend))
     return tile if batch is None else min(tile, batch)
 
 
 def _kernel_feasible(kernel: str, n: int, m: int, P: int | None,
                      pair_group: int | None = None,
                      backend: str = "tpu") -> bool:
-    default, extra, copies, pair_copies = _kernel_tile_args(kernel, n, m, P)
     pg = _resolve_pair_group(kernel, n, P, pair_group)
-    return _auto_tile_fits(n, m, default, extra_bytes=extra,
-                           tn2_copies=copies, pair_copies=pair_copies,
-                           pair_group=pg, backend=backend)
+    return _auto_tile_fits(n, m,
+                           **_kernel_tile_args(kernel, n, m, P, pg, backend))
 
 
 def lb1_kernel_feasible(n: int, m: int, backend: str = "tpu") -> bool:
@@ -675,15 +700,173 @@ def pfsp_lb1_d_bounds(
     )
 
 
-def _lb2_tile_lb(
+#: Johnson slots one iteration of the TPU lb2 slot loop runs, at most
+#: (`_lb2_slot_unroll`).  The unrolled body is traced in Python at every
+#: program build, so a full unroll costs set-up time on every run.
+_LB2_SLOT_UNROLL = 4
+
+
+def _lb2_group_stride(n: int, pg: int) -> int:
+    """int32 words between pair groups in the TPU lb2 kernel's HBM slot
+    table and halves of its SMEM buffer: a group's 4·n·pg words rounded up
+    to the 1024-word tile Mosaic slices a 1-D HBM array on."""
+    return _round_up(pg * 4 * n, 1024)
+
+
+def _lb2_slot_unroll(n: int) -> int:
+    """Johnson slots per iteration of the TPU lb2 slot loop: the largest
+    divisor of ``n`` up to `_LB2_SLOT_UNROLL`."""
+    return max(d for d in range(1, min(n, _LB2_SLOT_UNROLL) + 1)
+               if n % d == 0)
+
+
+@dataclasses.dataclass(frozen=True)
+class _Lb2Lanes:
+    """The refs of the TPU lb2 pair loop (`_lb2_tile_lb`).
+
+    ``slots`` (groups*stride,) int32 in HBM: pair q's Johnson slot t at
+    ``q*4n + f*n + t`` for f = job, p0, p1, lag within its pair group,
+    group g at ``g * stride`` (`_lb2_group_stride`), DMA'd into ``buf``
+    (2*stride,) SMEM half g % 2 under ``sems`` while the group before it
+    runs.  ``pairs`` (P*4,) int32 SMEM: ``4q + f`` for f = machine 0,
+    machine 1, tail 0, tail 1.  ``cf`` (m, n, T) and ``u`` (n, n, T) int32
+    VMEM scratch: the child fronts by machine and the free-job flags by
+    job, child slot on the sublanes and the tile's parents on the lanes."""
+
+    slots: object
+    pairs: object
+    cf: object
+    u: object
+    buf: object
+    sems: object
+
+
+def _lb2_lane_specs(P: int, index):
+    """in_specs of the TPU lb2 kernel's two tables; ``index`` is the
+    grid's constant index map of a 1-D block."""
+    return [pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec((P * 4,), index, memory_space=pltpu.SMEM)]
+
+
+def _lb2_lane_scratch(n: int, m: int, t: int, pg: int):
+    """scratch_shapes of the TPU lb2 pair loop at tile ``t``, in
+    `_Lb2Lanes` order after ``pairs``."""
+    return [pltpu.VMEM((m, n, t), jnp.int32), pltpu.VMEM((n, n, t), jnp.int32),
+            pltpu.SMEM((2 * _lb2_group_stride(n, pg),), jnp.int32),
+            pltpu.SemaphoreType.DMA((2,))]
+
+
+def _lb2_tile_lb(prmu, limit1, ptm, heads, lanes: _Lb2Lanes, scan_ref,
+                 *, n: int, m: int, P: int, pg: int = 1, bf16: bool = False):
+    """(T, n) int32 lb2 bound of every child in the tile — the TPU body of
+    `_lb2_kernel`, shared with the one-kernel cycle (`ops/megakernel.py`).
+
+    After the shared prologue (`_tile_parent_state`) the tile is turned
+    once into slabs with the child slot k on the sublanes and the T
+    parents on the lanes: the m child-front columns into ``lanes.cf`` and
+    the free-job flags ``U[j][k, b]`` (job j open in parent b and not the
+    job child k schedules) into ``lanes.u``.  Each pair then runs the
+    reference's Johnson scan (`c_bound_johnson.c:190-209`) slot by slot
+    (`_lb2_slot_unroll` slots to a loop iteration), reading the job and
+    its times from SMEM, where each pair group's tables arrive by DMA while
+    the group before it runs:
+
+        tmp0 += p0[q, t] * u_j
+        tmp1  = u_j ? max(tmp1, tmp0 + lag[q, t]) + p1[q, t] : tmp1
+
+    on (n, T) int32 slabs — elementwise work, no matmul, no lane
+    reduction — with the ``pg`` pairs of a group interleaved, and folds
+    ``max(tmp1 + tail1, tmp0 + tail0)`` into a running (n, T) max that is
+    transposed back once.  The early exit (`c_bound_johnson.c:231-234`) is
+    dropped as in `pfsp_device._lb2_chunk`; the bound is bit-equal to it
+    on open slots."""
+    T = prmu.shape[0]
+    i32 = jnp.int32
+    S = 4 * n  # one pair's slot table
+    groups = P // pg
+    stride = lanes.buf.shape[0] // 2
+
+    def fetch(g, half):
+        return pltpu.make_async_copy(
+            lanes.slots.at[pl.ds(g * stride, stride)],
+            lanes.buf.at[pl.ds(half * stride, stride)],
+            lanes.sems.at[half])
+
+    fetch(0, 0).start()  # the first group's tables arrive behind the prologue
+    _, _, _, _, cf = _tile_parent_state(
+        prmu, limit1, ptm, heads, scan_ref, n, m, bf16
+    )
+    for i in range(m):
+        lanes.cf[i] = cf[i].T
+    job = prmu.T  # (n, T): the job child k schedules
+    slot = jax.lax.broadcasted_iota(i32, (T, n), 1)
+    free = (slot > limit1[:, None]).astype(i32).T  # (n, T): slot k open
+
+    def flags(j, carry):
+        eq = job == j
+        open_j = jnp.max(jnp.where(eq, free, 0), axis=0, keepdims=True)
+        lanes.u[j] = jnp.where(eq, 0, open_j)
+        return carry
+
+    jax.lax.fori_loop(0, n, flags, 0)
+
+    def group_body(g, lb):
+        half = g % 2
+        fetch(g, half).wait()
+
+        @pl.when(g + 1 < groups)
+        def _():
+            fetch(g + 1, 1 - half).start()
+
+        tab, base = lanes.buf, half * stride
+        q0 = g * pg
+        chains = tuple(
+            (lanes.cf[lanes.pairs[4 * (q0 + j)]],
+             lanes.cf[lanes.pairs[4 * (q0 + j) + 1]])
+            for j in range(pg)
+        )
+
+        def step(t, chains):
+            out = []
+            for j, (tmp0, tmp1) in enumerate(chains):
+                r = base + j * S + t
+                u = lanes.u[tab[r]] != 0
+                tmp0 = tmp0 + jnp.where(u, tab[r + n], 0)
+                tmp1 = jnp.where(
+                    u, jnp.maximum(tmp1, tmp0 + tab[r + 3 * n]) + tab[r + 2 * n],
+                    tmp1)
+                out.append((tmp0, tmp1))
+            return tuple(out)
+
+        k = _lb2_slot_unroll(n)
+
+        def steps(c, chains):
+            for i in range(k):
+                chains = step(c * k + i, chains)
+            return chains
+
+        chains = jax.lax.fori_loop(0, n // k, steps, chains)
+        for j, (tmp0, tmp1) in enumerate(chains):
+            q = q0 + j
+            lb = jnp.maximum(lb, jnp.maximum(tmp1 + lanes.pairs[4 * q + 3],
+                                             tmp0 + lanes.pairs[4 * q + 2]))
+        return lb
+
+    lb = jax.lax.fori_loop(0, groups, group_body, jnp.zeros((n, T), i32))
+    return lb.T
+
+
+def _lb2_tile_lb_matmul(
     prmu, limit1, ptm, heads,
     p0_ref, p1_ref, lag_ref, t0_ref, t1_ref, msel0_ref, msel1_ref, jorder_ref,
     scan_ref, *, n: int, m: int, P: int, pg: int = 1, bf16: bool = False,
 ):
-    """(T, n) f32 lb2 bound of every child in the tile — the body of
-    `_lb2_kernel`, shared with the one-kernel cycle (`ops/megakernel.py`).
-    Mixed value/Ref signature: the per-pair tables stay Refs because the
-    pair loop indexes them dynamically on a non-tiled leading axis."""
+    """(T, n) f32 lb2 bound of every child in the tile — the Triton
+    flavor's pair loop (`_lb2_kernel_gpu`, the gpu cycle of
+    `ops/megakernel.py`): the max-plus closed form as one-hot and
+    triangular matmuls over (T, n, n) values.  Mixed value/Ref signature:
+    the per-pair tables stay Refs because the pair loop indexes them
+    dynamically on a non-tiled leading axis."""
     T = prmu.shape[0]
     hp = _hp_dot
     onehot, _, _, _, cf = _tile_parent_state(
@@ -751,37 +934,26 @@ def _lb2_tile_lb(
     return lb
 
 
-def _lb2_kernel(
-    prmu_ref, limit1_ref, ptm_ref, heads_ref,
-    p0_ref, p1_ref, lag_ref, t0_ref, t1_ref, msel0_ref, msel1_ref, jorder_ref,
-    out_ref, scan_ref, *, n: int, m: int, P: int, pg: int = 1,
-    bf16: bool = False,
-):
+def _lb2_kernel(prmu_ref, limit1_ref, ptm_ref, heads_ref, slots_ref,
+                pairs_ref, out_ref, scan_ref, *lane_scratch, n: int, m: int,
+                P: int, pg: int = 1, bf16: bool = False):
     """Full lb2 (two-machine Johnson) bound of every child in the tile.
 
-    Math identical to `ops/pfsp_device._lb2_chunk` (the closed-form max-plus
-    scan of `c_bound_johnson.c:190-234`, early exit dropped — see that
-    module's docstring). The decisive difference from the jnp path: the
-    whole pair loop runs against VMEM-resident tile state (child fronts,
-    free-job flags, the Johnson-ordered tables), so the ~P x (B, n, n)
-    intermediates never touch HBM.
+    Math identical to `ops/pfsp_device._lb2_chunk` (`c_bound_johnson.c:
+    190-234`, early exit dropped — see that module's docstring).  The
+    whole pair loop runs against VMEM-resident tile state (`_lb2_tile_lb`:
+    child fronts and free-job flags as lane-dense slabs, the Johnson
+    tables as SMEM scalars), so no per-pair intermediate touches HBM.
 
-    ``pg``: pair-group unroll — the fori_loop runs over P/pg pair GROUPS
-    (caller pads P to a multiple) with pg statically-unrolled pair bodies
-    per iteration, giving the VPU/MXU pg independent chains to overlap
-    instead of one serialized pair per loop step (the pair-axis batching
-    of the blocked jnp path, expressed as unrolling here — the VMEM model
-    charges the per-pair live values once per group member).
-    """
-    prmu = prmu_ref[:].astype(jnp.int32)  # (T, n)
-    limit1 = limit1_ref[:, 0].astype(jnp.int32)  # (T,)
-    ptm = ptm_ref[:].astype(jnp.float32)  # (n, m)
-    lb = _lb2_tile_lb(
-        prmu, limit1, ptm, heads_ref[:],
-        p0_ref, p1_ref, lag_ref, t0_ref, t1_ref, msel0_ref, msel1_ref,
-        jorder_ref, scan_ref, n=n, m=m, P=P, pg=pg, bf16=bf16,
+    ``pg``: pair-group unroll — the loop runs over P/pg pair GROUPS
+    (caller pads P to a multiple) with pg interleaved pair chains per
+    iteration, so independent chains hide each other's latency."""
+    lanes = _Lb2Lanes(slots_ref, pairs_ref, *lane_scratch)
+    out_ref[:] = _lb2_tile_lb(
+        prmu_ref[:].astype(jnp.int32), limit1_ref[:, 0].astype(jnp.int32),
+        ptm_ref[:].astype(jnp.float32), heads_ref[:], lanes, scan_ref,
+        n=n, m=m, P=P, pg=pg, bf16=bf16,
     )
-    out_ref[:] = lb.astype(jnp.int32)
 
 
 def _lb2_kernel_gpu(
@@ -789,56 +961,88 @@ def _lb2_kernel_gpu(
     p0_ref, p1_ref, lag_ref, t0_ref, t1_ref, msel0_ref, msel1_ref, jorder_ref,
     out_ref, *, n: int, m: int, P: int, pg: int = 1, bf16: bool = False,
 ):
-    """The lb2 kernel without its scan scratch — the Triton flavor.  The
-    pair loop's dynamic leading-axis ref reads stay: a Triton ref is a
-    pointer, and dynamic pointer offsets are the one dynamic indexing form
-    the lowering is built on."""
-    _lb2_kernel(
-        prmu_ref, limit1_ref, ptm_ref, heads_ref,
+    """The lb2 kernel's Triton flavor: the matmul pair body
+    (`_lb2_tile_lb_matmul`) with no scan scratch.  The pair loop's dynamic
+    leading-axis ref reads stay: a Triton ref is a pointer, and dynamic
+    pointer offsets are the one dynamic indexing form the lowering is
+    built on.  ``pg`` unrolls the pair group as in `_lb2_kernel`."""
+    prmu = prmu_ref[:].astype(jnp.int32)  # (T, n)
+    limit1 = limit1_ref[:, 0].astype(jnp.int32)  # (T,)
+    ptm = ptm_ref[:].astype(jnp.float32)  # (n, m)
+    lb = _lb2_tile_lb_matmul(
+        prmu, limit1, ptm, heads_ref[:],
         p0_ref, p1_ref, lag_ref, t0_ref, t1_ref, msel0_ref, msel1_ref,
-        jorder_ref, out_ref, None, n=n, m=m, P=P, pg=pg, bf16=bf16,
+        jorder_ref, None, n=n, m=m, P=P, pg=pg, bf16=bf16,
     )
+    out_ref[:] = lb.astype(jnp.int32)
 
 
 @lru_cache(maxsize=None)
 def _lb2_call(n: int, m: int, P: int, B: int, tile: int, interpret: bool,
-              bf16: bool = False, pg: int = 1, backend: str = "tpu"):
-    kernel_fn = _lb2_kernel_gpu if backend == "gpu" else _lb2_kernel
-    kernel = partial(kernel_fn, n=n, m=m, P=P, pg=pg, bf16=bf16)
+              bf16: bool = False, pg: int = 1, backend: str = "tpu",
+              parallel: bool = False):
+    """The standalone lb2 kernel over ``B // tile`` tiles; ``parallel``
+    marks the grid Megacore-splittable (it carries nothing across tiles —
+    `megakernel.streamed_eval_bounds`)."""
     grid = (B // tile,)
     full = lambda i: (0, 0)
     full3 = lambda i: (0, 0, 0)
     bs = partial(_bs, backend=backend)
+    head = [
+        bs((tile, n), lambda i: (i, 0)),
+        bs((tile, 1), lambda i: (i, 0)),
+        bs((n, m), full),
+        bs((1, m), full),
+    ]
+    if backend == "gpu":
+        kernel = partial(_lb2_kernel_gpu, n=n, m=m, P=P, pg=pg, bf16=bf16)
+        tables = [
+            # Per-pair tables as (P, 1, n)/(P, 1, m): leading-axis dynamic
+            # ref reads (see `_lb2_tile_lb_matmul`).
+            bs((P, 1, n), full3),
+            bs((P, 1, n), full3),
+            bs((P, 1, n), full3),
+            bs((P,), lambda i: (0,), space="smem"),
+            bs((P,), lambda i: (0,), space="smem"),
+            bs((P, 1, m), full3),
+            bs((P, 1, m), full3),
+            bs((P, n, n), full3),
+        ]
+        scratch = []
+    else:
+        kernel = partial(_lb2_kernel, n=n, m=m, P=P, pg=pg, bf16=bf16)
+        tables = _lb2_lane_specs(P, lambda i: (0,))
+        scratch = [pltpu.VMEM((n, tile, m), jnp.int32),
+                   *_lb2_lane_scratch(n, m, tile, pg)]
     return pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct((B, n), jnp.int32),
         name="tts_eval_lb2",
         grid=grid,
-        in_specs=[
-            bs((tile, n), lambda i: (i, 0)),
-            bs((tile, 1), lambda i: (i, 0)),
-            bs((n, m), full),
-            bs((1, m), full),
-            # Per-pair tables as (P, 1, n)/(P, 1, m): leading-axis dynamic
-            # ref reads (see pair_body).
-            bs((P, 1, n), full3),
-            bs((P, 1, n), full3),
-            bs((P, 1, n), full3),
-            # Per-pair scalars read with a dynamic index: SMEM (Mosaic cannot
-            # dynamically index 1-D VMEM along the lane dim; Triton has no
-            # memory spaces — the shim drops the pin there).
-            bs((P,), lambda i: (0,), space="smem"),
-            bs((P,), lambda i: (0,), space="smem"),
-            # (P, 1, m) one-hot machine selectors (rows read per pair).
-            bs((P, 1, m), full3),
-            bs((P, 1, m), full3),
-            bs((P, n, n), full3),
-        ],
+        in_specs=head + tables,
         out_specs=bs((tile, n), lambda i: (i, 0)),
-        scratch_shapes=_scratch(backend, pltpu.VMEM((n, tile, m), jnp.int32)),
-        compiler_params=_compiler_params(backend=backend),
+        scratch_shapes=scratch,
+        compiler_params=_compiler_params(parallel=parallel, backend=backend),
         interpret=interpret,
     )
+
+
+def lb2_kernel_tables(ordered, pg: int, backend: str = "tpu") -> tuple:
+    """The pair-table operands of an lb2 kernel over ``ordered`` (padded to
+    a multiple of the pair group ``pg``), after ptm and the heads: the TPU
+    kernels' tables (`_Lb2Lanes`: the slot table holds each group at its
+    `_lb2_group_stride`), or the Triton flavor's slot-ordered rows,
+    selectors and (P, n, n) one-hot order."""
+    if backend != "gpu":
+        P, n = ordered.lag_o.shape
+        rows = jnp.reshape(ordered.slot_tab, (P // pg, pg * 4 * n))
+        slots = jnp.pad(rows, ((0, 0), (0, _lb2_group_stride(n, pg)
+                                         - pg * 4 * n))).reshape(-1)
+        return (slots, ordered.pair_tab)
+    return (ordered.p0_o[:, None, :], ordered.p1_o[:, None, :],
+            ordered.lag_o[:, None, :], ordered.tails0, ordered.tails1,
+            ordered.msel0[:, None, :], ordered.msel1[:, None, :],
+            ordered.jorder)
 
 
 def _eager_context() -> bool:
@@ -863,8 +1067,7 @@ def pfsp_lb2_bounds(prmu, limit1, tables, interpret: bool | None = None,
     m = tables.ptm_t.shape[1]
     P = tables.pairs.shape[0]
     pg = _resolve_pair_group("lb2", n, P, pair_group)
-    # Tile-independent residents (per-pair tables) + the shared + per-pair
-    # (T, n, n)-class live f32 pair-loop values — see _KERNEL_MODEL["lb2"].
+    # The tile the backend's pair body fits (_KERNEL_MODEL, `_model_key`).
     tile = effective_tile("lb2", n, m, P, batch=B, pair_group=pg,
                           backend=backend)
     Bp = _round_up(B, tile)
@@ -883,14 +1086,7 @@ def pfsp_lb2_bounds(prmu, limit1, tables, interpret: bool | None = None,
         limit1.astype(jnp.int32)[:, None],
         tables.ptm_t,
         tables.min_heads[None, :],
-        ordered.p0_o[:, None, :],
-        ordered.p1_o[:, None, :],
-        ordered.lag_o[:, None, :],
-        ordered.tails0,
-        ordered.tails1,
-        ordered.msel0[:, None, :],
-        ordered.msel1[:, None, :],
-        ordered.jorder,
+        *lb2_kernel_tables(ordered, pg, backend),
     )
     return out[:B]
 

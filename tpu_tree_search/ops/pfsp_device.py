@@ -312,10 +312,11 @@ class PFSPDeviceTables:
 
     ``johnson_ordered()`` additionally derives the Johnson data in *schedule
     order* per machine pair (`p0_o/p1_o/lag_o[q, t]` = value of the t-th job
-    of pair q's Johnson schedule) plus a per-pair permutation one-hot, so the
-    Pallas lb2 kernel reorders the per-child free-job flags with one small
-    matmul instead of a runtime gather chain. Built lazily: the dense
-    (P, n, n) one-hot is only worth its memory when that kernel runs.
+    of pair q's Johnson schedule), the same as flat int32 scalar tables
+    for the TPU Pallas lb2 kernels (``slot_tab``/``pair_tab``, read from
+    SMEM), and a per-pair permutation one-hot for the Triton flavor, which
+    reorders the per-child free-job flags with one small matmul. Built
+    lazily, only when a kernel runs.
     """
 
     def __init__(self, lb1_data, lb2_data):
@@ -387,6 +388,15 @@ class PFSPDeviceTables:
         eye = np.eye(m, dtype=np.float32)
         o.msel0 = eye[pairs[:, 0]]
         o.msel1 = eye[pairs[:, 1]]
+        # The TPU kernels' SMEM scalar tables (`pallas_kernels._Lb2Lanes`):
+        # per pair the Johnson order as job ids and its p0/p1/lag rows,
+        # flat (P*4*n,); per pair its machines and tails, flat (P*4,).
+        o.slot_tab = np.stack(
+            [sched, o.p0_o, o.p1_o, o.lag_o], axis=1
+        ).astype(np.int32).reshape(-1)
+        o.pair_tab = np.stack(
+            [pairs[:, 0], pairs[:, 1], o.tails0, o.tails1], axis=1
+        ).astype(np.int32).reshape(-1)
         return o
 
     def johnson_ordered(self):
@@ -417,7 +427,7 @@ class PFSPDeviceTables:
 
             d = _Dev()
             for f in ("p0_o", "p1_o", "lag_o", "tails0", "tails1",
-                      "msel0", "msel1", "jorder"):
+                      "msel0", "msel1", "jorder", "slot_tab", "pair_tab"):
                 setattr(d, f, jnp.asarray(getattr(o, f)))
             cache[pad_to] = d
         return cache[pad_to]

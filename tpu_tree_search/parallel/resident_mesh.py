@@ -793,6 +793,7 @@ def mesh_resident_search(
                 megakernel_reason=program.inner.megakernel.reason,
                 megakernel_mt=program.inner.megakernel.mt or None,
                 megakernel_tiled=program.inner.megakernel.tiled,
+                lb2_body=program.inner.megakernel.lb2_body,
                 kernel_backend=program.inner.kernel_backend,
                 kernel_interpret=program.inner.kernel_interpret,
                 pipeline_depth=depth,
@@ -891,6 +892,7 @@ def mesh_resident_search(
         megakernel_reason=program.inner.megakernel.reason,
         megakernel_mt=program.inner.megakernel.mt or None,
         megakernel_tiled=program.inner.megakernel.tiled,
+        lb2_body=program.inner.megakernel.lb2_body,
         kernel_backend=program.inner.kernel_backend,
         kernel_interpret=program.inner.kernel_interpret,
         pipeline_depth=depth,
