@@ -1147,7 +1147,7 @@ def _megakernel_ab_rows(extras: list, on_tpu: bool) -> None:
 
     ladder = []
     orig_on_tpu = MK._native_kind
-    MK._native_kind = ((lambda device=None: "tpu") if not on_tpu
+    MK._native_kind = ((lambda device=None: True) if not on_tpu
                        else orig_on_tpu)
     try:
         for Mr in (4096, 16384, 65536):
@@ -1380,186 +1380,6 @@ def fleet_sat_main() -> int:
         for d in daemons:
             d.scheduler.drain(timeout_s=30.0)
             d.close()
-
-
-# -- GPU headline session (`python bench.py gpu_headline`) -------------------
-
-GPU_BASELINE_PATH = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                                 "GPU_BASELINE.json")
-
-
-def _gpu_kernel_parity() -> list[dict]:
-    """Interpret-mode bit-parity gate for the GPU-lowered kernel bodies:
-    run the Triton-shaped lb1/lb2 kernels (``backend="gpu"``,
-    ``interpret=True`` — exact on any host, no GPU required) on a random
-    ta014 chunk against the jnp oracles the engine trusts.  This is the
-    CPU-provable half of the GPU story: a rate banked past a red gate
-    would be a number for a different tree.  Returns one row per kernel;
-    ``ok`` on every row is the session's go/no-go."""
-    import jax.numpy as jnp
-    import numpy as np
-
-    from tpu_tree_search.ops import pallas_kernels as PK
-    from tpu_tree_search.ops import pfsp_device
-    from tpu_tree_search.problems import PFSPProblem
-
-    prob = PFSPProblem(inst=14, lb="lb2", ub=1)
-    t = pfsp_device.PFSPDeviceTables(prob.lb1_data, prob.lb2_data)
-    n = prob.jobs
-    rng = np.random.default_rng(20)
-    B = 64
-    prmu = jnp.asarray(
-        np.stack([rng.permutation(n).astype(np.int32) for _ in range(B)]))
-    limit1 = jnp.asarray(rng.integers(-1, n - 1, B).astype(np.int32))
-    rows = []
-
-    oracle1 = pfsp_device._lb1_chunk(
-        prmu, limit1, t.ptm_t, t.min_heads, t.min_tails)
-    got1 = PK.pfsp_lb1_bounds(
-        prmu, limit1, t.ptm_t, t.min_heads, t.min_tails,
-        interpret=True, backend="gpu")
-    rows.append({"kernel": "pfsp_lb1",
-                 "ok": bool(np.array_equal(np.asarray(oracle1),
-                                           np.asarray(got1)))})
-
-    oracle2 = pfsp_device._lb2_chunk(
-        prmu, limit1, t.ptm_t, t.min_heads, t.min_tails,
-        t.pairs, t.lags, t.johnson_schedules)
-    got2 = PK.pfsp_lb2_bounds(prmu, limit1, t, interpret=True, backend="gpu")
-    # Open child slots only — closed slots are garbage by contract.
-    open_ = np.arange(n)[None, :] >= np.asarray(limit1)[:, None] + 1
-    rows.append({"kernel": "pfsp_lb2",
-                 "ok": bool(np.array_equal(np.asarray(oracle2)[open_],
-                                           np.asarray(got2)[open_]))})
-    return rows
-
-
-def gpu_headline_main() -> int:
-    """``python bench.py gpu_headline``: the GPU flavor of the headline —
-    PFSP ta014 lb1 + lb2 and N-Queens under ``TTS_KERNEL_BACKEND=gpu``,
-    parity-gated against the same sequential goldens as the TPU bench,
-    banked flush-as-you-go into GPU_BASELINE.json with roofline capture
-    (TTS_PHASEPROF armed, so ``SearchResult.roofline`` lands in each row).
-
-    Two-stage gate: (1) interpret-mode bit-parity of the GPU-lowered
-    kernel bodies vs the jnp oracles — provable on this CPU container,
-    red means DO NOT bank; (2) per-row tree/sol/makespan parity of the
-    full search.  On a host without a GPU the searches run on whatever
-    jax picks (the forced-gpu knob routes policy tables and reporting;
-    the Pallas routing stays off off-chip), the artifact is written to
-    tempdir (platform "cpu-sim"), and rc=0 still requires every gate
-    green — that is the CI arming path for scripts/gpu_session.sh, which
-    runs this same entry on a real card and commits the artifact.
-    Knobs: TTS_GPU_BASELINE_OUT (artifact path), TTS_GPU_HEADLINE_NQ
-    (N-Queens size; default 15 on a GPU, 12 in cpu-sim)."""
-    partial = BenchPartial()
-    partial.install_sigterm()
-    import tempfile as _tempfile
-
-    import jax
-
-    from tpu_tree_search.problems import NQueensProblem, PFSPProblem
-    from tpu_tree_search.utils import compile_cache
-
-    compile_cache.enable()
-    cpu = os.environ.get("JAX_PLATFORMS", "").startswith("cpu")
-    on_gpu = jax.devices()[0].platform == "gpu"
-    out = os.environ.get("TTS_GPU_BASELINE_OUT") or (
-        GPU_BASELINE_PATH if on_gpu
-        else os.path.join(_tempfile.gettempdir(), "GPU_BASELINE.json"))
-    doc = {
-        "metric": "gpu_headline",
-        "commit": _git_head(),
-        "contracts": contracts_fingerprint(),
-        "platform": "gpu" if on_gpu else ("cpu-sim" if cpu else "non-gpu"),
-        "kernel_backend_mode": "gpu",
-        "status": "running",
-        "kernel_parity": [],
-        "records": [],
-    }
-
-    def bank() -> None:
-        doc["updated"] = time.strftime("%Y-%m-%d %H:%M:%S UTC", time.gmtime())
-        tmp = out + ".tmp"
-        with open(tmp, "w") as f:
-            json.dump(doc, f, indent=1)
-            f.flush()
-            os.fsync(f.fileno())
-        os.replace(tmp, out)
-
-    bank()
-    partial.stage("kernel_parity", "running")
-    doc["kernel_parity"] = _gpu_kernel_parity()
-    gate_ok = bool(doc["kernel_parity"]) and all(
-        r.get("ok") for r in doc["kernel_parity"])
-    partial.stage("kernel_parity", "ok" if gate_ok else "error",
-                  rows=doc["kernel_parity"])
-    if not gate_ok:
-        doc["status"] = "kernel-parity-failed"
-        bank()
-        print(json.dumps(doc))
-        partial.finish(1, "gpu kernel parity gate red")
-        return 1
-
-    nq_n = int(os.environ.get("TTS_GPU_HEADLINE_NQ")
-               or (15 if on_gpu else 12))
-    rows = [
-        ("pfsp_ta014_lb1", lambda: PFSPProblem(inst=14, lb="lb1", ub=1),
-         25, HEADLINE_M,
-         lambda r: (r.explored_tree == GOLDEN_LB1["tree"]
-                    and r.explored_sol == GOLDEN_LB1["sol"]
-                    and r.best == GOLDEN_LB1["makespan"])),
-        ("pfsp_ta014_lb2", lambda: PFSPProblem(inst=14, lb="lb2", ub=1),
-         25, 1024 if on_gpu else 4096,
-         lambda r: (r.explored_tree == GOLDEN_LB2["tree"]
-                    and r.explored_sol == GOLDEN_LB2["sol"]
-                    and r.best == GOLDEN_LB2["makespan"])),
-        (f"nqueens_n{nq_n}", lambda: NQueensProblem(N=nq_n),
-         25, 65536,
-         lambda r: r.explored_sol == NQ_SOL.get(nq_n, r.explored_sol)),
-    ]
-    all_parity = True
-    for name, mk, m, M, parity_fn in rows:
-        partial.stage(name, "running")
-        # TTS_PHASEPROF arms the phase clocks so res.roofline (the
-        # memory-roofline audit, obs/roofline.py) rides each row; the
-        # audit resolves its peak by device_kind, so a forced non-native
-        # run never borrows a chip's peak ("not measured" instead).
-        with _env_override("TTS_KERNEL_BACKEND", "gpu"), \
-                _env_override("TTS_PHASEPROF", "1"):
-            res, nps, elapsed, device_phase = run_config(mk(), m=m, M=M)
-        parity = bool(parity_fn(res))
-        row = {
-            "metric": f"{name}_nodes_per_sec_per_chip",
-            "value": round(nps, 1),
-            "unit": "nodes/sec",
-            "parity": parity,
-            "explored_tree": res.explored_tree,
-            "explored_sol": res.explored_sol,
-            "best": res.best,
-            "device_phase_s": round(device_phase, 3),
-            "total_s": round(elapsed, 3),
-            "kernel_backend": res.kernel_backend,
-            "megakernel": res.megakernel,
-        }
-        if f"{name}" in REF_C_SEQ:
-            row["vs_ref_c_seq"] = round(nps / REF_C_SEQ[name], 3)
-        if res.megakernel_reason:
-            row["megakernel_reason"] = res.megakernel_reason
-        if res.roofline is not None:
-            row["roofline_mem"] = res.roofline
-        all_parity = all_parity and parity
-        doc["records"].append(row)
-        bank()
-        partial.stage(name, "ok" if parity else "error",
-                      value=row.get("value"),
-                      **({"error": row["error"]} if row.get("error") else {}))
-    doc["status"] = "complete" if all_parity else "parity-failed"
-    bank()
-    print(json.dumps(doc))
-    partial.finish(0 if all_parity else 1,
-                   None if all_parity else "search parity gate red")
-    return 0 if all_parity else 1
 
 
 def _main(partial: BenchPartial) -> int:
@@ -1960,6 +1780,4 @@ def _collect_extras(extras: list, on_tpu: bool) -> None:
 if __name__ == "__main__":
     if len(sys.argv) > 1 and sys.argv[1] == "fleet_sat":
         sys.exit(fleet_sat_main())
-    if len(sys.argv) > 1 and sys.argv[1] == "gpu_headline":
-        sys.exit(gpu_headline_main())
     sys.exit(main())

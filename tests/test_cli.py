@@ -80,12 +80,6 @@ def test_flag_validation(argv, msg, capsys):
     (7777, "pfsp", "device", "resident", "tpu", 7777),  # explicit wins
     (None, "pfsp", "device", "resident", "tpu", 1024),  # measured default
     (None, "pfsp", "device", "resident", "cpu", 50000),  # unmeasured backend
-    # The GPU row: the reference's published ~50k-node offload chunk
-    # (arXiv 2012.09511 §IV) rounded DOWN to a multiple of 8 (50000 % 8
-    # == 2 would refuse the megakernel/tiled-compaction alignment gates).
-    (None, "pfsp", "device", "resident", "gpu", 49152),
-    (None, "pfsp", "device", "offload", "gpu", 50000),  # non-candidate
-    (None, "nqueens", "device", "resident", "gpu", 50000),  # wide frontier
     (None, "pfsp", "device", "offload", "tpu", 50000),  # per-chunk round trip
     (None, "pfsp", "mesh", "resident", "tpu", 1024),   # per shard, same cycle
     (None, "pfsp", "dist_mesh", "offload", "tpu", 1024),  # always resident
@@ -97,22 +91,21 @@ def test_resolve_chunk_size(M, name, tier, engine, backend, expect):
     (docs/HW_VALIDATION.md); explicit values, the offload engine, and
     unmeasured combinations keep the reference's 50000 (`util.chpl`)."""
     assert cli.resolve_chunk_size(M, name, tier, engine, backend) == expect
-    assert cli.resolve_chunk_size(None, "pfsp", "device", "resident",
-                                  "gpu") % 8 == 0
 
 
 def test_resolve_chunk_size_backend_default_tracks_kernel_knob(monkeypatch):
-    """With no explicit backend the candidate row resolves through
-    ops/backend.policy_backend: TTS_KERNEL_BACKEND=gpu on this CPU host
-    must pick the GPU chunk row (CI routes like a GPU host), while the
-    unset knob keeps the host platform's row."""
-    monkeypatch.delenv("TTS_KERNEL_BACKEND", raising=False)
+    """With no explicit backend the candidate row follows the host
+    platform (`pallas_kernels.platform`): this CPU host takes the 50000
+    row, and asking for the kernels (TTS_PALLAS=force) does not turn it
+    into the TPU's 1024 row."""
+    import jax
+
+    from tpu_tree_search.ops import pallas_kernels as PK
+
+    assert PK.platform() == jax.default_backend() == "cpu"
+    monkeypatch.delenv("TTS_PALLAS", raising=False)
     assert cli.resolve_chunk_size(None, "pfsp", "device", "resident") == 50000
-    monkeypatch.setenv("TTS_KERNEL_BACKEND", "gpu")
-    assert cli.resolve_chunk_size(None, "pfsp", "device", "resident") == 49152
-    # Forced tpu off-TPU stays jnp-routed (policy_backend returns the
-    # physical platform), so the chunk row must NOT flip to 1024.
-    monkeypatch.setenv("TTS_KERNEL_BACKEND", "tpu")
+    monkeypatch.setenv("TTS_PALLAS", "force")
     assert cli.resolve_chunk_size(None, "pfsp", "device", "resident") == 50000
 
 
@@ -250,3 +243,44 @@ def test_json_record_flags_interpreted_kernels(capsys, monkeypatch):
     rec = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
     assert rec["kernel_backend"] == "tpu" and rec["pallas"] is True
     assert rec["kernel_interpret"] is True
+
+
+_RESIDENT_N6 = ["nqueens", "--N", "6", "--tier", "device", "--engine",
+                "resident", "--m", "4", "--M", "64"]
+
+
+def test_cli_json_records_the_armed_cycle_route(capsys, monkeypatch):
+    """The armed cycle, interpreted on this CPU host, is the Pallas route:
+    the record says ``tpu`` and interpreted, and has no
+    ``kernel_backend_mode`` (there is no flavor knob to report)."""
+    monkeypatch.setenv("TTS_MEGAKERNEL", "force")
+    assert cli.main(_RESIDENT_N6 + ["--json"]) == 0
+    rec = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert rec["megakernel"] == "on"
+    assert rec["kernel_backend"] == "tpu" and rec["kernel_interpret"] is True
+    assert "kernel_backend_mode" not in rec
+
+
+def test_cli_banner_names_the_kernel_route(capsys, monkeypatch):
+    """The banner prints the route as ``Kernel backend: <route>``, with
+    no forced-flavor tag."""
+    monkeypatch.setenv("TTS_PALLAS_INTERPRET", "1")
+    assert cli.main(_RESIDENT_N6) == 0
+    out = capsys.readouterr().out
+    assert "Kernel backend: tpu\n" in out
+    assert "forced" not in out
+
+
+def test_cli_json_default_backend_unforced(capsys, monkeypatch):
+    """A default CPU run records the jnp route and no
+    ``kernel_backend_mode``; asking for the kernels (TTS_PALLAS=force)
+    changes neither on a host that is no TPU."""
+    for env in ({}, {"TTS_PALLAS": "force"}):
+        for k in ("TTS_PALLAS", "TTS_PALLAS_INTERPRET", "TTS_MEGAKERNEL"):
+            monkeypatch.delenv(k, raising=False)
+        for k, v in env.items():
+            monkeypatch.setenv(k, v)
+        assert cli.main(_RESIDENT_N6 + ["--json"]) == 0
+        rec = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+        assert rec["kernel_backend"] == "jnp", env
+        assert "kernel_backend_mode" not in rec

@@ -182,9 +182,9 @@ def test_tamper_pair_axis_serialized(monkeypatch):
 
 
 def test_tamper_engine_stitch_behind_the_pushing_cycle(monkeypatch):
-    """Write a pool from XLA again behind the armed TPU cycle: the push
-    contract must name the dynamic_update_slice; the gpu flavor's cell,
-    which stitches by design, stays green."""
+    """Write a pool from XLA again behind the armed cycle: the push
+    contract must name the dynamic_update_slice, and the untampered cell
+    stays green."""
     import jax
 
     from tpu_tree_search.ops import megakernel as MK
@@ -207,9 +207,8 @@ def test_tamper_engine_stitch_behind_the_pushing_cycle(monkeypatch):
     msgs = contracts.run_one("megakernel-push-in-kernel", art, cell)
     assert msgs and "dynamic_update_slice" in msgs[0], msgs
     monkeypatch.setattr(MK, "make_cycle", real)
-    gpu = program_audit.Cell("nqueens", megakernel="force", backend="gpu")
-    art = program_audit.trace_cell(gpu)
-    assert contracts.run_one("megakernel-push-in-kernel", art, gpu) == []
+    art = program_audit.trace_cell(cell)
+    assert contracts.run_one("megakernel-push-in-kernel", art, cell) == []
 
 
 def test_tamper_fingerprint_drift():

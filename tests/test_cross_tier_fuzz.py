@@ -196,28 +196,12 @@ def test_all_tiers_match_sequential_narrow_axis(mode, monkeypatch):
 @pytest.mark.parametrize("kb", ["jnp", "tpu"])
 def test_all_tiers_match_sequential_kernel_backend_inert_axis(kb,
                                                               monkeypatch):
-    """Kernel-backend knob axis (ops/backend.py TTS_KERNEL_BACKEND): the
-    inert settings on this host — forced jnp, and forced tpu off-TPU
-    (non-native, so routing stays on the jnp evaluators) — must land the
-    sequential counts on every tier.  The `kernel-backend-inert` contract
-    checks the jaxpr is byte-identical; this checks the search is."""
-    monkeypatch.setenv("TTS_KERNEL_BACKEND", kb)
+    """Kernels-or-jnp axis (`pallas_kernels.use_pallas`, decided by the
+    device's platform): the kernels switched off (TTS_PALLAS=0) and
+    asked for on a host that is no TPU (TTS_PALLAS=force, which keeps the
+    jnp evaluators here) must land the sequential counts on every tier."""
+    monkeypatch.setenv("TTS_PALLAS", "0" if kb == "jnp" else "force")
     _fuzz_all_tiers(227, "lb1")
-
-
-@pytest.mark.slow  # forced gpu routes every tier through interpret-mode kernels; CI tests-gpu-lowering runs it unfiltered
-@pytest.mark.parametrize("seed,lb", [(227, "lb1"), (229, "lb2")])
-def test_all_tiers_match_sequential_kernel_backend_gpu_axis(seed, lb,
-                                                            monkeypatch):
-    """Forced-gpu axis: TTS_KERNEL_BACKEND=gpu (+ TTS_PALLAS=force to
-    re-arm the demoted lb1 family) lowers every evaluator through the
-    Triton-flavored tile bodies — interpret mode on this CPU host, same
-    program — and every tier must still land the sequential counts.  The
-    backend changes HOW the bounds are computed, never what the search
-    explores."""
-    monkeypatch.setenv("TTS_KERNEL_BACKEND", "gpu")
-    monkeypatch.setenv("TTS_PALLAS", "force")
-    _fuzz_all_tiers(seed, lb)
 
 
 @pytest.mark.parametrize("mode", ["dense", "auto"])

@@ -12,6 +12,8 @@ so every claim here is about the real fused cycle body.
 
 from __future__ import annotations
 
+import types
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -84,6 +86,50 @@ def test_force_matches_sequential_goldens(monkeypatch):
         res = resident_search(mk(), m=4, M=64, K=8, initial_best=opt)
         assert res.megakernel == "on", res.megakernel_reason
         assert _counts(res) == _counts(seq)
+
+
+# -- the cycle against the sequential oracle, across the routing axes -------
+
+def _reduced(lb: str):
+    from tpu_tree_search.problems.pfsp import taillard
+
+    ptm = taillard.reduced_instance(14, jobs=8, machines=5)
+    return lambda: PFSPProblem(lb=lb, ub=0, p_times=ptm)
+
+
+def _armed_matches_sequential(mk, M: int = 64):
+    opt = sequential_search(mk()).best
+    seq = sequential_search(mk(), initial_best=opt)
+    res = resident_search(mk(), m=4, M=M, K=8, initial_best=opt)
+    assert res.megakernel == "on", res.megakernel_reason
+    assert res.kernel_backend == "tpu" and res.kernel_interpret
+    assert _counts(res) == _counts(seq)
+
+
+@pytest.mark.parametrize("compact", ["auto", "dense", "scatter"])
+@pytest.mark.parametrize("narrow", ["0", "auto"])
+def test_cycle_lb1_matches_sequential(compact, narrow, monkeypatch):
+    """The armed lb1 cycle, interpreted, on a reduced ta014 (8x5): the
+    resident search lands the sequential tier's counts and optimum under
+    every survivor compact mode the off path would take and with narrow
+    node storage on and off."""
+    monkeypatch.setenv("TTS_MEGAKERNEL", "force")
+    monkeypatch.setenv("TTS_COMPACT", compact)
+    monkeypatch.setenv("TTS_NARROW", narrow)
+    _armed_matches_sequential(_reduced("lb1"))
+
+
+@pytest.mark.parametrize("pairblock", ["1", "auto"])
+def test_cycle_lb2_matches_sequential(pairblock, monkeypatch):
+    """The armed lb2 cycle at pair group 1 and at the policy's group."""
+    monkeypatch.setenv("TTS_MEGAKERNEL", "force")
+    monkeypatch.setenv("TTS_LB2_PAIRBLOCK", pairblock)
+    _armed_matches_sequential(_reduced("lb2"))
+
+
+def test_cycle_nqueens_matches_sequential(monkeypatch):
+    monkeypatch.setenv("TTS_MEGAKERNEL", "force")
+    _armed_matches_sequential(lambda: NQueensProblem(N=9), M=256)
 
 
 # -- checkpoint cuts + the batched engine ----------------------------------
@@ -203,28 +249,18 @@ def test_lb2_bf16_gate_refuses_and_falls_back(monkeypatch):
     assert _counts(res) == _counts(seq)
 
 
-def test_lb2_decision_names_its_pair_body(monkeypatch):
-    """An armed lb2 decision names the pair loop it compiles — ``lanes``
-    on the TPU flavor, ``matmul`` on the gpu flavor — and the search
-    result carries it (`SearchResult.lb2_body`, which the banner and the
-    stats line print); the state stays ``on``/``off`` and the other
-    families name no body."""
+def test_lb2_decision_state(monkeypatch):
+    """An lb2 decision's state is ``on`` when armed and ``off`` when the
+    knob turns the cycle off, and the search result carries it."""
     monkeypatch.setenv("TTS_MEGAKERNEL", "force")
     prob = PFSPProblem(inst=14, lb="lb2", ub=1)
     dec = MK.resolve(prob, 1024)
-    assert (dec.backend, dec.lb2_body, dec.state) == ("tpu", "lanes", "on")
-    assert MK.resolve(PFSPProblem(inst=14, lb="lb1", ub=1),
-                      1024).lb2_body is None
+    assert (dec.enabled, dec.state) == (True, "on")
     res = resident_search(_mk_problem("pfsp-lb2")(), m=4, M=64, K=8)
-    assert (res.megakernel, res.lb2_body) == ("on", "lanes")
-    res = resident_search(_mk_problem("pfsp-lb1")(), m=4, M=64, K=8)
-    assert (res.megakernel, res.lb2_body) == ("on", None)
-    monkeypatch.setenv("TTS_KERNEL_BACKEND", "gpu")
-    dec = MK.resolve(prob, 1024)
-    assert (dec.backend, dec.lb2_body, dec.state) == ("gpu", "matmul", "on")
+    assert res.megakernel == "on"
     monkeypatch.setenv("TTS_MEGAKERNEL", "0")
     dec = MK.resolve(prob, 1024)
-    assert (dec.lb2_body, dec.state) == (None, "off")
+    assert (dec.enabled, dec.state) == (False, "off")
 
 
 def test_lb2_lanes_contract_catches_a_matmul_in_the_pair_loop(monkeypatch):
@@ -358,7 +394,7 @@ def test_auto_window_arms_tiled_past_limit(monkeypatch):
     the window the original single-tile form is kept verbatim.  The TPU
     backend gate is patched on — this is a decision-policy fact, not an
     execution one."""
-    monkeypatch.setattr(MK, "_native_kind", lambda device=None: "tpu")
+    monkeypatch.setattr(MK, "_native_kind", lambda device=None: True)
     prob = NQueensProblem(N=8)
     n = int(prob.child_slots)
     small = MK.resolve(prob, 64)
@@ -663,3 +699,80 @@ def test_cycle_kernel_carries_its_trace_names(family, name, mt, monkeypatch):
     scopes = {str(e.source_info.name_stack)
               for _, e in prim_eqns(eqn.params["jaxpr"])}
     assert {MK.BOUND_SCOPE, MK.COMPACT_SCOPE} <= scopes
+
+
+# -- routing on a described v5e (no compile) --------------------------------
+
+#: A stand-in for one v5e chip: routing reads only the device's platform.
+_V5E = types.SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")
+
+#: case -> (problem, M, (enabled, Mt, grid) that ``auto`` resolves on a
+#: v5e).  ta014 and N=15 at the CLI's chunk sizes are the benchmark cells.
+_V5E_ROUTES = {
+    "ta014-lb1": (lambda: PFSPProblem(inst=14, lb="lb1", ub=1), 1024,
+                  (True, 128, 8)),
+    "ta014-lb2": (lambda: PFSPProblem(inst=14, lb="lb2", ub=1), 1024,
+                  (True, 128, 8)),
+    "nqueens-15": (lambda: NQueensProblem(N=15), 50000, (True, 200, 250)),
+    "ta021-lb1": (lambda: PFSPProblem(inst=21, lb="lb1", ub=1), 1024,
+                  (True, 128, 8)),
+    "ta021-lb2": (lambda: PFSPProblem(inst=21, lb="lb2", ub=1), 1024,
+                  (True, 128, 8)),
+    "ta031-lb1": (lambda: PFSPProblem(inst=31, lb="lb1", ub=1), 1024,
+                  (True, 64, 16)),
+    "ta051-lb1": (lambda: PFSPProblem(inst=51, lb="lb1", ub=1), 1024,
+                  (True, 64, 16)),
+    "ta051-lb2": (lambda: PFSPProblem(inst=51, lb="lb2", ub=1), 1024,
+                  (True, 64, 16)),
+}
+
+
+def _unpin_routing(monkeypatch):
+    for k in ("TTS_MEGAKERNEL", "TTS_MEGAKERNEL_MT", "TTS_NARROW",
+              "TTS_PALLAS", "TTS_PALLAS_INTERPRET", "TTS_PALLAS_VMEM_MB",
+              "TTS_COMPACT", "TTS_LB2_PAIRBLOCK"):
+        monkeypatch.delenv(k, raising=False)
+
+
+@pytest.mark.parametrize("case", sorted(_V5E_ROUTES))
+def test_auto_routing_on_a_v5e(case, monkeypatch):
+    """What ``auto`` arms on a TPU, read from the device's platform alone:
+    the cycle armed and compiled (not interpreted), at the pool tile and
+    grid the VMEM model picks for the shape."""
+    _unpin_routing(monkeypatch)
+    mk, M, want = _V5E_ROUTES[case]
+    dec = MK.resolve(mk(), M, _V5E)
+    assert (dec.enabled, dec.mt, dec.grid) == want, dec
+    assert dec.auto and not dec.interpret and dec.reason is None
+
+
+@pytest.mark.parametrize("case", ["ta014-lb1", "ta014-lb2", "nqueens-15"])
+def test_compact_mode_on_a_v5e(case, monkeypatch):
+    """The benchmark cells' survivor compaction on a TPU: ``dense`` (PFSP
+    at M*n <= 64k, and N-Queens, which never prunes)."""
+    from tpu_tree_search.ops import compaction
+
+    _unpin_routing(monkeypatch)
+    mk, M, _ = _V5E_ROUTES[case]
+    prob = mk()
+    assert compaction.resolve_compact_mode(prob, M, prob.child_slots,
+                                           _V5E) == "dense"
+
+
+@pytest.mark.parametrize("platform,expect", [("tpu", "tpu"), ("cpu", "jnp")])
+def test_kernels_or_jnp_follows_the_platform(platform, expect, monkeypatch):
+    """`use_pallas` answers "kernels or jnp" from the target device: a TPU
+    routes to the Pallas kernels, a CPU device to the jnp oracles, and the
+    program reports the route it builds (`SearchResult.kernel_backend`)."""
+    from tpu_tree_search.engine.resident import _make_program, resolve_capacity
+    from tpu_tree_search.ops import pallas_kernels as PK
+
+    _unpin_routing(monkeypatch)
+    dev = _V5E if platform == "tpu" else jax.devices("cpu")[0]
+    assert PK.use_pallas(dev) is (platform == "tpu")
+    prob = PFSPProblem(inst=14, lb="lb2", ub=1)
+    capacity, M = resolve_capacity(prob, 1024, None)
+    prog = _make_program(prob, 25, M, 4096, capacity, dev)
+    assert prog.kernel_backend == expect
+    assert prog.megakernel.enabled is (platform == "tpu")
+    assert not prog.kernel_interpret

@@ -19,9 +19,10 @@ from tpu_tree_search.problems.pfsp import taillard
 
 
 @pytest.mark.parametrize("g", [1, 3])
-def test_nqueens_labels_match_oracle(g):
+@pytest.mark.parametrize("N", [9, 11, 12])
+def test_nqueens_labels_match_oracle(N, g):
     rng = np.random.default_rng(7)
-    N, B = 11, 700  # B not a tile multiple: exercises padding
+    B = 700  # not a tile multiple: exercises padding
     boards = np.stack([rng.permutation(N).astype(np.uint8) for _ in range(B)])
     depth = rng.integers(0, N + 1, B).astype(np.int32)
     oracle = nqueens_device.make_core(N, g)(jnp.asarray(boards), jnp.asarray(depth))
@@ -34,7 +35,7 @@ def test_nqueens_labels_match_oracle(g):
 @pytest.mark.parametrize("bf16", [False, True])
 @pytest.mark.parametrize(
     "inst,jobs,machines",
-    [(14, 20, 10), (1, 12, 5)],
+    [(14, 20, 10), (21, 20, 20), (1, 12, 5)],
 )
 def test_lb1_bounds_match_oracle(inst, jobs, machines, bf16):
     rng = np.random.default_rng(3)
@@ -87,6 +88,32 @@ def test_lb2_bounds_match_oracle(inst, jobs, machines, bf16):
     assert np.array_equal(
         np.asarray(oracle)[open_], np.asarray(got)[open_]
     )
+
+
+@pytest.mark.parametrize("pair_group", [1, 4, None])
+@pytest.mark.parametrize("inst", [14, 21])
+def test_lb2_bounds_across_pair_groups(inst, pair_group):
+    """The lb2 lane body over the pair-group axis — one pair a group, four
+    interleaved, and the group the TTS_LB2_PAIRBLOCK policy resolves — on
+    ta014 (P=45) and ta021 (20x20, P=190, where the policy blocks),
+    bit-equal to `_lb2_chunk` on open child slots (closed slots are
+    garbage by contract)."""
+    rng = np.random.default_rng(7 + inst)
+    prob = PFSPProblem(inst=inst, lb="lb2", ub=1)
+    jobs = prob.jobs
+    t = pfsp_device.PFSPDeviceTables(prob.lb1_data, prob.lb2_data)
+    B = 200
+    prmu = np.stack([rng.permutation(jobs).astype(np.int32) for _ in range(B)])
+    limit1 = rng.integers(-1, jobs - 1, B).astype(np.int32)
+    pd, ld = jnp.asarray(prmu), jnp.asarray(limit1)
+    oracle = pfsp_device._lb2_chunk(
+        pd, ld, t.ptm_t, t.min_heads, t.min_tails,
+        t.pairs, t.lags, t.johnson_schedules,
+    )
+    got = pallas_kernels.pfsp_lb2_bounds(pd, ld, t, interpret=True,
+                                         pair_group=pair_group)
+    open_ = np.arange(jobs)[None, :] >= limit1[:, None] + 1
+    assert np.array_equal(np.asarray(oracle)[open_], np.asarray(got)[open_])
 
 
 def _lb2_lane_problem(shape: str):
@@ -238,7 +265,7 @@ def test_auto_tile_shrinks_for_large_instances():
 @pytest.mark.parametrize("bf16", [False, True])
 @pytest.mark.parametrize(
     "inst,jobs,machines",
-    [(14, 20, 10), (1, 12, 5)],
+    [(14, 20, 10), (21, 20, 20), (1, 12, 5)],
 )
 def test_lb1_d_bounds_match_oracle(inst, jobs, machines, bf16):
     rng = np.random.default_rng(11)

@@ -56,7 +56,7 @@ KNOBS = (
     "TTS_LB2_STAGED", "TTS_FLIGHTREC", "TTS_COSTMODEL",
     "TTS_QUALITY", "TTS_MEGAKERNEL", "TTS_MEGAKERNEL_MT", "TTS_STEAL",
     "TTS_PODS", "TTS_SIM_LAT_ICI", "TTS_SIM_LAT_DCN", "TTS_NARROW",
-    "TTS_HBM_GBPS", "TTS_KERNEL_BACKEND", "TTS_PALLAS_GPU_MB",
+    "TTS_HBM_GBPS",
 )
 
 #: Matrix axes (the lb2 families add the pair-block axis).
@@ -73,7 +73,7 @@ def load_contracts() -> dict:
     and return the registry."""
     from ..engine import batched, pipeline, resident  # noqa: F401
     from ..obs import counters, phases, quality  # noqa: F401
-    from ..ops import backend, compaction, megakernel, pfsp_device  # noqa: F401
+    from ..ops import compaction, megakernel, pfsp_device  # noqa: F401
     from ..parallel import topology  # noqa: F401
     from . import guard, lockorder  # noqa: F401
 
@@ -118,9 +118,6 @@ class Cell:
     # pins the streamed pool-tile axis so the grid form (grid = M/Mt > 1)
     # gets its own audited cell.
     mt: str | None = None
-    # None = TTS_KERNEL_BACKEND unset (keys stay byte-stable); "gpu" pins
-    # the Triton flavor of the armed cycle, whose rows XLA still stitches.
-    backend: str | None = None
 
     @property
     def key(self) -> str:
@@ -131,8 +128,6 @@ class Cell:
             s += f"|mk={self.megakernel}"
         if self.mt is not None:
             s += f"|mt={self.mt}"
-        if self.backend is not None:
-            s += f"|kb={self.backend}"
         return s
 
     def env(self) -> dict[str, str]:
@@ -147,8 +142,6 @@ class Cell:
             e["TTS_MEGAKERNEL"] = self.megakernel
         if self.mt is not None:
             e["TTS_MEGAKERNEL_MT"] = self.mt
-        if self.backend is not None:
-            e["TTS_KERNEL_BACKEND"] = self.backend
         return e
 
 
@@ -205,12 +198,6 @@ def matrix_cells(families=None, compact=None, obs=None, phaseprof=None,
             out.append(Cell(fam, "auto", (obs or OBS_AXIS)[0],
                             (phaseprof or PHASEPROF_AXIS)[0], pb,
                             megakernel="force", mt="16"))
-        # The gpu flavor of the armed cycle (single tile, its rows stitched
-        # by XLA — megakernel-push-in-kernel pins both flavors' paths).
-        if fam == "nqueens":
-            out.append(Cell(fam, "auto", (obs or OBS_AXIS)[0],
-                            (phaseprof or PHASEPROF_AXIS)[0], pb,
-                            megakernel="force", backend="gpu"))
     return out
 
 
@@ -431,14 +418,6 @@ VARIANT_ENVS = {
     "steal-flat": {"TTS_STEAL": "flat"},
     "steal-hier": {"TTS_STEAL": "hier", "TTS_PODS": "2"},
     "narrow0": {"TTS_NARROW": "0"},
-    # Kernel-backend seam (ops/backend.py): auto/jnp/tpu must stay
-    # byte-identical to "off" on this non-GPU audit host; gpu may change
-    # the program body but never the step's carry signature
-    # (kernel-backend-inert).
-    "kb-auto": {"TTS_KERNEL_BACKEND": "auto"},
-    "kb-jnp": {"TTS_KERNEL_BACKEND": "jnp"},
-    "kb-tpu": {"TTS_KERNEL_BACKEND": "tpu"},
-    "kb-gpu": {"TTS_KERNEL_BACKEND": "gpu"},
 }
 
 
@@ -537,13 +516,6 @@ def cache_key_artifact(family: str) -> CacheKeyArtifact:
         "TTS_NARROW": (
             build({**base, "TTS_NARROW": "auto"}),
             build({**base, "TTS_NARROW": "0"}),
-        ),
-        # The kernel-backend flavor rides the routing token (raw knob +
-        # resolved kind), so a flip to the gpu flavor must rebuild — a
-        # stale auto program under =gpu would run the wrong kernel body.
-        "TTS_KERNEL_BACKEND": (
-            p0,
-            build({**base, "TTS_KERNEL_BACKEND": "gpu"}),
         ),
     }
     if family == "pfsp-lb2":

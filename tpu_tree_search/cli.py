@@ -564,21 +564,16 @@ def resolve_chunk_size(M, problem_name: str, tier: str, engine: str,
     shard and each shard runs the same cycle): at 50000 per shard ``auto``
     arms a 3125-tile lb2 megakernel grid whose D=4 step compiled to
     ~300 MB of code and did not finish within 540 s on four v5e chips.
-    The gpu backend gets its own explicit row, 49152: the reference's GPU
-    offload sizing is the same ``M = 50000`` pool chunk (the published
-    PFSP-on-GPU runs saturate the device with a single ~50k-node offload
-    per cycle — arXiv 2012.09511 §IV), rounded DOWN to a multiple of 8 so
-    the resident pool keeps the sublane-quantum alignment the megakernel
-    and tiled compaction gates require (50000 % 8 == 2 would refuse them).
     Everything else — explicit ``--M``, the offload engine (each chunk
     pays a ~360ms host round trip; small chunks would multiply them),
-    remaining non-TPU backends (unmeasured) and N-Queens (wide frontiers
-    fill big chunks) — keeps the
+    non-TPU backends (unmeasured) and N-Queens (wide frontiers fill big
+    chunks) — keeps the
     reference's 50000 (the per-program ``config const M = 50000`` of each
     GPU main, `pfsp_gpu_chpl.chpl:24` / `nqueens_gpu_chpl.chpl:21`; it is
     not defined in `util.chpl`). The candidate combination is
     checked BEFORE the backend so non-candidates (e.g. ``--tier seq``)
-    never touch jax."""
+    never touch jax.  ``backend`` defaults to the platform of the process
+    default device (`pallas_kernels.platform`)."""
     if M is not None:
         return M
     resident = (tier in ("mesh", "dist_mesh")
@@ -586,14 +581,10 @@ def resolve_chunk_size(M, problem_name: str, tier: str, engine: str,
     if not (problem_name == "pfsp" and resident):
         return 50000
     if backend is None:
-        from .ops import backend as BK
+        from .ops.pallas_kernels import platform
 
-        backend = BK.policy_backend()
-    if backend == "tpu":
-        return 1024
-    if backend == "gpu":
-        return 49152
-    return 50000
+        backend = platform()
+    return 1024 if backend == "tpu" else 50000
 
 
 def uses_compaction(args) -> bool:
@@ -830,13 +821,7 @@ def print_results(args, problem, res) -> None:
         tag = " (auto)" if res.compact_auto else ""
         print(f"Survivor path: {res.compact}{tag}")
     if res.kernel_backend:
-        # The resolved kernel flavor (TTS_KERNEL_BACKEND, ops/backend.py),
-        # with the raw knob when it forced a non-default resolution.
-        from .ops import backend as _BK
-
-        mode = _BK.kernel_backend_mode()
-        tag = "" if mode == "auto" else f" (forced: {mode})"
-        print(f"Kernel backend: {res.kernel_backend}{tag}")
+        print(f"Kernel backend: {res.kernel_backend}")
     if res.megakernel:
         tag = " (auto)" if res.megakernel_auto else ""
         why = f" — {res.megakernel_reason}" if res.megakernel_reason else ""
@@ -848,8 +833,7 @@ def print_results(args, problem, res) -> None:
         if res.megakernel == "on" and res.megakernel_mt:
             form = "tiled" if res.megakernel_tiled else "resident"
             tile = f", {form} Mt={res.megakernel_mt}"
-        body = f", lb2 body {res.lb2_body}" if res.lb2_body else ""
-        print(f"One-kernel cycle: {res.megakernel}{tag}{tile}{body}{why}")
+        print(f"One-kernel cycle: {res.megakernel}{tag}{tile}{why}")
     if res.k_resolved is not None:
         tag = " (auto)" if res.k_auto else ""
         print(f"Dispatch pipeline: depth={res.pipeline_depth}, "
@@ -986,20 +970,15 @@ def result_record(args, res) -> dict:
                 rec["k"] = res.k_resolved
             if res.k_auto:
                 rec["k_auto"] = True
+            # Whether the Pallas kernels ran ("tpu") or the jnp oracles
+            # ("jnp"), and whether the kernels ran in interpret mode.
+            if res.kernel_backend is not None:
+                rec["kernel_backend"] = res.kernel_backend
+                rec["kernel_interpret"] = res.kernel_interpret
             # The RESOLVED one-kernel-cycle state (engine-surfaced, like
             # "compact") — a stats line must prove whether the fused cycle
             # or the op-chain produced the number, and a refusal must say
             # why it fell back.
-            # The kernel flavor that ran (TTS_KERNEL_BACKEND seam), whether
-            # it ran in interpret mode, and the raw knob when it forced the
-            # resolution.
-            if res.kernel_backend is not None:
-                rec["kernel_backend"] = res.kernel_backend
-                rec["kernel_interpret"] = res.kernel_interpret
-                from .ops import backend as _BK
-
-                if _BK.kernel_backend_mode() != "auto":
-                    rec["kernel_backend_mode"] = _BK.kernel_backend_mode()
             if res.megakernel is not None:
                 rec["megakernel"] = res.megakernel
                 if res.megakernel_auto:
@@ -1013,8 +992,6 @@ def result_record(args, res) -> dict:
                 if res.megakernel_mt:
                     rec["megakernel_mt"] = res.megakernel_mt
                     rec["megakernel_tiled"] = res.megakernel_tiled
-                if res.lb2_body:
-                    rec["lb2_body"] = res.lb2_body
             if res.roofline is not None:
                 # Phase-profiled runs bank the memory-roofline audit
                 # (obs/roofline.py) — per-phase %-of-memory-bound-peak.

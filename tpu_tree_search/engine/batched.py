@@ -342,7 +342,6 @@ def batched_search(
                     megakernel=prog.inner.megakernel.state,
                     megakernel_auto=prog.inner.megakernel.auto,
                     megakernel_reason=prog.inner.megakernel.reason,
-                    lb2_body=prog.inner.megakernel.lb2_body,
                     k_resolved=prog.K,
                     obs=({"device_counters": sl["ctr"]}
                          if sl.get("ctr") is not None else None),

@@ -92,14 +92,10 @@ def resolve_target_band(
     profile = cm.load(path_env)
     if not profile:
         return default, None
-    # The kernel-backend seam's profile key: the raw platform under auto
-    # (byte-stable with every banked profile), a compound "platform+kind"
-    # for a forced non-native flavor so its bands never contaminate the
-    # native rows (ops/backend.profile_backend).
-    from ..ops import backend as BK
+    # Profiles are keyed by the platform of the default device.
+    from ..ops.pallas_kernels import platform
 
-    backend = BK.profile_backend()
-    hit = cm.lookup(profile, backend, topology, cm.shape_class(problem))
+    hit = cm.lookup(profile, platform(), topology, cm.shape_class(problem))
     if hit is None:
         return default, None
     key, entry = hit

@@ -184,20 +184,17 @@ class _ResidentProgram:
         # (capacity, n) exactly.
         self.row_width, self.row_pack, self.pool_rows = MK.push_layout(
             self.megakernel, n, capacity)
-        # Kernel-backend seam (TTS_KERNEL_BACKEND, ops/backend.py): the
-        # flavor this build actually runs — "jnp" when neither the cycle
-        # nor the evaluator routes to a Pallas kernel — and whether those
-        # kernels run in interpret mode. Surfaced through SearchResult;
-        # the raw knob and the resolved kind both ride routing_cache_token.
-        from ..ops import backend as _BK
+        # The route this build runs: "tpu" when the cycle or the
+        # evaluator routes to a Pallas kernel, "jnp" when neither does,
+        # and whether those kernels run in interpret mode. Surfaced
+        # through SearchResult.
         from ..ops import pallas_kernels as PK
 
         kernels = self.megakernel.enabled or self._evaluator_uses_kernels()
-        self.kernel_backend = (_BK.kernel_kind(self.device) if kernels
-                               else "jnp")
+        self.kernel_backend = "tpu" if kernels else "jnp"
         self.kernel_interpret = kernels and (
             self.megakernel.interpret if self.megakernel.enabled
-            else PK._default_interpret(self.kernel_backend))
+            else PK.pallas_interpret())
         self._step = self._build()
         self._fill = self._build_fill()
 
@@ -292,8 +289,7 @@ class _ResidentProgram:
                 # Armed one-kernel cycle (ops/megakernel.py): bound + prune
                 # + shift-compact + push run inside ONE pallas_call, which
                 # places the survivors at [size, size + tree_inc) of the
-                # pools in their own dtypes (TPU: by DMA into the aliased
-                # pools; the gpu flavor stitches its single tile in XLA).
+                # pools in their own dtypes, by DMA into the aliased pools.
                 # The engine only advances size. The phase profiler
                 # reports the collapse honestly: everything lands in
                 # `eval`, and the pop+eval+...+overflow == total telescope
@@ -1156,7 +1152,6 @@ def resident_search(
                 megakernel_reason=program.megakernel.reason,
                 megakernel_mt=program.megakernel.mt or None,
                 megakernel_tiled=program.megakernel.tiled,
-                lb2_body=program.megakernel.lb2_body,
                 kernel_backend=program.kernel_backend,
                 kernel_interpret=program.kernel_interpret,
                 pipeline_depth=depth,
@@ -1260,7 +1255,6 @@ def resident_search(
         megakernel_reason=program.megakernel.reason,
         megakernel_mt=program.megakernel.mt or None,
         megakernel_tiled=program.megakernel.tiled,
-        lb2_body=program.megakernel.lb2_body,
         kernel_backend=program.kernel_backend,
         kernel_interpret=program.kernel_interpret,
         pipeline_depth=depth,

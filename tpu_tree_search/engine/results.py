@@ -87,15 +87,10 @@ class SearchResult:
     # pool-resident form). None/False when the kernel is off.
     megakernel_mt: int | None = None
     megakernel_tiled: bool = False
-    # Resident tiers, armed lb2 builds: the pair loop the cycle compiled
-    # (ops/megakernel.py Decision.lb2_body) — "lanes" (TPU) or "matmul"
-    # (the gpu flavor). None otherwise.
-    lb2_body: str | None = None
-    # Resident tiers: the kernel flavor the build actually runs
-    # (TTS_KERNEL_BACKEND, ops/backend.py) — "tpu" (Mosaic), "gpu" (the
-    # Triton-structured lowering) or "jnp" (no Pallas kernel on the path),
-    # and whether those kernels run in Pallas interpret mode. None for
-    # tiers without a resident program.
+    # Resident tiers: the route the build runs — "tpu" (the Pallas/Mosaic
+    # kernels, `pallas_kernels.use_pallas` or an armed cycle) or "jnp" (no
+    # Pallas kernel on the path) — and whether those kernels run in Pallas
+    # interpret mode. None for tiers without a resident program.
     kernel_backend: str | None = None
     kernel_interpret: bool = False
     # Roofline audit (obs/roofline.py): per-phase %-of-memory-bound-peak

@@ -210,7 +210,7 @@ def meta_args(program) -> dict:
     the byte floors from a trace alone."""
     import numpy as np
 
-    from ..ops import backend as BK
+    from ..ops.pallas_kernels import platform
 
     vals_dt = program.pool_fields[0][1]
     aux_dt = program.pool_fields[1][1]
@@ -223,7 +223,7 @@ def meta_args(program) -> dict:
         "megakernel": bool(program.megakernel.enabled),
         "megakernel_mt": int(program.megakernel.mt),
         "megakernel_grid": int(program.megakernel.grid),
-        "backend": BK.profile_backend(program.device),
+        "backend": platform(program.device),
         "device_kind": program.device.device_kind,
     }
 

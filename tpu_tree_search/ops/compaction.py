@@ -77,14 +77,7 @@ def _auto_compact(problem, M: int | None, n: int | None, platform: str) -> str:
         on the full M*n grid, and the shift compaction's cost is flat in
         the survivor count -> ``dense`` (every backend: the CPU tiers only
         see test-sized chunks).
-      * gpu kernel backend: small grids take the same log-shift ``dense``
-        as TPU (the shift passes are coalesced row copies, the regime the
-        reference's prefix-sum compaction runs in — arXiv 2012.09511);
-        larger grids fall back to ``scatter``, which on CUDA is a real
-        parallel scatter rather than the TPU's serialized one.
-        PROVISIONAL until `bench.py pick_compact` rows land from a GPU
-        session (scripts/gpu_session.sh stage 4).
-      * other non-TPU backends: ``scatter`` is a fast gather-like op on
+      * non-TPU backends: ``scatter`` is a fast gather-like op on
         CPU and sort LOSES ~2x (the original measured default)
         -> unchanged.
       * TPU, small grids (M*n <= 64k — the tuned PFSP M=1024 class): the
@@ -96,16 +89,11 @@ def _auto_compact(problem, M: int | None, n: int | None, platform: str) -> str:
         S-proportional binary-search inverse does the least work
         -> ``search``.
 
-    ``platform`` is the policy backend (`ops/backend.policy_backend`): the
-    resolved kernel backend when it names real hardware, else the physical
-    platform — so TTS_KERNEL_BACKEND=gpu exercises the gpu rows anywhere.
+    ``platform`` is the target device's platform
+    (`pallas_kernels.platform`).
     """
     if getattr(problem, "name", None) == "nqueens":
         return "dense"
-    if platform == "gpu":
-        if M is not None and n is not None and M * n <= (1 << 16):
-            return "dense"
-        return "scatter"
     if platform != "tpu":
         return "scatter"
     if M is not None and n is not None and M * n <= (1 << 16):
@@ -127,9 +115,9 @@ def resolve_compact_mode(problem=None, M: int | None = None,
     mode = compact_mode()
     if mode != "auto":
         return mode
-    from . import backend as BK
+    from .pallas_kernels import platform
 
-    return _auto_compact(problem, M, n, BK.policy_backend(device))
+    return _auto_compact(problem, M, n, platform(device))
 
 
 def _shift_left(x, s: int):

@@ -17,8 +17,8 @@ bounds are evaluated with the same tile math as the standalone kernels
 already-pinned-exact kernel values), pruning, the LSB-first binary-shift
 survivor compaction of `ops/compaction.shift_compact`, and the push all
 happen against the tile in VMEM, and only the compacted child rows leave:
-on a TPU the kernel DMAs them, in the pool dtypes, straight into the
-donated pool (`_push_emit`).
+the kernel DMAs them, in the pool dtypes, straight into the donated pool
+(`_push_emit`).
 
 Tiling (``TTS_MEGAKERNEL_MT``, auto-resolved like `_auto_compact`):
 
@@ -40,9 +40,6 @@ Tiling (``TTS_MEGAKERNEL_MT``, auto-resolved like `_auto_compact`):
   stash) and runs prune/compact/emit against the final incumbent (at
   G == 1 too).  N-Queens has no bound pruning and keeps the single sweep
   ``grid=(G,)``.
-* The gpu flavor (Triton: no DMA into an aliased pool, no sequential
-  grid) is single-tile only and hands its (M*n, n) rows back for XLA to
-  stitch into the pool (`make_cycle`).
 * The cross-tile carry forces sequential grid order, so the full cycle
   kernels declare ``dimension_semantics=("arbitrary", ...)``; the
   evaluation-only pass has no carry and ships as a separate
@@ -59,13 +56,12 @@ Exactness:
 * lb2 is only allowed to arm when the instance passes the bf16-exactness
   gate (`PFSPDeviceTables.exact_bf16`: every processing time < 2^8, so
   every matmul operand is exactly representable in bf16) — otherwise
-  :func:`resolve` refuses and records why (banner + SearchResult).  On a
-  TPU its pair loop is int32 scalar-times-slab work on lane-dense child
-  slabs (`pallas_kernels._lb2_tile_lb`); the gpu flavor keeps the
-  max-plus closed form as bf16 MXU matmuls (`_lb2_tile_lb_matmul`).
+  :func:`resolve` refuses and records why (banner + SearchResult).  Its
+  pair loop is int32 scalar-times-slab work on lane-dense child slabs
+  (`pallas_kernels._lb2_tile_lb`).
 
 Routing (`TTS_MEGAKERNEL=auto|0|force`, resolved like the compact auto
-policy): ``auto`` arms only on a real TPU backend and when the per-tile
+policy): ``auto`` arms only on a TPU device and when the per-tile
 VMEM model fits — inside the small-M window the single-tile resident form
 is kept verbatim; past it `Mt` shrinks `_auto_tile`-style (halving, sublane
 aligned, dividing M) until each tile fits the window and the per-tile +
@@ -98,7 +94,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..analysis.contracts import contract
-from . import backend as BK
 from . import pallas_kernels as PK
 
 #: single-tile window on the Mt*n product — within it the original
@@ -147,7 +142,7 @@ _ROW_TILE = 8
 _AUX_TILE = _ROW_TILE * 128
 
 #: Nodes past ``size + M*n`` a push may touch (its last chunk and aux tile
-#: are whole; a chunk is at most 512 nodes): the armed TPU pool carries
+#: are whole; a chunk is at most 512 nodes): the armed pool carries
 #: this much slack past the capacity, so the while-cond's
 #: ``size + M*n <= C`` stays the one rule.
 PUSH_SLACK = _AUX_TILE
@@ -155,7 +150,7 @@ PUSH_SLACK = _AUX_TILE
 
 def push_pack(n: int) -> tuple[int, int]:
     """(slot width ``W``, nodes per pool row ``kq``) of the value pool the
-    TPU cycle pushes into.  A node of ``n`` values takes a slot of ``W``
+    cycle pushes into.  A node of ``n`` values takes a slot of ``W``
     lanes — ``n`` rounded up to a power of two (at least 8) — and
     ``kq = 128 // W`` nodes share one 128-lane row; past 64 values a node
     takes whole 128-lane rows alone (``kq`` 1)."""
@@ -170,18 +165,17 @@ def push_layout(decision, n: int, capacity: int) -> tuple[int, int, int]:
     pool a resident program allocates; the value pool's array is
     ``(rows // kq, kq * W)``.
 
-    The TPU cycle pushes its survivors into the pool by DMA, and Mosaic
+    The cycle pushes its survivors into the pool by DMA, and Mosaic
     slices a DMA destination only on whole (8, 128) tiles: a (C, n) pool
-    with n < 128 cannot be sliced by row at all.  So where that cycle is
+    with n < 128 cannot be sliced by row at all.  So where the cycle is
     armed the value pool is lane-dense: `push_pack`'s ``kq`` nodes to a
     128-lane row, each in a ``W``-lane slot whose lanes past ``n`` are
     dead — ``W/n`` times the bytes of a compact (C, n) pool (16/15 for
     N=15, 32/20 for a 20-job PFSP instance).  Both pools get
     :data:`PUSH_SLACK` nodes past the capacity, rounded to whole aux tiles
     so the aux pool has its (rows/128, 128) view.  Interpret mode runs the
-    same layout.  Every other build (off, refused, the gpu flavor) stores
-    (capacity, n)."""
-    if not (decision.enabled and decision.backend == "tpu"):
+    same layout.  Every other build (off, refused) stores (capacity, n)."""
+    if not decision.enabled:
         return n, 1, capacity
     width, kq = push_pack(n)
     return width, kq, PK._round_up(capacity + PUSH_SLACK, _AUX_TILE)
@@ -245,13 +239,7 @@ class Decision:
     `tts` banner and carried in SearchResult.megakernel_reason.
     ``mt``/``grid`` record the resolved pool-tile width and tile count
     (``grid == 1`` is the original single-tile resident form; ``grid > 1``
-    streams the pool through VMEM tile by tile).  ``backend`` is the
-    kernel flavor the cycle builds with (`ops/backend.py` — ``gpu`` is
-    the Triton lowering, single-tile only: the cross-tile SMEM carry
-    needs the TPU's sequential grid).  ``lb2_body`` names the pair loop an
-    armed lb2 cycle compiles: ``lanes`` on a TPU (`pallas_kernels.
-    _lb2_tile_lb`), ``matmul`` on the gpu flavor; SearchResult.lb2_body
-    carries it to the banner and the stats line."""
+    streams the pool through VMEM tile by tile)."""
 
     enabled: bool
     auto: bool
@@ -259,8 +247,6 @@ class Decision:
     reason: str | None
     mt: int = 0
     grid: int = 1
-    backend: str = "tpu"
-    lb2_body: str | None = None
 
     @property
     def state(self) -> str:
@@ -280,13 +266,10 @@ def _family(problem) -> str | None:
     return None
 
 
-def _native_kind(device) -> str | None:
-    """The kernel flavor the resolved backend compiles NATIVELY on the
-    target device — 'tpu' or 'gpu' — else None (interpret territory:
-    forced/interpret builds, CPU processes).  Replaces the old hard
-    ``platform == "tpu"`` gate with the `ops/backend.py` seam."""
-    b = BK.resolve_backend(device)
-    return b.kind if (b.native and b.kind in ("tpu", "gpu")) else None
+def _native_kind(device) -> bool:
+    """True when the target device is a TPU, where the cycle compiles;
+    elsewhere it can only run interpreted."""
+    return PK.platform(device) == "tpu"
 
 
 def _push_bytes(mt: int, n: int, pool_itemsize: int) -> int:
@@ -308,8 +291,7 @@ def _push_bytes(mt: int, n: int, pool_itemsize: int) -> int:
 
 
 def _mega_pool_bytes(M: int, n: int, pool_itemsize: int = 4,
-                     mt: int | None = None, lb_stash: bool = False,
-                     push: bool = True) -> int:
+                     mt: int | None = None, lb_stash: bool = False) -> int:
     """The cycle's VMEM charge on top of the bound kernels' own
     `_model_bytes` model (the ``extra_bytes`` the feasibility gate adds).
 
@@ -327,11 +309,11 @@ def _mega_pool_bytes(M: int, n: int, pool_itemsize: int = 4,
     charge that scales with M, not Mt, and therefore the one that can
     still refuse a shape.
 
-    ``push`` (the TPU flavor) charges the in-kernel push's staging
-    (`_push_bytes`) — it replaced the streamed (Mt*n, n) int32 out blocks —
-    and, single-tile too, the PFSP stash (its grid is two-phase there as
-    well).  ``pool_itemsize`` charges the pool-dtype tiles at their
-    storage width; the in-kernel intermediates stay int32/f32."""
+    Both forms charge the in-kernel push's staging (`_push_bytes`), and
+    with ``lb_stash`` the single tile charges the PFSP stash too (its
+    grid is two-phase there as well).  ``pool_itemsize`` charges the
+    pool-dtype tiles at their storage width; the in-kernel intermediates
+    stay int32/f32."""
     r8, r128 = PK._r8, PK._r128
     if mt is not None and mt >= M:
         mt = None
@@ -344,11 +326,9 @@ def _mega_pool_bytes(M: int, n: int, pool_itemsize: int = 4,
         # popped pool tile + its narrow copy, keep plane, scalar lanes
         io = (2 * r8(M) * r128(n) * pool_itemsize
               + r8(M) * r128(n) * 4 + 128 * 4)
-        total = sel + flat + cols + tri + io
-        if push:
-            total += _push_bytes(M, n, pool_itemsize)
-            if lb_stash:
-                total += r8(M) * r128(n) * 4
+        total = sel + flat + cols + tri + io + _push_bytes(M, n, pool_itemsize)
+        if lb_stash:
+            total += r8(M) * r128(n) * 4
         return total
     G = M // mt
     Mtn = mt * n
@@ -378,12 +358,12 @@ def _tile_window_ok(fam: str, mt: int, n: int) -> bool:
     return mt * n <= SMALL_M_LIMIT
 
 
-def _fits(problem, fam: str, M: int, n: int, mt: int | None = None,
-          backend: str = "tpu") -> tuple[bool, str | None]:
-    """Fast-memory feasibility at pool-tile width ``mt`` (None or M — the
+def _fits(problem, fam: str, M: int, n: int,
+          mt: int | None = None) -> tuple[bool, str | None]:
+    """VMEM feasibility at pool-tile width ``mt`` (None or M — the
     single-tile resident form; smaller — the streamed per-tile +
     double-buffer + stash charge of `_mega_pool_bytes`), against the
-    backend's budget (`pallas_kernels._vmem_limit_bytes`)."""
+    budget (`pallas_kernels._vmem_limit_bytes`)."""
     from ..problems.base import narrow_enabled
 
     if mt is not None and mt >= M:
@@ -391,8 +371,7 @@ def _fits(problem, fam: str, M: int, n: int, mt: int | None = None,
     t = mt or M
     itemsize = _pool_itemsize(fam, n) if narrow_enabled() else 4
     extra = _mega_pool_bytes(M, n, itemsize, mt=mt,
-                             lb_stash=(fam != "nqueens"),
-                             push=(backend == "tpu"))
+                             lb_stash=(fam != "nqueens"))
     if fam == "nqueens":
         need = PK._model_bytes(t, n, 1, extra, 3)
     elif fam == "lb1":
@@ -403,15 +382,10 @@ def _fits(problem, fam: str, M: int, n: int, mt: int | None = None,
         m = problem.machines
         P = problem.lb2_data.pairs.shape[0]
         pg = PD.lb2_kernel_pair_group(P, n)
-        if backend == "gpu":  # the matmul pair body
-            need = PK._model_bytes(
-                t, n, m, extra + PK._lb2_static_extra(n, m, P + (-P) % pg),
-                3, pair_copies=5, pair_group=pg,
-            )
-        else:  # the lane-dense pair body: its tables stream through SMEM
-            need = PK._model_bytes(
-                t, n, m, extra, 3, lane_slabs=PK._lb2_lane_slabs(n, m, pg))
-    budget = PK._vmem_budget(backend)
+        # The lane-dense pair body: its tables stream through SMEM.
+        need = PK._model_bytes(
+            t, n, m, extra, 3, lane_slabs=PK._lb2_lane_slabs(n, m, pg))
+    budget = PK._vmem_budget()
     if need > budget:
         if mt is None:
             return False, (
@@ -451,40 +425,26 @@ def resolve(problem, M: int, device=None, mp_axis: str | None = None,
     """Resolve the megakernel routing for one resident program build —
     the `_auto_compact`-style policy.  Correctness refusals (unsupported
     bound family, mp pair sharding, the lb2 bf16-exactness gate, tile
-    misalignment — including a TTS_MEGAKERNEL_MT that does not divide M,
-    and tiled streaming on the gpu flavor, whose cross-tile SMEM carry
-    only the TPU's sequential grid can run) hold even under ``force``;
-    the remaining gates (native TPU/GPU backend, per-tile memory fit)
-    apply to ``auto`` only.  An armed lb2 decision names its pair body
-    (`Decision.lb2_body`)."""
-    dec = _resolve(problem, M, device, mp_axis, mp_size)
-    if dec.enabled and _family(problem) == "lb2":
-        dec = dataclasses.replace(
-            dec, lb2_body="matmul" if dec.backend == "gpu" else "lanes")
-    return dec
-
-
-def _resolve(problem, M: int, device, mp_axis: str | None,
-             mp_size: int) -> Decision:
+    misalignment — including a TTS_MEGAKERNEL_MT that does not divide M)
+    hold even under ``force``; the remaining gates (a TPU device, per-tile
+    memory fit) apply to ``auto`` only."""
     mode = megakernel_mode()
     if mode == "0":
         return Decision(False, False, False, None)
     auto = mode == "auto"
     fam = _family(problem)
     n = int(problem.child_slots)
-    kb = BK.kernel_kind(device)  # 'gpu' only when the seam resolves gpu
     if fam not in ("nqueens", "lb1", "lb2"):
         return Decision(False, auto, False,
                         f"unsupported bound family {fam!r} (the megakernel "
-                        "ports nqueens/lb1/lb2 only)", backend=kb)
+                        "ports nqueens/lb1/lb2 only)")
     if mp_axis is not None or mp_size > 1:
         return Decision(False, auto, False,
                         "mp pair-axis sharding (the fused cycle is "
-                        "single-shard)", backend=kb)
+                        "single-shard)")
     if M % 8 != 0:
         return Decision(False, auto, False,
-                        f"M={M} not a multiple of the sublane quantum (8)",
-                        backend=kb)
+                        f"M={M} not a multiple of the sublane quantum (8)")
     if fam == "lb2":
         t = problem.device_tables()
         if not getattr(t, "exact_bf16", False):
@@ -492,42 +452,19 @@ def _resolve(problem, M: int, device, mp_axis: str | None,
                             "lb2 bf16-exactness gate: max processing time "
                             ">= 256, the max-plus MXU formulation is not "
                             "bit-exact (f32 pair-blocked oracle keeps the "
-                            "cycle)", backend=kb)
+                            "cycle)")
     mt_env = megakernel_mt()
     if mt_env is not None and (mt_env % 8 != 0 or M % mt_env != 0):
         return Decision(False, auto, False,
                         f"TTS_MEGAKERNEL_MT={mt_env} must be a multiple of "
-                        f"the sublane quantum (8) and divide M={M}",
-                        backend=kb)
-    if kb == "gpu" and mt_env is not None and mt_env < M:
-        return Decision(False, auto, False,
-                        f"gpu backend: TTS_MEGAKERNEL_MT={mt_env} < M={M} "
-                        "requests tiled streaming, whose cross-tile SMEM "
-                        "carry needs the TPU's sequential grid (Triton "
-                        "blocks are parallel)", backend=kb)
+                        f"the sublane quantum (8) and divide M={M}")
     native = _native_kind(device)
     if not auto:
-        interpret = PK.pallas_interpret() or native is None
-        if kb == "gpu":
-            return Decision(True, False, interpret, None, mt=M, grid=1,
-                            backend="gpu")
+        interpret = PK.pallas_interpret() or not native
         mt = mt_env or _resolve_mt(problem, fam, M, n) or M
         return Decision(True, False, interpret, None, mt=mt, grid=M // mt)
-    if native is None or PK.pallas_interpret():
-        reason = ("auto: not on a TPU backend" if kb != "gpu" else
-                  "auto: kernel backend gpu is not native here (no GPU in "
-                  "this process — TTS_MEGAKERNEL=force runs it interpreted)")
-        return Decision(False, True, False, reason, backend=kb)
-    if kb == "gpu":
-        # Single tile or nothing: tiled streaming is a TPU-only construct.
-        ok, why = _fits(problem, fam, M, n, backend="gpu")
-        if _tile_window_ok(fam, M, n) and ok:
-            return Decision(True, True, False, None, mt=M, grid=1,
-                            backend="gpu")
-        why = why or (
-            f"gpu backend: M*n={M * n} exceeds the single-tile window and "
-            "tiled streaming needs the TPU's sequential-grid SMEM carry")
-        return Decision(False, True, False, why, backend="gpu")
+    if not native or PK.pallas_interpret():
+        return Decision(False, True, False, "auto: not on a TPU backend")
     if mt_env is not None:
         ok, why = _fits(problem, fam, M, n, mt_env)
         if not ok:
@@ -572,8 +509,8 @@ def _scalar_lanes(tree_inc, sol_inc, best, pushed=0):
     )
 
 
-def _compact_push(vals, aux, d, keep, *, n: int, M: int, shift=None,
-                  width: int | None = None):
+def _compact_push(vals, aux, d, keep, *, n: int, M: int, shift,
+                  width: int):
     """Survivor compaction entirely in VMEM, as one-hot MXU matmuls.
 
     Survivor ranks are exclusive prefix counts (triangular matmuls over
@@ -587,31 +524,25 @@ def _compact_push(vals, aux, d, keep, *, n: int, M: int, shift=None,
     on ``(M*n, n)`` tiles.  Nothing is reshaped between sublanes and lanes
     (Mosaic refuses those shape casts) and no slice is unaligned.
 
-    Returns (rows (Mn, n) i32, caux (Mn, 1) i32, tree_inc) with rows
-    beyond ``tree_inc`` zero (dead by the pool contract — the engine
-    advances ``size`` by tree_inc only).  On the streamed path this runs
-    per tile at ``M = Mt``; the cross-tile offset carry makes the
-    concatenation of tiles the dense-mode global order.
-
-    The TPU push passes ``shift`` (its destination's node offset inside
-    an 8-row pool tile, traced) and ``width`` (the pool's slot width):
-    output row ``k`` then holds survivor ``k - shift`` over
-    `_push_sizes`' ``rc`` rows — rows below ``shift`` empty, for the pool
-    nodes already there — and the value rows come ``width`` lanes wide,
-    lanes past ``n`` zero: one pool slot each, still with nothing
-    reshaped."""
+    ``shift`` is the push destination's node offset inside an 8-row pool
+    tile (traced) and ``width`` the pool's slot width.  Returns (rows
+    (rc, width) i32, caux (rc, 1) i32, tree_inc) over `_push_sizes`'
+    ``rc`` rows: row ``k`` holds survivor ``k - shift`` — rows below
+    ``shift`` empty, for the pool nodes already there, and rows past the
+    survivors zero (dead by the pool contract — the engine advances
+    ``size`` by tree_inc only) — with lanes past ``n`` zero: one pool slot
+    each, still with nothing reshaped.  On the streamed path this runs per
+    tile at ``M = Mt``; the cross-tile offset carry makes the concatenation
+    of tiles the dense-mode global order."""
     i32, f32 = jnp.int32, jnp.float32
-    Mn = M * n
-    R = Mn if shift is None else _push_sizes(M, n)[0]
-    W = n if width is None else width
+    R, W = _push_sizes(M, n)[0], width
     keep_f = keep.astype(f32)  # (M, n)
     # Every operand value of the big selection matmuls (0/1 masks, lane
     # ranks < n, job or column indices < n) is exact in bf16 when n < 256.
     small = n < 256
 
     def ranks(shape):
-        r = jax.lax.broadcasted_iota(i32, shape, 0)
-        return (r if shift is None else r - shift).astype(f32)
+        return (jax.lax.broadcasted_iota(i32, shape, 0) - shift).astype(f32)
 
     # Exclusive prefix counts, within the row along lanes and across
     # parents.  Counts are < 2^24, so f32 accumulation is exact.
@@ -675,7 +606,7 @@ def _compact_push(vals, aux, d, keep, *, n: int, M: int, shift=None,
 
 
 def _pfsp_epilogue(prmu, limit1, valid, best, lb, *, n: int, M: int,
-                   shift=None, width: int | None = None):
+                   shift, width: int):
     """The `_PFSPResident` evaluate fold (open/leaf/incumbent/keep — the
     unstaged branch; see the staged-equivalence note in `make_cycle`) +
     compaction.  ``lb`` int32 per child slot; swap position and child
@@ -708,176 +639,8 @@ def _pfsp_leaf_min(limit1, valid, lb, *, n: int, M: int):
 
 
 # ---------------------------------------------------------------------------
-# gpu flavor — single tile, rows handed back to XLA (Triton has no DMA
-# into an aliased pool; the engine-side stitch lives in `make_cycle`)
-# ---------------------------------------------------------------------------
-
-
-def _mega_nqueens_kernel(board_ref, depth_ref, valid_ref, best_ref,
-                         out_vals_ref, out_aux_ref, scal_ref,
-                         *, N: int, g: int, M: int):
-    board = board_ref[:].astype(jnp.int32)  # (M, N)
-    depth = depth_ref[:, 0].astype(jnp.int32)  # (M,)
-    valid = valid_ref[:, 0] != 0
-    best = best_ref[0]
-    with jax.named_scope(BOUND_SCOPE):
-        labels = PK._nqueens_tile_labels(board, depth, N=N, g=g)
-    # The `_NQueensResident` evaluate fold: swap position is the depth.
-    keep = labels & valid[:, None] & (depth < N)[:, None]
-    sol_inc = jnp.sum(valid & (depth == N), dtype=jnp.int32)
-    with jax.named_scope(COMPACT_SCOPE):
-        rows, caux, tree_inc = _compact_push(board, depth, depth, keep,
-                                             n=N, M=M)
-    out_vals_ref[:] = rows
-    out_aux_ref[:] = caux
-    scal_ref[:] = _scalar_lanes(tree_inc, sol_inc, best)
-
-
-def _mega_lb1_kernel_gpu(prmu_ref, limit1_ref, valid_ref, best_ref,
-                         ptm_ref, heads_ref, tails_ref,
-                         out_vals_ref, out_aux_ref, scal_ref,
-                         *, n: int, m: int, M: int, bf16: bool):
-    """The lb1 cycle without a scan scratch (`pallas_kernels._front_scan`
-    unrolls statically where the TPU kernel staged; the epilogue is
-    shift/select math, backend-neutral)."""
-    prmu = prmu_ref[:].astype(jnp.int32)
-    limit1 = limit1_ref[:, 0].astype(jnp.int32)
-    valid = valid_ref[:, 0] != 0
-    ptm = ptm_ref[:].astype(jnp.float32)
-    with jax.named_scope(BOUND_SCOPE):
-        lb = PK._lb1_tile_lb(prmu, limit1, ptm, heads_ref[:], tails_ref[:],
-                             None, n=n, m=m, bf16=bf16)
-    with jax.named_scope(COMPACT_SCOPE):
-        rows, caux, tree_inc, sol_inc, best = _pfsp_epilogue(
-            prmu, limit1, valid, best_ref[0], lb, n=n, M=M)
-    out_vals_ref[:] = rows
-    out_aux_ref[:] = caux
-    scal_ref[:] = _scalar_lanes(tree_inc, sol_inc, best)
-
-
-def _mega_lb2_kernel_gpu(prmu_ref, limit1_ref, valid_ref, best_ref,
-                         ptm_ref, heads_ref,
-                         p0_ref, p1_ref, lag_ref, t0_ref, t1_ref,
-                         msel0_ref, msel1_ref, jorder_ref,
-                         out_vals_ref, out_aux_ref, scal_ref,
-                         *, n: int, m: int, P: int, M: int, pg: int,
-                         bf16: bool):
-    prmu = prmu_ref[:].astype(jnp.int32)
-    limit1 = limit1_ref[:, 0].astype(jnp.int32)
-    valid = valid_ref[:, 0] != 0
-    ptm = ptm_ref[:].astype(jnp.float32)
-    with jax.named_scope(BOUND_SCOPE):
-        lb = PK._lb2_tile_lb_matmul(
-            prmu, limit1, ptm, heads_ref[:],
-            p0_ref, p1_ref, lag_ref, t0_ref, t1_ref, msel0_ref, msel1_ref,
-            jorder_ref, None, n=n, m=m, P=P, pg=pg, bf16=bf16,
-        ).astype(jnp.int32)
-    with jax.named_scope(COMPACT_SCOPE):
-        rows, caux, tree_inc, sol_inc, best = _pfsp_epilogue(
-            prmu, limit1, valid, best_ref[0], lb, n=n, M=M)
-    out_vals_ref[:] = rows
-    out_aux_ref[:] = caux
-    scal_ref[:] = _scalar_lanes(tree_inc, sol_inc, best)
-
-
-def _gpu_out(M: int, n: int):
-    """The gpu flavor's out plumbing: the (Mn, n) compacted rows, the
-    (Mn, 1) aux column and the scalar row, whole (grid=(1,))."""
-    Mn = M * n
-    full = lambda i: (0, 0)
-    shapes = (
-        jax.ShapeDtypeStruct((Mn, n), jnp.int32),
-        jax.ShapeDtypeStruct((Mn, 1), jnp.int32),
-        jax.ShapeDtypeStruct((1, 128), jnp.int32),
-    )
-    specs = (
-        PK._bs((Mn, n), full, backend="gpu"),
-        PK._bs((Mn, 1), full, backend="gpu"),
-        PK._bs((1, 128), full, backend="gpu"),
-    )
-    return shapes, specs
-
-
-def _gpu_chunk_specs(M: int, n: int):
-    full = lambda i: (0, 0)
-    return [
-        PK._bs((M, n), full, backend="gpu"),   # vals
-        PK._bs((M, 1), full, backend="gpu"),   # aux
-        PK._bs((M, 1), full, backend="gpu"),   # valid
-        PK._bs((1,), lambda i: (0,), space="smem", backend="gpu"),  # best
-    ]
-
-
-@lru_cache(maxsize=None)
-def _nqueens_gpu_call(N: int, g: int, M: int, interpret: bool):
-    shapes, out_specs = _gpu_out(M, N)
-    return pl.pallas_call(
-        partial(_mega_nqueens_kernel, N=N, g=g, M=M),
-        out_shape=shapes,
-        name="tts_cycle_nqueens",
-        grid=(1,),
-        in_specs=_gpu_chunk_specs(M, N),
-        out_specs=out_specs,
-        compiler_params=PK._compiler_params(backend="gpu"),
-        interpret=interpret,
-    )
-
-
-@lru_cache(maxsize=None)
-def _lb1_gpu_call(n: int, m: int, M: int, bf16: bool, interpret: bool):
-    full = lambda i: (0, 0)
-    shapes, out_specs = _gpu_out(M, n)
-    return pl.pallas_call(
-        partial(_mega_lb1_kernel_gpu, n=n, m=m, M=M, bf16=bf16),
-        out_shape=shapes,
-        name="tts_cycle_lb1",
-        grid=(1,),
-        in_specs=_gpu_chunk_specs(M, n) + [
-            PK._bs((n, m), full, backend="gpu"),
-            PK._bs((1, m), full, backend="gpu"),
-            PK._bs((1, m), full, backend="gpu"),
-        ],
-        out_specs=out_specs,
-        compiler_params=PK._compiler_params(backend="gpu"),
-        interpret=interpret,
-    )
-
-
-@lru_cache(maxsize=None)
-def _lb2_gpu_call(n: int, m: int, P: int, M: int, pg: int, bf16: bool,
-                  interpret: bool):
-    full = lambda i: (0, 0)
-    full3 = lambda i: (0, 0, 0)
-    bs = partial(PK._bs, backend="gpu")
-    shapes, out_specs = _gpu_out(M, n)
-    return pl.pallas_call(
-        partial(_mega_lb2_kernel_gpu, n=n, m=m, P=P, M=M, pg=pg, bf16=bf16),
-        out_shape=shapes,
-        name="tts_cycle_lb2",
-        grid=(1,),
-        in_specs=_gpu_chunk_specs(M, n) + [
-            bs((n, m), full),
-            bs((1, m), full),
-            # Per-pair table layout matches `_lb2_call` exactly — see the
-            # leading-axis / SMEM notes there.
-            bs((P, 1, n), full3),
-            bs((P, 1, n), full3),
-            bs((P, 1, n), full3),
-            bs((P,), lambda i: (0,), space="smem"),
-            bs((P,), lambda i: (0,), space="smem"),
-            bs((P, 1, m), full3),
-            bs((P, 1, m), full3),
-            bs((P, n, n), full3),
-        ],
-        out_specs=out_specs,
-        compiler_params=PK._compiler_params(backend="gpu"),
-        interpret=interpret,
-    )
-
-
-# ---------------------------------------------------------------------------
-# TPU flavor — the pool tiles stream through VMEM and the kernel pushes
-# each tile's survivors into the donated pool by DMA
+# the cycle kernels — the pool tiles stream through VMEM and the kernel
+# pushes each tile's survivors into the donated pool by DMA
 # ---------------------------------------------------------------------------
 #
 # One grid step per pool tile (``grid=(G,)``; PFSP ``(2, G)``, see the
@@ -1240,7 +1003,7 @@ def _mega_lb2_push_kernel(prmu_ref, limit1_ref, valid_ref, best_ref,
 
 
 # ---------------------------------------------------------------------------
-# pallas_call factories (TPU flavor)
+# pallas_call factories
 # ---------------------------------------------------------------------------
 
 
@@ -1410,12 +1173,9 @@ def make_cycle(problem, M: int, device, decision: Decision,
     nodes the push wrote.  ``sharded``: the cycle runs inside the mesh
     tier's shard_map (how interpret mode runs it, `_interpret_mode`).
 
-    TPU flavor: the kernel writes the pools itself (aliased, laid out by
+    The kernel writes the pools itself (aliased, laid out by
     `push_layout`); the aux pool goes in through its (rows/128, 128) view,
-    a bitcast.  gpu flavor (single tile, no DMA into an aliased pool): the
-    kernel hands back the (Mn, n) int32 rows and aux column and XLA
-    stitches them in at ``size`` in the pool dtypes; ``push_rows`` is the
-    whole Mn reservation it writes.
+    a bitcast.
 
     lb2 note: the kernel always evaluates the UNSTAGED fold, even when the
     two-pass staged evaluator is enabled for the jnp path.  They are
@@ -1424,34 +1184,26 @@ def make_cycle(problem, M: int, device, decision: Decision,
     the staged keep ``open & ~leaf & (lb1 < best) & (lb2 < best)``
     equals the unstaged ``open & ~leaf & (lb2 < best)``.
     """
-    from jax import lax
-
     fam = _family(problem)
-    interpret = decision.interpret
     mt = decision.mt or M
     n = int(problem.child_slots)
-    gpu = decision.backend == "gpu"
 
     if fam == "nqueens":
         tables = ()
-        if gpu:
-            call = _nqueens_gpu_call(problem.N, problem.g, M, interpret)
-        else:
-            def push_call(W, rows, vdt, adt, mode):
-                return _nqueens_push_call(problem.N, problem.g, M, mt, W,
-                                          rows, vdt, adt, mode)
+
+        def push_call(W, rows, vdt, adt, mode):
+            return _nqueens_push_call(problem.N, problem.g, M, mt, W,
+                                      rows, vdt, adt, mode)
     else:
         t = problem.device_tables()
         m = problem.machines
         bf16 = bool(getattr(t, "exact_bf16", False))
         if fam == "lb1":
             tables = (t.ptm_t, t.min_heads[None, :], t.min_tails[None, :])
-            if gpu:
-                call = _lb1_gpu_call(n, m, M, bf16, interpret)
-            else:
-                def push_call(W, rows, vdt, adt, mode):
-                    return _lb1_push_call(n, m, M, mt, bf16, W, rows, vdt,
-                                          adt, mode)
+
+            def push_call(W, rows, vdt, adt, mode):
+                return _lb1_push_call(n, m, M, mt, bf16, W, rows, vdt,
+                                      adt, mode)
         else:
             # lb2 — Johnson-ordered tables resolved exactly like
             # `pfsp_lb2_bounds` (device cache when eager, numpy constants
@@ -1464,34 +1216,17 @@ def make_cycle(problem, M: int, device, decision: Decision,
                        else t.johnson_ordered_mp(pg))
             Pp = ordered.lag_o.shape[0]
             tables = (t.ptm_t, t.min_heads[None, :],
-                      *PK.lb2_kernel_tables(ordered, pg, decision.backend))
-            if gpu:
-                call = _lb2_gpu_call(n, m, Pp, M, pg, bf16, interpret)
-            else:
-                def push_call(W, rows, vdt, adt, mode):
-                    return _lb2_push_call(n, m, Pp, M, mt, pg, bf16, W, rows,
-                                          vdt, adt, mode)
+                      *PK.lb2_kernel_tables(ordered, pg))
+
+            def push_call(W, rows, vdt, adt, mode):
+                return _lb2_push_call(n, m, Pp, M, mt, pg, bf16, W, rows,
+                                      vdt, adt, mode)
 
     def chunk(vals_c, aux_c, valid, best):
         return (vals_c, aux_c[:, None], valid.astype(jnp.int32)[:, None],
                 jnp.reshape(best, (1,)))
 
-    if gpu:
-        def cycle(pool_vals, pool_aux, size, vals_c, aux_c, valid, best):
-            rows, caux, scal = call(*chunk(vals_c, aux_c, valid, best),
-                                    *tables)
-            with jax.named_scope("tts_push"):
-                pool_vals = lax.dynamic_update_slice(
-                    pool_vals, rows.astype(pool_vals.dtype),
-                    (size, jnp.int32(0)))
-                pool_aux = lax.dynamic_update_slice(
-                    pool_aux, caux[:, 0].astype(pool_aux.dtype), (size,))
-            return (pool_vals, pool_aux, scal[0, 0], scal[0, 1], scal[0, 2],
-                    jnp.int32(M * n))
-
-        return cycle
-
-    mode = _interpret_mode(interpret, sharded)
+    mode = _interpret_mode(decision.interpret, sharded)
 
     def cycle(pool_vals, pool_aux, size, vals_c, aux_c, valid, best):
         rows = pool_aux.shape[0]
@@ -1526,17 +1261,10 @@ def _eval_lb1_kernel(prmu_ref, limit1_ref, ptm_ref, heads_ref, tails_ref,
         scan_ref, n=n, m=m, bf16=bf16)
 
 
-def _eval_lb1_kernel_gpu(prmu_ref, limit1_ref, ptm_ref, heads_ref, tails_ref,
-                         out_ref, *, n: int, m: int, bf16: bool):
-    _eval_lb1_kernel(prmu_ref, limit1_ref, ptm_ref, heads_ref, tails_ref,
-                     out_ref, None, n=n, m=m, bf16=bf16)
-
-
 @lru_cache(maxsize=None)
-def _eval_nqueens_call(N: int, g: int, B: int, mt: int, interpret: bool,
-                       backend: str = "tpu"):
+def _eval_nqueens_call(N: int, g: int, B: int, mt: int, interpret: bool):
     tm = lambda i: (i, 0)
-    bs = partial(PK._bs, backend=backend)
+    bs = PK._bs
     return pl.pallas_call(
         partial(_eval_nqueens_kernel, N=N, g=g),
         out_shape=jax.ShapeDtypeStruct((B, N), jnp.int32),
@@ -1544,20 +1272,19 @@ def _eval_nqueens_call(N: int, g: int, B: int, mt: int, interpret: bool,
         grid=(B // mt,),
         in_specs=[bs((mt, N), tm), bs((mt, 1), tm)],
         out_specs=bs((mt, N), tm),
-        compiler_params=PK._compiler_params(parallel=True, backend=backend),
+        compiler_params=PK._compiler_params(parallel=True),
         interpret=interpret,
     )
 
 
 @lru_cache(maxsize=None)
 def _eval_lb1_call(n: int, m: int, B: int, mt: int, bf16: bool,
-                   interpret: bool, backend: str = "tpu"):
+                   interpret: bool):
     tm = lambda i: (i, 0)
     full = lambda i: (0, 0)
-    bs = partial(PK._bs, backend=backend)
-    kernel = _eval_lb1_kernel_gpu if backend == "gpu" else _eval_lb1_kernel
+    bs = PK._bs
     return pl.pallas_call(
-        partial(kernel, n=n, m=m, bf16=bf16),
+        partial(_eval_lb1_kernel, n=n, m=m, bf16=bf16),
         out_shape=jax.ShapeDtypeStruct((B, n), jnp.int32),
         name="tts_eval_lb1",
         grid=(B // mt,),
@@ -1569,9 +1296,8 @@ def _eval_lb1_call(n: int, m: int, B: int, mt: int, bf16: bool,
             bs((1, m), full),
         ],
         out_specs=bs((mt, n), tm),
-        scratch_shapes=PK._scratch(backend,
-                                   pltpu.VMEM((n, mt, m), jnp.int32)),
-        compiler_params=PK._compiler_params(parallel=True, backend=backend),
+        scratch_shapes=[pltpu.VMEM((n, mt, m), jnp.int32)],
+        compiler_params=PK._compiler_params(parallel=True),
         interpret=interpret,
     )
 
@@ -1595,19 +1321,18 @@ def streamed_eval_bounds(problem, vals, aux, mt: int | None = None,
         raise ValueError(
             f"streamed_eval_bounds: tile {mt} must divide B={B} and be a "
             "multiple of the sublane quantum (8)")
-    kb = BK.kernel_kind(None)
     if interpret is None:
-        interpret = PK.pallas_interpret() or _native_kind(None) is None
+        interpret = PK.pallas_interpret() or not _native_kind(None)
     vals_c = jnp.asarray(vals).astype(jnp.int32)
     aux_c = jnp.asarray(aux).astype(jnp.int32)[:, None]
     if fam == "nqueens":
-        call = _eval_nqueens_call(problem.N, problem.g, B, mt, interpret, kb)
+        call = _eval_nqueens_call(problem.N, problem.g, B, mt, interpret)
         return call(vals_c, aux_c)
     t = problem.device_tables()
     n, m = problem.jobs, problem.machines
     bf16 = bool(getattr(t, "exact_bf16", False))
     if fam == "lb1":
-        call = _eval_lb1_call(n, m, B, mt, bf16, interpret, kb)
+        call = _eval_lb1_call(n, m, B, mt, bf16, interpret)
         return call(vals_c, aux_c, t.ptm_t, t.min_heads[None, :],
                     t.min_tails[None, :])
     from . import pfsp_device as PD
@@ -1619,14 +1344,13 @@ def streamed_eval_bounds(problem, vals, aux, mt: int | None = None,
     Pp = ordered.lag_o.shape[0]
     # The carry-free lb2 pass: the standalone kernel at tile ``mt`` with
     # every grid axis Megacore-parallel.
-    call = PK._lb2_call(n, m, Pp, B, mt, interpret, bf16, pg, kb,
-                        parallel=True)
+    call = PK._lb2_call(n, m, Pp, B, mt, interpret, bf16, pg, parallel=True)
     return call(vals_c, aux_c, t.ptm_t, t.min_heads[None, :],
-                *PK.lb2_kernel_tables(ordered, pg, kb))
+                *PK.lb2_kernel_tables(ordered, pg))
 
 
 def megakernel_lb2_bounds(prmu, limit1, tables, interpret: bool | None = None):
-    """The lb2 bound values the TPU megakernel arms with, as a standalone
+    """The lb2 bound values the megakernel arms with, as a standalone
     (B, n) call — the shared `_lb2_tile_lb` body with the prologue's bf16
     one-hot gathers.  The bf16-exactness gate test bit-compares this
     against the f32 pair-blocked oracle (`pfsp_device._lb2_chunk`) on real
@@ -1753,11 +1477,10 @@ def _outer_eqns(jaxpr, out=None):
 
 @contract(
     "megakernel-push-in-kernel",
-    claim="the armed TPU cycle places its survivors itself: outside the "
+    claim="the armed cycle places its survivors itself: outside the "
           "kernel the loop body holds no dynamic_update_slice on a pool and "
           "no convert_element_type of an (M*n, ...) array, and the kernel "
-          "aliases both pool operands to its outputs; the gpu flavor keeps "
-          "its single-tile XLA stitch, one dynamic_update_slice per pool",
+          "aliases both pool operands to its outputs",
     artifact="resident-step",
     applies=lambda cell: cell is not None
     and getattr(cell, "megakernel", None) == "force",
@@ -1773,19 +1496,14 @@ def _contract_megakernel_push_in_kernel(art, cell):
     dus = [e for name, e in eqns if name == "dynamic_update_slice"
            and tuple(e.invars[0].aval.shape) in pools]
     out = []
-    if dec.backend == "gpu":
-        if len(dus) != 2:
-            out.append(f"gpu flavor stitches with {len(dus)} pool "
-                       "dynamic_update_slice eqns (expected one per pool)")
-        return out
     if dus:
-        out.append(f"armed TPU loop body writes a pool with {len(dus)} "
+        out.append(f"armed loop body writes a pool with {len(dus)} "
                    "dynamic_update_slice eqn(s): the kernel must push")
     wide = [tuple(e.invars[0].aval.shape) for name, e in eqns
             if name == "convert_element_type" and e.invars[0].aval.ndim
             and e.invars[0].aval.shape[0] == Mn]
     if wide:
-        out.append(f"armed TPU loop body converts (M*n, ...) arrays {wide}")
+        out.append(f"armed loop body converts (M*n, ...) arrays {wide}")
     calls = [e for name, e in eqns if name == "pallas_call"]
     want = {tuple(prog.pool_shapes()[0]), (prog.pool_rows // 128, 128)}
     for e in calls:
@@ -1812,10 +1530,9 @@ def _loop_bodies(jaxpr, out=None):
 
 @contract(
     "megakernel-lb2-lanes",
-    claim="the armed TPU lb2 cycle runs the lane-dense pair loop: the "
-          "decision names it (lanes), the kernel takes no (P, n, n) "
-          "one-hot order operand, and no loop inside it holds a "
-          "dot_general or a reduction along the lanes",
+    claim="the armed lb2 cycle runs the lane-dense pair loop: the "
+          "kernel takes no (P, n, n) one-hot order operand, and no loop "
+          "inside it holds a dot_general or a reduction along the lanes",
     artifact="resident-step",
     applies=lambda cell: cell is not None
     and getattr(cell, "megakernel", None) == "force"
@@ -1826,12 +1543,9 @@ def _contract_megakernel_lb2_lanes(art, cell):
 
     prog = art.prog
     dec = getattr(prog, "megakernel", None)
-    if dec is None or not dec.enabled or dec.backend == "gpu":
+    if dec is None or not dec.enabled:
         return []
     out = []
-    if dec.lb2_body != "lanes":
-        out.append(f"armed TPU lb2 decision names body {dec.lb2_body!r}, "
-                   "not lanes")
     n = int(prog.problem.child_slots)
     for name, e in _outer_eqns(art.jaxpr):
         if name != "pallas_call":

@@ -50,17 +50,13 @@ def make_core(N: int, g: int = 1):
 
 
 def make_labels(N: int, g: int = 1, device=None):
-    """Routed safety evaluator: Pallas kernel when the target device
-    natively compiles the resolved kernel flavor (`pallas_kernels.py` /
-    `ops/backend.py` — the gpu flavor also routes forced-interpret), the
-    jnp/XLA core elsewhere. Same contract as ``make_core``."""
-    from . import backend as BK
+    """Routed safety evaluator: Pallas kernel when the target device is a
+    TPU (`pallas_kernels.use_pallas`), the jnp/XLA core elsewhere. Same
+    contract as ``make_core``."""
     from . import pallas_kernels as PK
 
     if PK.use_pallas(device):
-        kb = BK.kernel_kind(device)
-        return lambda board, depth: PK.nqueens_labels(board, depth, N, g,
-                                                      backend=kb)
+        return lambda board, depth: PK.nqueens_labels(board, depth, N, g)
     return make_core(N, g)
 
 
@@ -78,11 +74,7 @@ def make_jitted_core(N: int, g: int = 1, device=None):
     key — flipping TTS_PALLAS / TTS_PALLAS_INTERPRET between searches must
     rebuild, not reuse a stale core (same invariant as
     ``pfsp_device.routing_cache_token``)."""
-    from . import backend as BK
     from . import pallas_kernels as PK
 
     return _make_jitted_core(
-        N, g, device,
-        (PK.use_pallas(device), PK.pallas_interpret(),
-         BK.kernel_backend_mode(), BK.kernel_kind(device)),
-    )
+        N, g, device, (PK.use_pallas(device), PK.pallas_interpret()))

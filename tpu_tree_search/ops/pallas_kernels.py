@@ -13,16 +13,11 @@ Reference counterparts: `evaluate_gpu` (`nqueens_gpu_chpl.chpl:97-123`) and
 — one SIMT thread per (parent, child); here one grid step per TILE_B parents
 with all children vectorized on the VPU/MXU.
 
-Selection: ``use_pallas()`` consults the kernel-backend seam
-(`ops/backend.py`, ``TTS_KERNEL_BACKEND``) — True on native TPU/GPU
-backends unless disabled via ``TTS_PALLAS=0``; tests force
-``interpret=True`` on CPU to check the kernels bit-for-bit against the jnp
-oracles.  Every factory takes a ``backend`` flavor ("tpu"/"gpu"): the GPU
-flavor lowers the SAME tile bodies through `jax.experimental.pallas.triton`
-— plain BlockSpecs (Triton has no memory spaces), no scratch refs (the
-position-major scan staging statically unrolls instead — `_front_scan`),
-Triton compiler params — and runs under interpret mode on non-GPU
-processes (the CI parity path).
+Selection: ``use_pallas(device)`` is the one answer to "kernels or jnp":
+True when the target device is a TPU, unless disabled via ``TTS_PALLAS=0``;
+``TTS_PALLAS_INTERPRET=1`` routes to the kernels in interpret mode on any
+device.  Tests force ``interpret=True`` on CPU to check the kernels
+bit-for-bit against the jnp oracles.
 """
 
 from __future__ import annotations
@@ -37,31 +32,29 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
+def platform(device=None) -> str:
+    """The platform of the target device (``tpu``, ``cpu``, ...): an
+    explicit device wins, else the process default backend.  The routing
+    tables (`use_pallas`, `megakernel.resolve`, `compaction._auto_compact`,
+    `cli.resolve_chunk_size`) and the cost-model keys read this string."""
+    if device is not None:
+        return device.platform
+    return jax.default_backend()
+
+
 def use_pallas(device=None) -> bool:
-    """Route to the Pallas kernels only when the *target device* natively
-    compiles the resolved kernel flavor (`ops/backend.py`).
+    """Route to the Pallas kernels only when the *target device* is a TPU
+    (or ``TTS_PALLAS_INTERPRET=1`` runs them interpreted anywhere).
 
     The reference's dispatcher selects per device context
     (`evaluate.cu:93-119`); keying on the process default backend instead
     breaks any CPU-device execution inside a TPU-default process (e.g. the
     driver's virtual-CPU multichip dryrun). Callers that own a device thread
-    it through; ``None`` falls back to the default backend.  A FORCED gpu
-    flavor on a non-GPU process still routes to the kernels — they run
-    under interpret mode (`_default_interpret`), which is how CI proves the
-    Triton-structured lowering bit-exact without a GPU.
+    it through; ``None`` falls back to the default backend.
     """
     if os.environ.get("TTS_PALLAS", "1") == "0":
         return False
-    if pallas_interpret():
-        return True
-    from . import backend as BK
-
-    b = BK.resolve_backend(device)
-    if b.kind == "jnp":
-        return False
-    if b.kind == "tpu":
-        return b.native
-    return True  # gpu: native compiles Triton; forced runs interpret
+    return pallas_interpret() or platform(device) == "tpu"
 
 
 def pallas_forced() -> bool:
@@ -99,86 +92,37 @@ def pallas_interpret() -> bool:
     return os.environ.get("TTS_PALLAS_INTERPRET", "0") == "1"
 
 
-def _default_interpret(backend: str = "tpu") -> bool:
-    """The interpret default a kernel entry resolves when the caller does
-    not force one: the TTS_PALLAS_INTERPRET knob as always, plus — for the
-    gpu flavor — any process that cannot compile Triton natively (the CI
-    parity path: Triton-structured kernels, interpreted on CPU)."""
-    if pallas_interpret():
-        return True
-    if backend == "gpu":
-        from . import backend as BK
-
-        return not BK.resolve_backend(None).native
-    return False
-
-
 def _round_up(x: int, k: int) -> int:
     return (x + k - 1) // k * k
 
 
-def _vmem_limit_bytes(backend: str = "tpu") -> int | None:
-    """Scoped fast-memory ceiling for the PFSP kernels, per backend.
-
-    TPU: the Mosaic scoped-VMEM charge. The Mosaic default (16 MB) rejects
-    the lb-family kernels above tile 64 (the (T, n, n) one-hot and the
-    (n, T, m) scan scratch pad n/m up to the 128-lane tile); v5e has
-    128 MB of VMEM, so raising the scope to 96 MB is safe for a standalone
-    pallas_call and lets the batch tile grow to MXU-efficient sizes.
-
-    GPU: Triton has no compiler-enforced scope — this is the PROVISIONAL
-    per-block working-set ceiling the tile chooser sizes against
-    (``TTS_PALLAS_GPU_MB``, default 64: register file + L1/shared per SM
-    on A100/H100-class parts comfortably covers a 32 MB half-budget
-    working set via L2 residency; re-measure with
-    `scripts/gpu_session.sh`)."""
-    if backend == "gpu":
-        mb = int(os.environ.get("TTS_PALLAS_GPU_MB", "64"))
-        if mb < 0:
-            raise ValueError(
-                f"TTS_PALLAS_GPU_MB must be >= 0 (0 disables), got {mb}")
-        return mb * 2**20 if mb else None
+def _vmem_limit_bytes() -> int | None:
+    """Scoped VMEM ceiling for the PFSP kernels: the Mosaic scoped-VMEM
+    charge. The Mosaic default (16 MB) rejects the lb-family kernels above
+    tile 64 (the (T, n, n) one-hot and the (n, T, m) scan scratch pad n/m
+    up to the 128-lane tile); v5e has 128 MB of VMEM, so raising the scope
+    to 96 MB is safe for a standalone pallas_call and lets the batch tile
+    grow to MXU-efficient sizes."""
     mb = int(os.environ.get("TTS_PALLAS_VMEM_MB", "96"))
     if mb < 0:
         raise ValueError(f"TTS_PALLAS_VMEM_MB must be >= 0 (0 disables), got {mb}")
     return mb * 2**20 if mb else None
 
 
-def _compiler_params(ndims: int = 1, parallel: bool = False,
-                     backend: str = "tpu"):
-    """Backend-keyed ``pallas_call`` compiler params.
-
-    TPU: ``pltpu.CompilerParams`` with ``dimension_semantics`` sized to the
-    grid rank (``parallel`` marks every axis Megacore-splittable — only
+def _compiler_params(ndims: int = 1, parallel: bool = False):
+    """``pallas_call`` compiler params: ``dimension_semantics`` sized to
+    the grid rank (``parallel`` marks every axis Megacore-splittable — only
     safe for carry-free kernels, see megakernel.streamed_eval_bounds) and
-    the scoped-VMEM ceiling.  GPU: Triton's params at their defaults —
-    Triton has no dimension semantics (every grid program is a parallel
-    CUDA block) and no VMEM scope."""
-    if backend == "gpu":
-        from jax.experimental.pallas import triton as plgpu
-
-        return plgpu.CompilerParams()
+    the scoped-VMEM ceiling."""
     sem = ("parallel" if parallel else "arbitrary",) * ndims
     return pltpu.CompilerParams(dimension_semantics=sem,
-                                vmem_limit_bytes=_vmem_limit_bytes(backend))
+                                vmem_limit_bytes=_vmem_limit_bytes())
 
 
-def _bs(shape, index_map, space: str = "vmem", backend: str = "tpu"):
-    """Backend-keyed BlockSpec: the TPU flavor pins the block to VMEM or
-    SMEM (``space``); the Triton flavor has no memory spaces — every
-    operand is a plain ref, including the per-pair scalar tables the TPU
-    kernels stage in SMEM."""
-    if backend == "gpu":
-        return pl.BlockSpec(shape, index_map)
+def _bs(shape, index_map, space: str = "vmem"):
+    """A BlockSpec pinned to VMEM or SMEM (``space``)."""
     ms = pltpu.SMEM if space == "smem" else pltpu.VMEM
     return pl.BlockSpec(shape, index_map, memory_space=ms)
-
-
-def _scratch(backend: str, *tpu_shapes):
-    """The ``scratch_shapes`` a kernel may declare: the TPU scratch
-    allocations, none on Triton (its lowering has no scratch memory;
-    `_front_scan` unrolls what the scratch ref staged)."""
-    return [] if backend == "gpu" else list(tpu_shapes)
 
 
 def _env_tile(name: str, default: int) -> int:
@@ -203,9 +147,8 @@ def _model_bytes(t: int, n: int, m: int, extra_bytes: int,
     source of truth shared by the tile chooser and the routing gate.
     ``tn2_copies`` counts the shared (T, n, n)-class f32 live values
     (one-hot + reshape copies); ``pair_copies`` the per-pair ones (the
-    matmul pair body's u_o/mp0/mp1/cum0/suf1), charged once per member of
-    the unrolled pair group — the extra pair axis of the blocked lb2
-    kernels (conservative: Mosaic may overlap the unrolled bodies'
+    self-bound kernel's matmul pair body: u_o/mp0/mp1/cum0/suf1), charged
+    once per member of the unrolled pair group (conservative: Mosaic may overlap the unrolled bodies'
     temporaries, so the model assumes they are all live). ``lane_slabs``
     counts (n, T) int32 slabs, the parents on the lanes (the TPU lb2 pair
     loop, `_lb2_lane_slabs`). ``extra_bytes`` adds tile-independent
@@ -219,16 +162,15 @@ def _model_bytes(t: int, n: int, m: int, extra_bytes: int,
     return tn2 + oh_nt + scan + ptg + chains + slabs + extra_bytes
 
 
-def _vmem_budget(backend: str = "tpu") -> int:
-    return (_vmem_limit_bytes(backend) or 16 * 2**20) // 2
+def _vmem_budget() -> int:
+    return (_vmem_limit_bytes() or 16 * 2**20) // 2
 
 
 def _auto_tile(n: int, m: int, default: int, extra_bytes: int = 0,
                tn2_copies: int = 3, pair_copies: int = 0,
-               pair_group: int = 1, backend: str = "tpu",
-               lane_slabs: int = 0) -> int:
-    """Shrink the batch tile until the kernel's modeled memory footprint
-    fits the backend's budget (`_vmem_limit_bytes`).
+               pair_group: int = 1, lane_slabs: int = 0) -> int:
+    """Shrink the batch tile until the kernel's modeled VMEM footprint
+    fits the budget (`_vmem_limit_bytes`).
 
     The reference rebuilds with bigger compile-time params for large
     instances (`Taillard.chpl:29-52`); here the same kernel covers 20-500
@@ -236,7 +178,7 @@ def _auto_tile(n: int, m: int, default: int, extra_bytes: int = 0,
     T*n rows, so MXU utilization survives small T at large n. The model
     (``_model_bytes``) is checked against half the scoped budget, halving
     the tile until it fits (floor 8)."""
-    budget = _vmem_budget(backend)
+    budget = _vmem_budget()
     tile = default
     while tile > 8 and _model_bytes(tile, n, m, extra_bytes, tn2_copies,
                                     pair_copies, pair_group,
@@ -250,15 +192,14 @@ def _auto_tile(n: int, m: int, default: int, extra_bytes: int = 0,
 
 def _auto_tile_fits(n: int, m: int, default: int, extra_bytes: int = 0,
                     tn2_copies: int = 3, pair_copies: int = 0,
-                    pair_group: int = 1, backend: str = "tpu",
-                    lane_slabs: int = 0) -> bool:
+                    pair_group: int = 1, lane_slabs: int = 0) -> bool:
     """True iff the kernel fits the memory model even at the smallest tile
     — the routing gate: shapes that do not fit must stay on the jnp path
     instead of dying inside a Mosaic VMEM OOM."""
     tile = _auto_tile(n, m, default, extra_bytes, tn2_copies, pair_copies,
-                      pair_group, backend, lane_slabs)
+                      pair_group, lane_slabs)
     return _model_bytes(tile, n, m, extra_bytes, tn2_copies, pair_copies,
-                        pair_group, lane_slabs) <= _vmem_budget(backend)
+                        pair_group, lane_slabs) <= _vmem_budget()
 
 
 def _lb2_static_extra(n: int, m: int, P: int) -> int:
@@ -270,32 +211,23 @@ def _lb2_static_extra(n: int, m: int, P: int) -> int:
 # per-pair tn2 copies — charged once per unrolled pair-group member).
 # Tile defaults: lb1 64 and lb1d 256 are MEASURED on the real v5e
 # (docs/HW_VALIDATION.md; lb1 at 128 compiled >270s — Mosaic compile time
-# grows superlinearly with tile). The lb2 family is not hardware-measured
-# yet; the matmul flavors start in the compile-time-safe class lb1 proved
-# (64). The TPU lb2 kernel ("lb2") puts the tile's parents on the 128
-# lanes of its pair loop (`_lb2_tile_lb`), so its tile is 128: the width
-# that fills them. Its pair loop holds no (T, n, n) value and its tables
-# stream through SMEM; `_lb2_lane_slabs` charges its (n, T) slabs. The Triton
-# flavor keeps the matmul pair body ("lb2_matmul", `_model_key`).
-# scripts/tile_sweep.py re-measures per (kernel, tile) so the defaults can
+# grows superlinearly with tile). The lb2 kernel puts the tile's parents
+# on the 128 lanes of its pair loop (`_lb2_tile_lb`), so its tile is 128:
+# the width that fills them. Its pair loop holds no (T, n, n) value and its
+# tables stream through SMEM; `_lb2_lane_slabs` charges its (n, T) slabs.
+# The self-bound kernel keeps a matmul pair body and starts in the
+# compile-time-safe class lb1 proved (64). scripts/tile_sweep.py re-measures per (kernel, tile) so the defaults can
 # be raised with data.
 _KERNEL_MODEL = {
     "lb1": ("TTS_TILE_LB1", 64, 3, False, 0),
     "lb1d": ("TTS_TILE_LB1D", 256, 3, False, 0),
     "lb2": ("TTS_TILE_LB2", 128, 3, False, 0),
-    "lb2_matmul": ("TTS_TILE_LB2", 64, 3, True, 5),
     "lb2self": ("TTS_TILE_LB2SELF", 64, 1, True, 5),
 }
 
 
-def _model_key(kernel: str, backend: str) -> str:
-    """The `_KERNEL_MODEL` entry of ``kernel`` on ``backend``: the Triton
-    lb2 kernel keeps the matmul pair body (no SMEM, no scratch there)."""
-    return "lb2_matmul" if kernel == "lb2" and backend == "gpu" else kernel
-
-
 def _lb2_lane_slabs(n: int, m: int, pg: int) -> int:
-    """(n, T) int32 slabs live in the TPU lb2 pair loop: the m staged
+    """(n, T) int32 slabs live in the lb2 pair loop: the m staged
     child-front columns and the n free-job flag slabs (VMEM scratch), two
     chains per unrolled pair, and the running max, the transposed
     permutation, the open-slot mask and one temporary."""
@@ -318,49 +250,43 @@ def _resolve_pair_group(kernel: str, n: int, P: int | None,
 
 
 def _kernel_tile_args(kernel: str, n: int, m: int, P: int | None,
-                      pg: int, backend: str):
-    key = _model_key(kernel, backend)
-    env, default, copies, pairwise, pair_copies = _KERNEL_MODEL[key]
+                      pg: int):
+    env, default, copies, pairwise, pair_copies = _KERNEL_MODEL[kernel]
     extra = _lb2_static_extra(n, m, P) if pairwise else 0
-    slabs = _lb2_lane_slabs(n, m, pg) if key == "lb2" else 0
+    slabs = _lb2_lane_slabs(n, m, pg) if kernel == "lb2" else 0
     return dict(default=_env_tile(env, default), extra_bytes=extra,
                 tn2_copies=copies, pair_copies=pair_copies, pair_group=pg,
-                backend=backend, lane_slabs=slabs)
+                lane_slabs=slabs)
 
 
 def effective_tile(kernel: str, n: int, m: int, P: int | None = None,
                    batch: int | None = None,
-                   pair_group: int | None = None,
-                   backend: str = "tpu") -> int:
+                   pair_group: int | None = None) -> int:
     """The batch tile a kernel will actually use for shape (n, m[, P]) —
     shared by the feasibility gates, the kernel callers, and
     scripts/tile_sweep.py so the model constants live in exactly one
     place."""
     pg = _resolve_pair_group(kernel, n, P, pair_group)
-    tile = _auto_tile(n, m, **_kernel_tile_args(kernel, n, m, P, pg, backend))
+    tile = _auto_tile(n, m, **_kernel_tile_args(kernel, n, m, P, pg))
     return tile if batch is None else min(tile, batch)
 
 
 def _kernel_feasible(kernel: str, n: int, m: int, P: int | None,
-                     pair_group: int | None = None,
-                     backend: str = "tpu") -> bool:
+                     pair_group: int | None = None) -> bool:
     pg = _resolve_pair_group(kernel, n, P, pair_group)
-    return _auto_tile_fits(n, m,
-                           **_kernel_tile_args(kernel, n, m, P, pg, backend))
+    return _auto_tile_fits(n, m, **_kernel_tile_args(kernel, n, m, P, pg))
 
 
-def lb1_kernel_feasible(n: int, m: int, backend: str = "tpu") -> bool:
-    return _kernel_feasible("lb1", n, m, None, backend=backend)
+def lb1_kernel_feasible(n: int, m: int) -> bool:
+    return _kernel_feasible("lb1", n, m, None)
 
 
-def lb2_kernel_feasible(n: int, m: int, P: int,
-                        backend: str = "tpu") -> bool:
-    return _kernel_feasible("lb2", n, m, P, backend=backend)
+def lb2_kernel_feasible(n: int, m: int, P: int) -> bool:
+    return _kernel_feasible("lb2", n, m, P)
 
 
-def lb2_self_kernel_feasible(n: int, m: int, P: int,
-                             backend: str = "tpu") -> bool:
-    return _kernel_feasible("lb2self", n, m, P, backend=backend)
+def lb2_self_kernel_feasible(n: int, m: int, P: int) -> bool:
+    return _kernel_feasible("lb2self", n, m, P)
 
 
 # ---------------------------------------------------------------------------
@@ -397,8 +323,7 @@ def _nqueens_kernel(board_ref, depth_ref, out_ref, *, N: int, g: int):
 
 
 @lru_cache(maxsize=None)
-def _nqueens_call(N: int, g: int, B: int, tile: int, interpret: bool,
-                  backend: str = "tpu"):
+def _nqueens_call(N: int, g: int, B: int, tile: int, interpret: bool):
     kernel = partial(_nqueens_kernel, N=N, g=g)
     grid = (B // tile,)
     return pl.pallas_call(
@@ -407,26 +332,26 @@ def _nqueens_call(N: int, g: int, B: int, tile: int, interpret: bool,
         name="tts_eval_nqueens",
         grid=grid,
         in_specs=[
-            _bs((tile, N), lambda i: (i, 0), backend=backend),
-            _bs((tile, 1), lambda i: (i, 0), backend=backend),
+            _bs((tile, N), lambda i: (i, 0)),
+            _bs((tile, 1), lambda i: (i, 0)),
         ],
-        out_specs=_bs((tile, N), lambda i: (i, 0), backend=backend),
-        compiler_params=_compiler_params(backend=backend),
+        out_specs=_bs((tile, N), lambda i: (i, 0)),
+        compiler_params=_compiler_params(),
         interpret=interpret,
     )
 
 
 def nqueens_labels(board, depth, N: int, g: int = 1,
-                   interpret: bool | None = None, backend: str = "tpu"):
+                   interpret: bool | None = None):
     """(B, N) uint8 labels; same contract as `nqueens_device.make_core`."""
-    interpret = _default_interpret(backend) if interpret is None else interpret
+    interpret = pallas_interpret() if interpret is None else interpret
     B = board.shape[0]
     tile = min(512, B)
     Bp = _round_up(B, tile)
     if Bp != B:
         board = jnp.pad(board, ((0, Bp - B), (0, 0)))
         depth = jnp.pad(depth, ((0, Bp - B),))
-    out = _nqueens_call(N, g, Bp, tile, interpret, backend)(
+    out = _nqueens_call(N, g, Bp, tile, interpret)(
         board.astype(jnp.int32), depth.astype(jnp.int32)[:, None]
     )
     return out[:B]
@@ -467,13 +392,7 @@ def _front_scan(prmu, limit1, ptm, scan_ref, n: int, m: int,
     Ref on its leading axis — so the scan's per-position processing times
     are staged there (position-major: the same one-hot trick as the child
     gather, rows swapped so the reshape lands (n, T, m) without a 3-D
-    transpose) and the fori_loop reads ``scan_ref[i]``.
-
-    ``scan_ref=None`` is the GPU (Triton) lowering: Triton pallas has no
-    scratch memory and cannot lower dynamic indexing of register values
-    either, so the scan unrolls STATICALLY over the n positions — static
-    slices of the position-major value, same math, n-way larger program
-    (n <= 100 by the lb2 routing gate, so the unroll stays bounded)."""
+    transpose) and the fori_loop reads ``scan_ref[i]``."""
     T = prmu.shape[0]
     iota_nT = jax.lax.broadcasted_iota(jnp.int32, (n, T, n), 2)
     oh_nT = (iota_nT == prmu.T[:, :, None]).astype(jnp.float32)
@@ -490,11 +409,6 @@ def _front_scan(prmu, limit1, ptm, scan_ref, n: int, m: int,
         return jnp.where((i <= limit1)[:, None], newf, front)
 
     front0 = jnp.zeros((T, m), jnp.int32)
-    if scan_ref is None:
-        front = front0
-        for i in range(n):  # static unroll — no scratch ref on Triton
-            front = step(i, pts[i], front)
-        return front
     scan_ref[...] = pts
     return jax.lax.fori_loop(
         0, n, lambda i, f: step(i, scan_ref[i], f), front0
@@ -505,9 +419,8 @@ def _tile_parent_state(prmu, limit1, ptm, heads, scan_ref, n: int, m: int,
                        bf16: bool = False):
     """Shared tile prologue of the PFSP bound kernels: the one-hot MXU gather
     of per-position processing times, the masked schedule_front scan
-    (`_front_scan` — staged through ``scan_ref`` on TPU, statically
-    unrolled when ``scan_ref`` is None on the Triton lowering), and the
-    per-child add_forward fronts.
+    (`_front_scan`, staged through ``scan_ref``), and the per-child
+    add_forward fronts.
 
     Returns (onehot, ptg, front, child_front_cols) with child_front_cols a
     list of m (T, n) columns.
@@ -585,11 +498,10 @@ def _lb1_kernel(
 @lru_cache(maxsize=None)
 def _lb1_family_call(kernel_fn, n: int, m: int, B: int, tile: int,
                      interpret: bool, bf16: bool = False,
-                     backend: str = "tpu", name: str = "tts_eval_lb1"):
+                     name: str = "tts_eval_lb1"):
     """Shared pallas_call factory for the lb1-shaped kernels (lb1 / lb1_d):
     same operand layout — (prmu, limit1, ptm, heads, tails) -> (B, n) —
-    same tiling, same scan scratch (TPU; the gpu flavor passes a
-    scratch-free kernel_fn and declares none — `_front_scan` unrolls)."""
+    same tiling, same scan scratch."""
     kernel = partial(kernel_fn, n=n, m=m, bf16=bf16)
     grid = (B // tile,)
     return pl.pallas_call(
@@ -598,40 +510,38 @@ def _lb1_family_call(kernel_fn, n: int, m: int, B: int, tile: int,
         name=name,
         grid=grid,
         in_specs=[
-            _bs((tile, n), lambda i: (i, 0), backend=backend),
-            _bs((tile, 1), lambda i: (i, 0), backend=backend),
-            _bs((n, m), lambda i: (0, 0), backend=backend),
-            _bs((1, m), lambda i: (0, 0), backend=backend),
-            _bs((1, m), lambda i: (0, 0), backend=backend),
+            _bs((tile, n), lambda i: (i, 0)),
+            _bs((tile, 1), lambda i: (i, 0)),
+            _bs((n, m), lambda i: (0, 0)),
+            _bs((1, m), lambda i: (0, 0)),
+            _bs((1, m), lambda i: (0, 0)),
         ],
-        out_specs=_bs((tile, n), lambda i: (i, 0), backend=backend),
-        scratch_shapes=_scratch(backend, pltpu.VMEM((n, tile, m), jnp.int32)),
-        compiler_params=_compiler_params(backend=backend),
+        out_specs=_bs((tile, n), lambda i: (i, 0)),
+        scratch_shapes=[pltpu.VMEM((n, tile, m), jnp.int32)],
+        compiler_params=_compiler_params(),
         interpret=interpret,
     )
 
 
 def _lb1_family_bounds(
     kernel_fn, prmu, limit1, ptm_t, min_heads, min_tails, interpret: bool,
-    bf16: bool = False, kernel_name: str = "lb1", backend: str = "tpu",
+    bf16: bool = False, kernel_name: str = "lb1",
 ):
     B, n = prmu.shape
     m = ptm_t.shape[1]
-    if backend == "gpu":
-        kernel_fn = _GPU_KERNELS[kernel_fn]
     # Per-kernel tile defaults are measured, not uniform (_KERNEL_MODEL):
     # Mosaic compile time for the lb1 kernel grows superlinearly with the
     # batch tile (64 -> ~16s, 128 -> >270s on v5e), while lb1_d compiles at
     # 256 in ~50s. Large instances then shrink the tile further until the
     # VMEM model fits.
-    tile = effective_tile(kernel_name, n, m, batch=B, backend=backend)
+    tile = effective_tile(kernel_name, n, m, batch=B)
     Bp = _round_up(B, tile)
     if Bp != B:
         prmu = jnp.pad(prmu, ((0, Bp - B), (0, 0)))
         limit1 = jnp.pad(limit1, ((0, Bp - B),))
     name = "tts_eval_lb1_d" if kernel_name == "lb1d" else "tts_eval_lb1"
     out = _lb1_family_call(kernel_fn, n, m, Bp, tile, interpret, bf16,
-                           backend, name)(
+                           name)(
         prmu.astype(jnp.int32),
         limit1.astype(jnp.int32)[:, None],
         ptm_t.astype(jnp.int32),
@@ -667,54 +577,33 @@ def _lb1_d_kernel(
     out_ref[:] = jnp.broadcast_to(lb, (T, n))
 
 
-def _lb1_kernel_gpu(prmu_ref, limit1_ref, ptm_ref, heads_ref, tails_ref,
-                    out_ref, *, n: int, m: int, bf16: bool = False):
-    """The lb1 kernel without its scan scratch — the Triton flavor
-    (`_front_scan` unrolls statically where the TPU kernel staged)."""
-    _lb1_kernel(prmu_ref, limit1_ref, ptm_ref, heads_ref, tails_ref,
-                out_ref, None, n=n, m=m, bf16=bf16)
-
-
-def _lb1_d_kernel_gpu(prmu_ref, limit1_ref, ptm_ref, heads_ref, tails_ref,
-                      out_ref, *, n: int, m: int, bf16: bool = False):
-    _lb1_d_kernel(prmu_ref, limit1_ref, ptm_ref, heads_ref, tails_ref,
-                  out_ref, None, n=n, m=m, bf16=bf16)
-
-
-#: TPU kernel body -> its scratch-free Triton twin (`_lb1_family_bounds`).
-_GPU_KERNELS = {
-    _lb1_kernel: _lb1_kernel_gpu,
-    _lb1_d_kernel: _lb1_d_kernel_gpu,
-}
-
-
 def pfsp_lb1_d_bounds(
     prmu, limit1, ptm_t, min_heads, min_tails, interpret: bool | None = None,
-    bf16: bool = False, backend: str = "tpu",
+    bf16: bool = False,
 ):
     """(B, n) int32 lb1_d child bounds; same contract as `_lb1_d_chunk`."""
-    interpret = _default_interpret(backend) if interpret is None else interpret
+    interpret = pallas_interpret() if interpret is None else interpret
     return _lb1_family_bounds(
         _lb1_d_kernel, prmu, limit1, ptm_t, min_heads, min_tails, interpret,
-        bf16, kernel_name="lb1d", backend=backend,
+        bf16, kernel_name="lb1d",
     )
 
 
-#: Johnson slots one iteration of the TPU lb2 slot loop runs, at most
+#: Johnson slots one iteration of the lb2 slot loop runs, at most
 #: (`_lb2_slot_unroll`).  The unrolled body is traced in Python at every
 #: program build, so a full unroll costs set-up time on every run.
 _LB2_SLOT_UNROLL = 4
 
 
 def _lb2_group_stride(n: int, pg: int) -> int:
-    """int32 words between pair groups in the TPU lb2 kernel's HBM slot
+    """int32 words between pair groups in the lb2 kernel's HBM slot
     table and halves of its SMEM buffer: a group's 4·n·pg words rounded up
     to the 1024-word tile Mosaic slices a 1-D HBM array on."""
     return _round_up(pg * 4 * n, 1024)
 
 
 def _lb2_slot_unroll(n: int) -> int:
-    """Johnson slots per iteration of the TPU lb2 slot loop: the largest
+    """Johnson slots per iteration of the lb2 slot loop: the largest
     divisor of ``n`` up to `_LB2_SLOT_UNROLL`."""
     return max(d for d in range(1, min(n, _LB2_SLOT_UNROLL) + 1)
                if n % d == 0)
@@ -722,7 +611,7 @@ def _lb2_slot_unroll(n: int) -> int:
 
 @dataclasses.dataclass(frozen=True)
 class _Lb2Lanes:
-    """The refs of the TPU lb2 pair loop (`_lb2_tile_lb`).
+    """The refs of the lb2 pair loop (`_lb2_tile_lb`).
 
     ``slots`` (groups*stride,) int32 in HBM: pair q's Johnson slot t at
     ``q*4n + f*n + t`` for f = job, p0, p1, lag within its pair group,
@@ -742,14 +631,14 @@ class _Lb2Lanes:
 
 
 def _lb2_lane_specs(P: int, index):
-    """in_specs of the TPU lb2 kernel's two tables; ``index`` is the
+    """in_specs of the lb2 kernel's two tables; ``index`` is the
     grid's constant index map of a 1-D block."""
     return [pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec((P * 4,), index, memory_space=pltpu.SMEM)]
 
 
 def _lb2_lane_scratch(n: int, m: int, t: int, pg: int):
-    """scratch_shapes of the TPU lb2 pair loop at tile ``t``, in
+    """scratch_shapes of the lb2 pair loop at tile ``t``, in
     `_Lb2Lanes` order after ``pairs``."""
     return [pltpu.VMEM((m, n, t), jnp.int32), pltpu.VMEM((n, n, t), jnp.int32),
             pltpu.SMEM((2 * _lb2_group_stride(n, pg),), jnp.int32),
@@ -758,7 +647,7 @@ def _lb2_lane_scratch(n: int, m: int, t: int, pg: int):
 
 def _lb2_tile_lb(prmu, limit1, ptm, heads, lanes: _Lb2Lanes, scan_ref,
                  *, n: int, m: int, P: int, pg: int = 1, bf16: bool = False):
-    """(T, n) int32 lb2 bound of every child in the tile — the TPU body of
+    """(T, n) int32 lb2 bound of every child in the tile — the body of
     `_lb2_kernel`, shared with the one-kernel cycle (`ops/megakernel.py`).
 
     After the shared prologue (`_tile_parent_state`) the tile is turned
@@ -856,84 +745,6 @@ def _lb2_tile_lb(prmu, limit1, ptm, heads, lanes: _Lb2Lanes, scan_ref,
     return lb.T
 
 
-def _lb2_tile_lb_matmul(
-    prmu, limit1, ptm, heads,
-    p0_ref, p1_ref, lag_ref, t0_ref, t1_ref, msel0_ref, msel1_ref, jorder_ref,
-    scan_ref, *, n: int, m: int, P: int, pg: int = 1, bf16: bool = False,
-):
-    """(T, n) f32 lb2 bound of every child in the tile — the Triton
-    flavor's pair loop (`_lb2_kernel_gpu`, the gpu cycle of
-    `ops/megakernel.py`): the max-plus closed form as one-hot and
-    triangular matmuls over (T, n, n) values.  Mixed value/Ref signature:
-    the per-pair tables stay Refs because the pair loop indexes them
-    dynamically on a non-tiled leading axis."""
-    T = prmu.shape[0]
-    hp = _hp_dot
-    onehot, _, _, _, cf = _tile_parent_state(
-        prmu, limit1, ptm, heads, scan_ref, n, m, bf16
-    )
-    child_front = jnp.stack(cf, axis=-1).astype(jnp.float32)  # (T, n, m)
-
-    # Free-job flags by job id: parent's open positions minus the child job.
-    slot_iota = jax.lax.broadcasted_iota(jnp.int32, (T, n), 1)
-    unsched = (slot_iota >= (limit1 + 1)[:, None]).astype(jnp.float32)  # (T, n)
-    u_parent = jnp.sum(onehot * unsched[:, :, None], axis=1)  # (T, n) by job
-    u_child = u_parent[:, None, :] - onehot  # (T, k, job)
-
-    neg = jnp.float32(-(2.0**30))
-    # Prefix/suffix sums along the ordered-slot axis as triangular matmuls
-    # (MXU work; Mosaic has no native lane-axis cumsum).
-    ri = jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
-    ci = jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
-    tri_incl = (ri <= ci).astype(jnp.float32)  # prefix: sum_{s<=t}
-    tri_suf = (ri >= ci).astype(jnp.float32)  # suffix: sum_{s>=t}
-
-    def pair_body(q, lb):
-        jord = jorder_ref[q]  # (n, n) slot-order one-hot
-        # u_o[b, k, t] = u_child[b, k, sched_q[t]]
-        u_o = hp(u_child.reshape(T * n, n), jord.T, bf16).reshape(T, n, n)
-        # Per-pair tables are (P, 1, n): the dynamic q index must hit a
-        # non-tiled leading axis (a (P, n) ref would put it on the sublane
-        # dim, which Mosaic cannot index dynamically).
-        p0 = p0_ref[q][0].astype(jnp.float32)  # (n,)
-        p1 = p1_ref[q][0].astype(jnp.float32)
-        lag = lag_ref[q][0].astype(jnp.float32)
-        mp0 = u_o * p0[None, None, :]
-        mp1 = u_o * p1[None, None, :]
-        # Machine selection as a one-hot contraction on the lane axis —
-        # Mosaic cannot dynamic_slice a VMEM *value* along a lane dim, but a
-        # masked reduction against the precomputed (P, m) selector rows is
-        # exact (0/1 mask) and pure VPU work.
-        s0 = msel0_ref[q][0].astype(jnp.float32)  # (m,)
-        s1 = msel1_ref[q][0].astype(jnp.float32)
-        tmp0_0 = jnp.sum(child_front * s0[None, None, :], axis=-1)  # (T, n)
-        tmp1_0 = jnp.sum(child_front * s1[None, None, :], axis=-1)
-        cum0 = hp(mp0.reshape(T * n, n), tri_incl, bf16).reshape(T, n, n)
-        suf1 = hp(mp1.reshape(T * n, n), tri_suf, bf16).reshape(T, n, n)
-        t0 = tmp0_0[:, :, None] + cum0
-        a = jnp.where(u_o > 0, t0 + lag[None, None, :] + suf1, neg)
-        tmp1 = jnp.maximum(tmp1_0 + jnp.sum(mp1, axis=-1), jnp.max(a, axis=-1))
-        tmp0 = tmp0_0 + jnp.sum(mp0, axis=-1)
-        pair_lb = jnp.maximum(
-            tmp1 + t1_ref[q].astype(jnp.float32),
-            tmp0 + t0_ref[q].astype(jnp.float32),
-        )
-        return jnp.maximum(lb, pair_lb)
-
-    lb0 = jnp.zeros((T, n), jnp.float32)
-    if pg > 1:
-        def group_body(g, lb):
-            q0 = g * pg
-            for j in range(pg):  # static unroll within the group
-                lb = pair_body(q0 + j, lb)
-            return lb
-
-        lb = jax.lax.fori_loop(0, P // pg, group_body, lb0)
-    else:
-        lb = jax.lax.fori_loop(0, P, pair_body, lb0)
-    return lb
-
-
 def _lb2_kernel(prmu_ref, limit1_ref, ptm_ref, heads_ref, slots_ref,
                 pairs_ref, out_ref, scan_ref, *lane_scratch, n: int, m: int,
                 P: int, pg: int = 1, bf16: bool = False):
@@ -956,93 +767,43 @@ def _lb2_kernel(prmu_ref, limit1_ref, ptm_ref, heads_ref, slots_ref,
     )
 
 
-def _lb2_kernel_gpu(
-    prmu_ref, limit1_ref, ptm_ref, heads_ref,
-    p0_ref, p1_ref, lag_ref, t0_ref, t1_ref, msel0_ref, msel1_ref, jorder_ref,
-    out_ref, *, n: int, m: int, P: int, pg: int = 1, bf16: bool = False,
-):
-    """The lb2 kernel's Triton flavor: the matmul pair body
-    (`_lb2_tile_lb_matmul`) with no scan scratch.  The pair loop's dynamic
-    leading-axis ref reads stay: a Triton ref is a pointer, and dynamic
-    pointer offsets are the one dynamic indexing form the lowering is
-    built on.  ``pg`` unrolls the pair group as in `_lb2_kernel`."""
-    prmu = prmu_ref[:].astype(jnp.int32)  # (T, n)
-    limit1 = limit1_ref[:, 0].astype(jnp.int32)  # (T,)
-    ptm = ptm_ref[:].astype(jnp.float32)  # (n, m)
-    lb = _lb2_tile_lb_matmul(
-        prmu, limit1, ptm, heads_ref[:],
-        p0_ref, p1_ref, lag_ref, t0_ref, t1_ref, msel0_ref, msel1_ref,
-        jorder_ref, None, n=n, m=m, P=P, pg=pg, bf16=bf16,
-    )
-    out_ref[:] = lb.astype(jnp.int32)
-
-
 @lru_cache(maxsize=None)
 def _lb2_call(n: int, m: int, P: int, B: int, tile: int, interpret: bool,
-              bf16: bool = False, pg: int = 1, backend: str = "tpu",
-              parallel: bool = False):
+              bf16: bool = False, pg: int = 1, parallel: bool = False):
     """The standalone lb2 kernel over ``B // tile`` tiles; ``parallel``
     marks the grid Megacore-splittable (it carries nothing across tiles —
     `megakernel.streamed_eval_bounds`)."""
-    grid = (B // tile,)
     full = lambda i: (0, 0)
-    full3 = lambda i: (0, 0, 0)
-    bs = partial(_bs, backend=backend)
-    head = [
-        bs((tile, n), lambda i: (i, 0)),
-        bs((tile, 1), lambda i: (i, 0)),
-        bs((n, m), full),
-        bs((1, m), full),
-    ]
-    if backend == "gpu":
-        kernel = partial(_lb2_kernel_gpu, n=n, m=m, P=P, pg=pg, bf16=bf16)
-        tables = [
-            # Per-pair tables as (P, 1, n)/(P, 1, m): leading-axis dynamic
-            # ref reads (see `_lb2_tile_lb_matmul`).
-            bs((P, 1, n), full3),
-            bs((P, 1, n), full3),
-            bs((P, 1, n), full3),
-            bs((P,), lambda i: (0,), space="smem"),
-            bs((P,), lambda i: (0,), space="smem"),
-            bs((P, 1, m), full3),
-            bs((P, 1, m), full3),
-            bs((P, n, n), full3),
-        ]
-        scratch = []
-    else:
-        kernel = partial(_lb2_kernel, n=n, m=m, P=P, pg=pg, bf16=bf16)
-        tables = _lb2_lane_specs(P, lambda i: (0,))
-        scratch = [pltpu.VMEM((n, tile, m), jnp.int32),
-                   *_lb2_lane_scratch(n, m, tile, pg)]
     return pl.pallas_call(
-        kernel,
+        partial(_lb2_kernel, n=n, m=m, P=P, pg=pg, bf16=bf16),
         out_shape=jax.ShapeDtypeStruct((B, n), jnp.int32),
         name="tts_eval_lb2",
-        grid=grid,
-        in_specs=head + tables,
-        out_specs=bs((tile, n), lambda i: (i, 0)),
-        scratch_shapes=scratch,
-        compiler_params=_compiler_params(parallel=parallel, backend=backend),
+        grid=(B // tile,),
+        in_specs=[
+            _bs((tile, n), lambda i: (i, 0)),
+            _bs((tile, 1), lambda i: (i, 0)),
+            _bs((n, m), full),
+            _bs((1, m), full),
+            *_lb2_lane_specs(P, lambda i: (0,)),
+        ],
+        out_specs=_bs((tile, n), lambda i: (i, 0)),
+        scratch_shapes=[pltpu.VMEM((n, tile, m), jnp.int32),
+                        *_lb2_lane_scratch(n, m, tile, pg)],
+        compiler_params=_compiler_params(parallel=parallel),
         interpret=interpret,
     )
 
 
-def lb2_kernel_tables(ordered, pg: int, backend: str = "tpu") -> tuple:
+def lb2_kernel_tables(ordered, pg: int) -> tuple:
     """The pair-table operands of an lb2 kernel over ``ordered`` (padded to
-    a multiple of the pair group ``pg``), after ptm and the heads: the TPU
-    kernels' tables (`_Lb2Lanes`: the slot table holds each group at its
-    `_lb2_group_stride`), or the Triton flavor's slot-ordered rows,
-    selectors and (P, n, n) one-hot order."""
-    if backend != "gpu":
-        P, n = ordered.lag_o.shape
-        rows = jnp.reshape(ordered.slot_tab, (P // pg, pg * 4 * n))
-        slots = jnp.pad(rows, ((0, 0), (0, _lb2_group_stride(n, pg)
-                                         - pg * 4 * n))).reshape(-1)
-        return (slots, ordered.pair_tab)
-    return (ordered.p0_o[:, None, :], ordered.p1_o[:, None, :],
-            ordered.lag_o[:, None, :], ordered.tails0, ordered.tails1,
-            ordered.msel0[:, None, :], ordered.msel1[:, None, :],
-            ordered.jorder)
+    a multiple of the pair group ``pg``), after ptm and the heads
+    (`_Lb2Lanes`: the slot table holds each group at its
+    `_lb2_group_stride`, then the per-pair table)."""
+    P, n = ordered.lag_o.shape
+    rows = jnp.reshape(ordered.slot_tab, (P // pg, pg * 4 * n))
+    slots = jnp.pad(rows, ((0, 0), (0, _lb2_group_stride(n, pg)
+                                     - pg * 4 * n))).reshape(-1)
+    return (slots, ordered.pair_tab)
 
 
 def _eager_context() -> bool:
@@ -1055,21 +816,19 @@ def _eager_context() -> bool:
 
 def pfsp_lb2_bounds(prmu, limit1, tables, interpret: bool | None = None,
                     bf16: bool | None = None,
-                    pair_group: int | None = None, backend: str = "tpu"):
+                    pair_group: int | None = None):
     """(B, n) int32 lb2 child bounds; same contract as `_lb2_chunk`.
     ``pair_group``: pair-group unroll per grid step (None resolves the
     shared TTS_LB2_PAIRBLOCK knob); the pair tables are padded to a
     multiple of it with copies of pair 0 (max is idempotent)."""
-    interpret = _default_interpret(backend) if interpret is None else interpret
+    interpret = pallas_interpret() if interpret is None else interpret
     if bf16 is None:
         bf16 = getattr(tables, "exact_bf16", False)
     B, n = prmu.shape
     m = tables.ptm_t.shape[1]
     P = tables.pairs.shape[0]
     pg = _resolve_pair_group("lb2", n, P, pair_group)
-    # The tile the backend's pair body fits (_KERNEL_MODEL, `_model_key`).
-    tile = effective_tile("lb2", n, m, P, batch=B, pair_group=pg,
-                          backend=backend)
+    tile = effective_tile("lb2", n, m, P, batch=B, pair_group=pg)
     Bp = _round_up(B, tile)
     if Bp != B:
         prmu = jnp.pad(prmu, ((0, Bp - B), (0, 0)))
@@ -1081,25 +840,25 @@ def pfsp_lb2_bounds(prmu, limit1, tables, interpret: bool | None = None,
     ordered = (tables.johnson_ordered_device(pg) if _eager_context()
                else tables.johnson_ordered_mp(pg))
     Pp = ordered.lag_o.shape[0]
-    out = _lb2_call(n, m, Pp, Bp, tile, interpret, bf16, pg, backend)(
+    out = _lb2_call(n, m, Pp, Bp, tile, interpret, bf16, pg)(
         prmu.astype(jnp.int32),
         limit1.astype(jnp.int32)[:, None],
         tables.ptm_t,
         tables.min_heads[None, :],
-        *lb2_kernel_tables(ordered, pg, backend),
+        *lb2_kernel_tables(ordered, pg),
     )
     return out[:B]
 
 
 def pfsp_lb1_bounds(
     prmu, limit1, ptm_t, min_heads, min_tails, interpret: bool | None = None,
-    bf16: bool = False, backend: str = "tpu",
+    bf16: bool = False,
 ):
     """(B, n) int32 lb1 child bounds; same contract as `_lb1_chunk`."""
-    interpret = _default_interpret(backend) if interpret is None else interpret
+    interpret = pallas_interpret() if interpret is None else interpret
     return _lb1_family_bounds(
         _lb1_kernel, prmu, limit1, ptm_t, min_heads, min_tails, interpret,
-        bf16, backend=backend,
+        bf16,
     )
 
 
@@ -1131,9 +890,8 @@ def _lb2_self_kernel(
         T = prmu.shape[0]
         hp = _hp_dot
 
-        # schedule_front via the position-major scan staging (`_front_scan`
-        # — scratch-staged on TPU, statically unrolled when scan_ref is
-        # None on the Triton lowering).
+        # schedule_front via the position-major scan staging
+        # (`_front_scan`).
         front = _front_scan(prmu, limit1, ptm, scan_ref, n, m,
                             bf16).astype(jnp.float32)
 
@@ -1190,53 +948,36 @@ def _lb2_self_kernel(
         out_ref[:] = lb.astype(jnp.int32)
 
 
-def _lb2_self_kernel_gpu(
-    prmu_ref, limit1_ref, nact_ref, ptm_ref,
-    p0_ref, p1_ref, lag_ref, t0_ref, t1_ref, msel0_ref, msel1_ref, jorder_ref,
-    out_ref, *, n: int, m: int, P: int, tile: int, pg: int = 1,
-    bf16: bool = False,
-):
-    """The staged self-bound kernel without its scan scratch — the Triton
-    flavor (tile skipping via `pl.when` is backend-neutral)."""
-    _lb2_self_kernel(
-        prmu_ref, limit1_ref, nact_ref, ptm_ref,
-        p0_ref, p1_ref, lag_ref, t0_ref, t1_ref, msel0_ref, msel1_ref,
-        jorder_ref, out_ref, None, n=n, m=m, P=P, tile=tile, pg=pg,
-        bf16=bf16,
-    )
-
-
 @lru_cache(maxsize=None)
 def _lb2_self_call(n: int, m: int, P: int, R: int, tile: int, interpret: bool,
-                   bf16: bool = False, pg: int = 1, backend: str = "tpu"):
-    kernel_fn = _lb2_self_kernel_gpu if backend == "gpu" else _lb2_self_kernel
-    kernel = partial(kernel_fn, n=n, m=m, P=P, tile=tile, pg=pg, bf16=bf16)
+                   bf16: bool = False, pg: int = 1):
+    kernel = partial(_lb2_self_kernel, n=n, m=m, P=P, tile=tile, pg=pg,
+                     bf16=bf16)
     grid = (R // tile,)
     full = lambda i: (0, 0)
     full3 = lambda i: (0, 0, 0)
-    bs = partial(_bs, backend=backend)
     return pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct((R, 1), jnp.int32),
         name="tts_eval_lb2_self",
         grid=grid,
         in_specs=[
-            bs((tile, n), lambda i: (i, 0)),
-            bs((tile, 1), lambda i: (i, 0)),
-            bs((1,), lambda i: (0,), space="smem"),
-            bs((n, m), full),
-            bs((P, 1, n), full3),
-            bs((P, 1, n), full3),
-            bs((P, 1, n), full3),
-            bs((P,), lambda i: (0,), space="smem"),
-            bs((P,), lambda i: (0,), space="smem"),
-            bs((P, 1, m), full3),
-            bs((P, 1, m), full3),
-            bs((P, n, n), full3),
+            _bs((tile, n), lambda i: (i, 0)),
+            _bs((tile, 1), lambda i: (i, 0)),
+            _bs((1,), lambda i: (0,), space="smem"),
+            _bs((n, m), full),
+            _bs((P, 1, n), full3),
+            _bs((P, 1, n), full3),
+            _bs((P, 1, n), full3),
+            _bs((P,), lambda i: (0,), space="smem"),
+            _bs((P,), lambda i: (0,), space="smem"),
+            _bs((P, 1, m), full3),
+            _bs((P, 1, m), full3),
+            _bs((P, n, n), full3),
         ],
-        out_specs=bs((tile, 1), lambda i: (i, 0)),
-        scratch_shapes=_scratch(backend, pltpu.VMEM((n, tile, m), jnp.int32)),
-        compiler_params=_compiler_params(backend=backend),
+        out_specs=_bs((tile, 1), lambda i: (i, 0)),
+        scratch_shapes=[pltpu.VMEM((n, tile, m), jnp.int32)],
+        compiler_params=_compiler_params(),
         interpret=interpret,
     )
 
@@ -1261,14 +1002,13 @@ class _PaddedOrdered:
 def pfsp_lb2_self_bounds_tables(prmu, limit1, n_active, ptm_t, ordered,
                                 interpret: bool | None = None,
                                 bf16: bool = False,
-                                pair_group: int | None = None,
-                                backend: str = "tpu"):
+                                pair_group: int | None = None):
     """`pfsp_lb2_self_bounds` over EXPLICIT ordered tables (possibly traced
     slices of the full pair set — the mp-sharded staged path slices each
     shard's contiguous pair block before the call; pallas_call takes traced
     operands like any other op). ``ordered`` needs p0_o/p1_o/lag_o (P, n),
     tails0/tails1 (P,), msel0/msel1 (P, m), jorder (P, n, n)."""
-    interpret = _default_interpret(backend) if interpret is None else interpret
+    interpret = pallas_interpret() if interpret is None else interpret
     R, n = prmu.shape
     m = ptm_t.shape[1]
     P = ordered.lag_o.shape[0]
@@ -1276,14 +1016,12 @@ def pfsp_lb2_self_bounds_tables(prmu, limit1, n_active, ptm_t, ordered,
     reps = _round_up(P, pg) - P
     if reps:
         ordered = _PaddedOrdered(ordered, reps)
-    tile = effective_tile("lb2self", n, m, P, batch=R, pair_group=pg,
-                          backend=backend)
+    tile = effective_tile("lb2self", n, m, P, batch=R, pair_group=pg)
     Rp = _round_up(R, tile)
     if Rp != R:
         prmu = jnp.pad(prmu, ((0, Rp - R), (0, 0)))
         limit1 = jnp.pad(limit1, ((0, Rp - R),))
-    out = _lb2_self_call(n, m, P + reps, Rp, tile, interpret, bf16, pg,
-                         backend)(
+    out = _lb2_self_call(n, m, P + reps, Rp, tile, interpret, bf16, pg)(
         prmu.astype(jnp.int32),
         limit1.astype(jnp.int32)[:, None],
         jnp.asarray(n_active, dtype=jnp.int32).reshape(1),
@@ -1303,8 +1041,7 @@ def pfsp_lb2_self_bounds_tables(prmu, limit1, n_active, ptm_t, ordered,
 def pfsp_lb2_self_bounds(prmu, limit1, n_active, tables,
                          interpret: bool | None = None,
                          bf16: bool | None = None,
-                         pair_group: int | None = None,
-                         backend: str = "tpu"):
+                         pair_group: int | None = None):
     """(R,) int32 self lb2 bounds; rows >= n_active are garbage (their
     tiles are skipped entirely). Same contract as `_lb2_self_chunk` on the
     first n_active rows."""
@@ -1318,5 +1055,5 @@ def pfsp_lb2_self_bounds(prmu, limit1, n_active, tables,
                else tables.johnson_ordered_mp(pg))
     return pfsp_lb2_self_bounds_tables(
         prmu, limit1, n_active, tables.ptm_t, ordered, interpret, bf16,
-        pair_group=pg, backend=backend,
+        pair_group=pg,
     )

@@ -313,10 +313,10 @@ class PFSPDeviceTables:
     ``johnson_ordered()`` additionally derives the Johnson data in *schedule
     order* per machine pair (`p0_o/p1_o/lag_o[q, t]` = value of the t-th job
     of pair q's Johnson schedule), the same as flat int32 scalar tables
-    for the TPU Pallas lb2 kernels (``slot_tab``/``pair_tab``, read from
-    SMEM), and a per-pair permutation one-hot for the Triton flavor, which
-    reorders the per-child free-job flags with one small matmul. Built
-    lazily, only when a kernel runs.
+    for the Pallas lb2 kernels (``slot_tab``/``pair_tab``, read from
+    SMEM), and a per-pair permutation one-hot for the self-bound kernel,
+    which reorders the free-job flags with one small matmul. Built lazily,
+    only when a kernel runs.
     """
 
     def __init__(self, lb1_data, lb2_data):
@@ -462,11 +462,10 @@ def lb1_bounds(prmu, limit1, tables: "PFSPDeviceTables", device=None):
     # jnp path measured ~7x faster in-kernel on chip (docs/HW_VALIDATION.md
     # decision record); TTS_PALLAS=force re-arms it for the A/B.
     n, m = prmu.shape[-1], tables.ptm_t.shape[1]
-    kb = _kernel_kind(device)
     if lb1_kernel_routed(n, m, device):
         return PK.pfsp_lb1_bounds(
             prmu, limit1, tables.ptm_t, tables.min_heads, tables.min_tails,
-            bf16=tables.exact_bf16, backend=kb,
+            bf16=tables.exact_bf16,
         )
     return _lb1_chunk(prmu, limit1, tables.ptm_t, tables.min_heads,
                       tables.min_tails, bf16=tables.exact_bf16)
@@ -478,11 +477,10 @@ def lb1_d_bounds(prmu, limit1, tables: "PFSPDeviceTables", device=None):
     from . import pallas_kernels as PK
 
     n, m = prmu.shape[-1], tables.ptm_t.shape[1]
-    kb = _kernel_kind(device)
     if lb1_kernel_routed(n, m, device):
         return PK.pfsp_lb1_d_bounds(
             prmu, limit1, tables.ptm_t, tables.min_heads, tables.min_tails,
-            bf16=tables.exact_bf16, backend=kb,
+            bf16=tables.exact_bf16,
         )
     return _lb1_d_chunk(
         prmu, limit1, tables.ptm_t, tables.min_heads, tables.min_tails,
@@ -499,7 +497,7 @@ def lb1_kernel_routed(n: int, m: int, device=None) -> bool:
     from . import pallas_kernels as PK
 
     return (PK.use_pallas(device) and PK.lb1_pallas_enabled() and n <= 512
-            and PK.lb1_kernel_feasible(n, m, backend=_kernel_kind(device)))
+            and PK.lb1_kernel_feasible(n, m))
 
 
 def lb2_kernel_routed(n: int, m: int, P: int, device=None,
@@ -516,17 +514,7 @@ def lb2_kernel_routed(n: int, m: int, P: int, device=None,
     feasible = (PK.lb2_self_kernel_feasible if self_bound
                 else PK.lb2_kernel_feasible)
     return (PK.use_pallas(device) and _lb2_pallas_enabled() and n <= 100
-            and feasible(n, m, P, backend=_kernel_kind(device)))
-
-
-def _kernel_kind(device=None) -> str:
-    """The kernel flavor the seam resolves for this device
-    (`ops/backend.kernel_kind`) — 'gpu' only when the resolved backend is
-    gpu, else the TPU flavor of record (so off-gpu routing stays
-    byte-identical)."""
-    from . import backend as BK
-
-    return BK.kernel_kind(device)
+            and feasible(n, m, P))
 
 
 def _lb2_pallas_enabled() -> bool:
@@ -541,27 +529,14 @@ def _lb2_pallas_enabled() -> bool:
     return os.environ.get("TTS_PALLAS_LB2", "1") != "0"
 
 
-def _auto_pairblock(P: int, n: int, backend: str | None = None) -> int:
+def _auto_pairblock(P: int, n: int) -> int:
     """Auto pair-block policy: the largest power-of-two block whose
     per-(row, child) working set stays near ~2048 ordered-slot lanes
     (``Pb * n``), clamped to the pair count. At the published shapes this
     gives Pb = P at ta014 (n=20, P=45 — a single block, loop-free) and
     Pb = 64 at ta021 (P=190 — three unrolled blocks); 500-job instances
-    fall to Pb = 4 so the (B, n, Pb, n) intermediates keep fitting.
-
-    The gpu row halves the lane target (~1024): the Triton kernels hold
-    the per-pair group's live values in registers/shared memory per CUDA
-    block rather than a chip-wide VMEM, and the reference tunes its pair
-    batching to that budget (arXiv 2012.09511). PROVISIONAL until a GPU
-    session banks measured rows. ``backend=None`` resolves the seam
-    (`ops/backend.policy_backend`) — off-gpu this is the 2048 row
-    verbatim."""
-    if backend is None:
-        from . import backend as BK
-
-        backend = BK.policy_backend(None)
-    lanes = 1024 if backend == "gpu" else 2048
-    per = max(4, lanes // max(1, n))
+    fall to Pb = 4 so the (B, n, Pb, n) intermediates keep fitting."""
+    per = max(4, 2048 // max(1, n))
     pb = 4
     # tts-lint: waive tracer-branch -- pure host policy on Python ints; P and n are static shapes at every call site (traced callers resolve the knob before tracing)
     while pb * 2 <= per:
@@ -569,11 +544,11 @@ def _auto_pairblock(P: int, n: int, backend: str | None = None) -> int:
     return max(1, min(P, pb))
 
 
-def lb2_pairblock(P: int, n: int, backend: str | None = None) -> int:
+def lb2_pairblock(P: int, n: int) -> int:
     """Resolved lb2 pair-block size for a (P pairs, n jobs) shape.
 
     ``TTS_LB2_PAIRBLOCK`` / ``--lb2-pairblock``: ``auto`` (default) applies
-    `_auto_pairblock` (backend-keyed — see its gpu row); an explicit
+    `_auto_pairblock`; an explicit
     positive integer forces the block size (``1`` = the serial per-pair
     fori_loop, the pre-blocking behavior; values above P clamp to P).
     Baked into compiled programs at trace time, so `routing_cache_token`
@@ -582,7 +557,7 @@ def lb2_pairblock(P: int, n: int, backend: str | None = None) -> int:
 
     knob = os.environ.get("TTS_LB2_PAIRBLOCK", "auto")
     if knob == "auto":
-        return _auto_pairblock(P, n, backend)
+        return _auto_pairblock(P, n)
     try:
         v = int(knob)
     except ValueError:
@@ -598,13 +573,13 @@ def lb2_pairblock(P: int, n: int, backend: str | None = None) -> int:
     return min(v, P)
 
 
-def lb2_kernel_pair_group(P: int, n: int, backend: str | None = None) -> int:
+def lb2_kernel_pair_group(P: int, n: int) -> int:
     """Pair-group unroll of the Pallas lb2 kernels: the same knob, capped
     at 8 — the kernel VMEM model charges the per-pair live values once per
     unrolled group member (`pallas_kernels._model_bytes`), and 8 is the
     largest group whose modeled footprint keeps MXU-efficient batch tiles
     at the published shapes."""
-    return min(lb2_pairblock(P, n, backend), 8)
+    return min(lb2_pairblock(P, n), 8)
 
 
 def lb2_bounds(prmu, limit1, tables: "PFSPDeviceTables", device=None):
@@ -615,11 +590,9 @@ def lb2_bounds(prmu, limit1, tables: "PFSPDeviceTables", device=None):
 
     n, m = prmu.shape[-1], tables.ptm_t.shape[1]
     P = tables.pairs.shape[0]
-    kb = _kernel_kind(device)
     if lb2_kernel_routed(n, m, P, device):
         return PK.pfsp_lb2_bounds(
-            prmu, limit1, tables,
-            pair_group=lb2_kernel_pair_group(P, n, kb), backend=kb,
+            prmu, limit1, tables, pair_group=lb2_kernel_pair_group(P, n),
         )
     return _lb2_chunk(
         prmu, limit1, tables.ptm_t, tables.min_heads, tables.min_tails,
@@ -724,11 +697,10 @@ def lb2_self_bounds(prmu, limit1, n_active, tables: "PFSPDeviceTables",
 
     n, m = prmu.shape[-1], tables.ptm_t.shape[1]
     P = tables.pairs.shape[0]
-    kb = _kernel_kind(device)
     if lb2_kernel_routed(n, m, P, device, self_bound=True):
         return PK.pfsp_lb2_self_bounds(
             prmu, limit1, n_active, tables,
-            pair_group=lb2_kernel_pair_group(P, n, kb), backend=kb,
+            pair_group=lb2_kernel_pair_group(P, n),
         )
     return _lb2_self_chunk(
         prmu, limit1, tables.ptm_t, tables.min_heads, tables.min_tails,
@@ -771,7 +743,6 @@ def lb2_self_bounds_mp(prmu, limit1, n_active, tables: "PFSPDeviceTables",
     pairs, lags, scheds = tables.mp_padded(mp_size)
     P_local = pairs.shape[0] // mp_size
     start = idx * P_local
-    kb = _kernel_kind(device)
     if lb2_kernel_routed(n, m, P_local, device, self_bound=True):
         ordered = tables.johnson_ordered_mp(mp_size)
         assert ordered.lag_o.shape[0] == pairs.shape[0]
@@ -779,7 +750,7 @@ def lb2_self_bounds_mp(prmu, limit1, n_active, tables: "PFSPDeviceTables",
         local = PK.pfsp_lb2_self_bounds_tables(
             prmu, limit1, n_active, tables.ptm_t, sliced,
             bf16=tables.exact_bf16,
-            pair_group=lb2_kernel_pair_group(P_local, n, kb), backend=kb,
+            pair_group=lb2_kernel_pair_group(P_local, n),
         )
     else:
         prs = jax.lax.dynamic_slice_in_dim(pairs, start, P_local, axis=0)
@@ -833,15 +804,10 @@ def routing_cache_token(problem, device=None) -> tuple:
     silently reusing a stale program. One definition — used by both the
     resident and mesh-resident cache keys."""
     from ..problems.base import narrow_mode
-    from . import backend as BK
     from . import pallas_kernels as PK
     from .megakernel import megakernel_mode
 
     tok: tuple = (PK.use_pallas(device), PK.pallas_interpret(),
-                  # Kernel-backend seam (ops/backend.py): the raw knob and
-                  # the flavor it resolves to — a TTS_KERNEL_BACKEND flip
-                  # rebuilds instead of reusing the other flavor's program.
-                  BK.kernel_backend_mode(), BK.kernel_kind(device),
                   # lb1-family demotion override (TTS_PALLAS=force) is a
                   # trace-time routing decision like the rest.
                   PK.pallas_forced(),

@@ -618,11 +618,11 @@ def _host_search(
         topo = topology or Topology.detect(collectives.num_hosts)
         # Resolved from env + the (shared) profile file only — every host
         # lands on the identical policy without communication.
-        from ..ops import backend as BK
+        from ..ops.pallas_kernels import platform
 
         policy = resolve_policy(
             problem, topo, m=m, cap=M, interval_s=steal_interval_s,
-            backend=BK.profile_backend(),
+            backend=platform(),
             topo_str=f"dist-H{collectives.num_hosts}xD{D}",
         )
         comm = _HostComm(

@@ -179,12 +179,12 @@ def _host_loop(
             H, slice_index=slice_idx,
             allgather=coll.allgather_obj if slice_idx is not None else None,
         )
-        from ..ops import backend as BK
+        from ..ops.pallas_kernels import platform
 
         policy = resolve_policy(
             problem, topo, m=m, cap=D * M,
             interval_s=exchange_sleep_s or (band[0] + band[1]) / 2.0,
-            backend=BK.profile_backend(),
+            backend=platform(),
             topo_str=f"dist_mesh-H{H}xD{D}",
         )
     ctl = AdaptiveK(k_value, target=band) if k_auto else None

@@ -793,7 +793,6 @@ def mesh_resident_search(
                 megakernel_reason=program.inner.megakernel.reason,
                 megakernel_mt=program.inner.megakernel.mt or None,
                 megakernel_tiled=program.inner.megakernel.tiled,
-                lb2_body=program.inner.megakernel.lb2_body,
                 kernel_backend=program.inner.kernel_backend,
                 kernel_interpret=program.inner.kernel_interpret,
                 pipeline_depth=depth,
@@ -892,7 +891,6 @@ def mesh_resident_search(
         megakernel_reason=program.inner.megakernel.reason,
         megakernel_mt=program.inner.megakernel.mt or None,
         megakernel_tiled=program.inner.megakernel.tiled,
-        lb2_body=program.inner.megakernel.lb2_body,
         kernel_backend=program.inner.kernel_backend,
         kernel_interpret=program.inner.kernel_interpret,
         pipeline_depth=depth,

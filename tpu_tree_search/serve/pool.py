@@ -96,7 +96,7 @@ def resolved_knobs(spec: dict, platform: str) -> dict:
             "TTS_LB2_PAIRBLOCK", "auto"
         )
         if pb == "auto":
-            pairblock = P._auto_pairblock(Pn, n, platform)
+            pairblock = P._auto_pairblock(Pn, n)
         else:
             pairblock = min(int(pb), Pn)
     return {"compact": compact, "lb2_pairblock": pairblock}
