@@ -204,12 +204,11 @@ def test_guard_green_armed(monkeypatch):
 
 @pytest.mark.parametrize("inst", [14, 21])
 def test_lb2_bf16_mxu_bit_parity_on_taillard(inst):
-    """The max-plus MXU formulation the megakernel arms with
-    (``megakernel_lb2_bounds``, bf16 one-hot gathers) is BIT-equal to the
-    f32 pair-blocked oracle (`pfsp_device._lb2_chunk`) on ta014/ta021
-    nodes — on open slots (closed slots carry garbage both engines mask).
-    If this ever fails, `resolve`'s exactness gate is wrong and the
-    kernel must refuse to arm for the instance class."""
+    """The lb2 bounds the megakernel arms with (``megakernel_lb2_bounds``:
+    the int32 lane-dense tile) are BIT-equal to the f32 pair-blocked
+    oracle (`pfsp_device._lb2_chunk`) on ta014/ta021 nodes, which pass
+    `resolve`'s exactness gate — on open slots (closed slots carry garbage
+    both engines mask)."""
     prob = PFSPProblem(inst=inst, lb="lb2", ub=1)
     t = prob.device_tables()
     assert t.exact_bf16  # the gate resolve() checks before arming
@@ -291,6 +290,41 @@ def test_lb2_lanes_contract_catches_a_matmul_in_the_pair_loop(monkeypatch):
         MK._lb2_push_call.cache_clear()
     msgs = contracts.run_one("megakernel-lb2-lanes", art, cell)
     assert any("dot_general" in m for m in msgs), msgs
+
+
+@pytest.mark.parametrize("family", ["pfsp-lb1", "pfsp-lb2"])
+def test_bound_lanes_contract_catches_a_one_hot_prologue(family, monkeypatch):
+    """`megakernel-pfsp-bound-lanes` holds on the armed lb1 and lb2 cells
+    of the audit and names the (Mt, n, n) one-hot that a prologue building
+    one brings back into the kernel's bound region."""
+    from tpu_tree_search.analysis import contracts, program_audit
+    from tpu_tree_search.ops import pallas_kernels as PK
+
+    program_audit.load_contracts()
+    pb = "auto" if family == "pfsp-lb2" else None
+    cell = program_audit.Cell(family, pairblock=pb, megakernel="force")
+    name = "megakernel-pfsp-bound-lanes"
+    art = program_audit.trace_cell(cell)
+    assert contracts.run_one(name, art, cell) == []
+    real = PK._tile_parent_state
+
+    def one_hot(prmu, *args, **kw):
+        s = real(prmu, *args, **kw)
+        T, n = prmu.shape
+        iota = jax.lax.broadcasted_iota(jnp.int32, (T, n, n), 2)
+        hits = jnp.sum((iota == prmu[:, :, None]).astype(jnp.int32), axis=2)
+        return s._replace(cf=[s.cf[0] + 0 * hits.T, *s.cf[1:]])
+
+    monkeypatch.setattr(PK, "_tile_parent_state", one_hot)
+    MK._lb1_push_call.cache_clear()
+    MK._lb2_push_call.cache_clear()
+    try:
+        art = program_audit.trace_cell(cell)
+    finally:
+        MK._lb1_push_call.cache_clear()
+        MK._lb2_push_call.cache_clear()
+    msgs = contracts.run_one(name, art, cell)
+    assert any("rank-3" in m for m in msgs), msgs
 
 
 def test_family_refusal_lb1d(monkeypatch):
@@ -489,7 +523,8 @@ def _warm_frontier(prob, target: int, copies: int = 1):
 
 
 def _trail(mk, env, monkeypatch, *, frontier, M: int = 64,
-           steps: int = 6, poison: int | None = None):
+           steps: int = 6, poison: int | None = None,
+           capacity: int = 8192):
     """Dispatch a resident program one cycle at a time (K=1) from
     ``frontier`` and record, after each dispatch, the counts and the pool
     rows [0, size) as nodes.  ``poison`` first fills every pool row at and
@@ -503,7 +538,7 @@ def _trail(mk, env, monkeypatch, *, frontier, M: int = 64,
         monkeypatch.setenv(k, v)
     prob = mk()
     n = prob.child_slots
-    capacity, M = resolve_capacity(prob, M, 8192)
+    capacity, M = resolve_capacity(prob, M, capacity)
     prog = _make_program(prob, 1, M, 1, capacity, jax.devices()[0])
     state = prog.init_state(frontier(prob), INF_BOUND)
     W = prog.row_width
@@ -554,6 +589,23 @@ def test_push_pool_rows_match_off_every_dispatch(family, mt, narrow,
     prog, on = _trail(mk, env, monkeypatch, frontier=frontier)
     assert prog.megakernel.enabled and prog.megakernel.tiled == bool(mt)
     assert prog.row_pack > 1  # nodes share a pool row
+    _assert_same_trail(on, off)
+
+
+@pytest.mark.parametrize("lb", ["lb1", "lb2"])
+def test_ta014_cycle_matches_off_at_the_cells_tile(lb, monkeypatch):
+    """ta014 (20x10, ub = the optimum) through the armed cycle at the
+    benchmark cells' tile, Mt=128 (two tiles of M=256): after each of a few
+    dispatches the pool rows, size, solution count and incumbent equal
+    the fused-jnp resident's."""
+    mk = lambda: PFSPProblem(inst=14, lb=lb, ub=1)  # noqa: E731
+    frontier = lambda prob: _warm_frontier(prob, 300)  # noqa: E731
+    run = dict(frontier=frontier, M=256, steps=3, capacity=1 << 15)
+    _, off = _trail(mk, {"TTS_MEGAKERNEL": "0"}, monkeypatch, **run)
+    prog, on = _trail(mk, {"TTS_MEGAKERNEL": "force",
+                           "TTS_MEGAKERNEL_MT": "128"}, monkeypatch, **run)
+    assert (prog.megakernel.mt, prog.megakernel.grid) == (128, 2)
+    assert len(on) == 3 and on[-1][0][3] > 0  # three cycles, a live pool
     _assert_same_trail(on, off)
 
 
@@ -719,11 +771,11 @@ _V5E_ROUTES = {
     "ta021-lb2": (lambda: PFSPProblem(inst=21, lb="lb2", ub=1), 1024,
                   (True, 128, 8)),
     "ta031-lb1": (lambda: PFSPProblem(inst=31, lb="lb1", ub=1), 1024,
-                  (True, 64, 16)),
+                  (True, 128, 8)),
     "ta051-lb1": (lambda: PFSPProblem(inst=51, lb="lb1", ub=1), 1024,
-                  (True, 64, 16)),
+                  (True, 128, 8)),
     "ta051-lb2": (lambda: PFSPProblem(inst=51, lb="lb2", ub=1), 1024,
-                  (True, 64, 16)),
+                  (True, 128, 8)),
 }
 
 

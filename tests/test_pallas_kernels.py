@@ -49,11 +49,12 @@ def test_lb1_bounds_match_oracle(inst, jobs, machines, bf16):
     prmu = np.stack([rng.permutation(jobs).astype(np.int32) for _ in range(B)])
     limit1 = rng.integers(-1, jobs - 1, B).astype(np.int32)
     oracle = pfsp_device._lb1_chunk(
-        jnp.asarray(prmu), jnp.asarray(limit1), t.ptm_t, t.min_heads, t.min_tails
+        jnp.asarray(prmu), jnp.asarray(limit1), t.ptm_t, t.min_heads,
+        t.min_tails, bf16=bf16,
     )
     got = pallas_kernels.pfsp_lb1_bounds(
         jnp.asarray(prmu), jnp.asarray(limit1), t.ptm_t, t.min_heads, t.min_tails,
-        interpret=True, bf16=bf16,
+        interpret=True,
     )
     assert np.array_equal(np.asarray(oracle), np.asarray(got))
 
@@ -76,10 +77,10 @@ def test_lb2_bounds_match_oracle(inst, jobs, machines, bf16):
     limit1 = rng.integers(-1, jobs - 1, B).astype(np.int32)
     oracle = pfsp_device._lb2_chunk(
         jnp.asarray(prmu), jnp.asarray(limit1), t.ptm_t, t.min_heads,
-        t.min_tails, t.pairs, t.lags, t.johnson_schedules,
+        t.min_tails, t.pairs, t.lags, t.johnson_schedules, bf16=bf16,
     )
     got = pallas_kernels.pfsp_lb2_bounds(
-        jnp.asarray(prmu), jnp.asarray(limit1), t, interpret=True, bf16=bf16
+        jnp.asarray(prmu), jnp.asarray(limit1), t, interpret=True
     )
     # Compare only open child slots (k > limit1): closed slots are garbage
     # by contract (never read by the host/engine).
@@ -186,6 +187,78 @@ def test_lb2_lane_body_matches_oracles(shape, entry, tile, monkeypatch):
             ), (i, k)
 
 
+#: (instance, tile) of the prologue parity cases: ta014 (20x10) and ta001
+#: (20x5) at the cycle's 128-wide tile, ta051 (50x20) at a 64-wide one.
+_PROLOGUE_SHAPES = {"ta014-T128": (14, 128), "ta001-T128": (1, 128),
+                    "ta051-T64": (51, 64)}
+#: bound -> (the kernel's `_KERNEL_MODEL` name, its tile knob)
+_PROLOGUE_KERNELS = {"lb1": ("lb1", "TTS_TILE_LB1"),
+                     "lb1_d": ("lb1d", "TTS_TILE_LB1D"),
+                     "lb2": ("lb2", "TTS_TILE_LB2"),
+                     "lb2_self": ("lb2self", "TTS_TILE_LB2SELF")}
+
+
+@pytest.mark.parametrize("shape", sorted(_PROLOGUE_SHAPES))
+@pytest.mark.parametrize("bound", sorted(_PROLOGUE_KERNELS))
+def test_bound_prologue_matches_oracles(bound, shape, monkeypatch):
+    """The lane-dense tile prologue (`pallas_kernels._tile_parent_state`:
+    the tile's parents on the lanes, the times gathered by selects, the
+    fronts scanned on (1, T) rows) under each PFSP bound kernel, at the
+    tile the case pins, bit-equal to the jnp oracles `_lb1_chunk`,
+    `_lb1_d_chunk`, `_lb2_chunk` and `_lb2_self_chunk`.  Every tile holds
+    rows at the root (limit1 = -1; the self bound's rows are children, so
+    0 there), at mid-depth and at the leaf level (n - 2), and rows that
+    are no permutation; the last tile is padded.  lb1 and lb1_d agree on
+    every slot of every row; the lb2 bounds on the open slots of the real
+    nodes (the others are garbage by contract)."""
+    inst, tile = _PROLOGUE_SHAPES[shape]
+    kernel, knob = _PROLOGUE_KERNELS[bound]
+    monkeypatch.setenv(knob, str(tile))
+    prob = PFSPProblem(inst=inst, lb="lb2", ub=1)
+    t = prob.device_tables()
+    n, m, P = prob.jobs, prob.machines, t.pairs.shape[0]
+    pg = 1 if bound == "lb2_self" else None  # its matmul body fits at T
+    assert pallas_kernels.effective_tile(kernel, n, m, P,
+                                         pair_group=pg) == tile
+    rng = np.random.default_rng(inst * 1000 + tile)
+    B = 2 * tile - 8
+    prmu = np.stack([rng.permutation(n) for _ in range(B)]).astype(np.int32)
+    lo = 0 if bound == "lb2_self" else -1
+    limit1 = (lo + np.arange(B) % (n - 1 - lo)).astype(np.int32)
+    bad = rng.choice(B, 6, replace=False)
+    prmu[bad] = rng.integers(0, n, size=(6, n))
+    pd, ld = jnp.asarray(prmu), jnp.asarray(limit1)
+    inst_tables = (t.ptm_t, t.min_heads, t.min_tails)
+    johnson = (t.pairs, t.lags, t.johnson_schedules)
+    if bound == "lb1":
+        got = pallas_kernels.pfsp_lb1_bounds(pd, ld, *inst_tables,
+                                             interpret=True)
+        want = pfsp_device._lb1_chunk(pd, ld, *inst_tables)
+    elif bound == "lb1_d":
+        got = pallas_kernels.pfsp_lb1_d_bounds(pd, ld, *inst_tables,
+                                               interpret=True)
+        want = pfsp_device._lb1_d_chunk(pd, ld, *inst_tables)
+    elif bound == "lb2":
+        got = pallas_kernels.pfsp_lb2_bounds(pd, ld, t, interpret=True)
+        want = pfsp_device._lb2_chunk(pd, ld, *inst_tables, *johnson)
+    else:
+        got = pallas_kernels.pfsp_lb2_self_bounds(pd, ld, B, t,
+                                                  interpret=True,
+                                                  pair_group=pg)
+        want = pfsp_device._lb2_self_chunk(pd, ld, *inst_tables, *johnson)
+    got, want = np.asarray(got), np.asarray(want)
+    if bound in ("lb1", "lb1_d"):
+        assert np.array_equal(got, want)
+        return
+    real = np.ones(B, bool)
+    real[bad] = False
+    if bound == "lb2_self":
+        assert np.array_equal(got[real], want[real])
+        return
+    open_ = (np.arange(n)[None, :] > limit1[:, None]) & real[:, None]
+    assert np.array_equal(got[open_], want[open_])
+
+
 def test_use_pallas_is_off_on_cpu(monkeypatch):
     monkeypatch.delenv("TTS_PALLAS", raising=False)
     assert pallas_kernels.use_pallas() is False  # tests run on the CPU backend
@@ -279,11 +352,12 @@ def test_lb1_d_bounds_match_oracle(inst, jobs, machines, bf16):
     prmu = np.stack([rng.permutation(jobs).astype(np.int32) for _ in range(B)])
     limit1 = rng.integers(-1, jobs - 1, B).astype(np.int32)
     oracle = pfsp_device._lb1_d_chunk(
-        jnp.asarray(prmu), jnp.asarray(limit1), t.ptm_t, t.min_heads, t.min_tails
+        jnp.asarray(prmu), jnp.asarray(limit1), t.ptm_t, t.min_heads,
+        t.min_tails, bf16=bf16,
     )
     got = pallas_kernels.pfsp_lb1_d_bounds(
         jnp.asarray(prmu), jnp.asarray(limit1), t.ptm_t, t.min_heads, t.min_tails,
-        interpret=True, bf16=bf16,
+        interpret=True,
     )
     assert np.array_equal(np.asarray(oracle), np.asarray(got))
 

@@ -78,8 +78,7 @@ def test_lb1_bounds_compiles_ta014(chip):
     n, B = 20, 1024
     txt = _compile_text(
         lambda prmu, limit1: PK.pfsp_lb1_bounds(
-            prmu, limit1, t.ptm_t, t.min_heads, t.min_tails,
-            bf16=t.exact_bf16),
+            prmu, limit1, t.ptm_t, t.min_heads, t.min_tails),
         chip, ((B, n), jnp.int32), ((B,), jnp.int32),
     )
     assert "tpu_custom_call" in txt

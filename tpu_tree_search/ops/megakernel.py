@@ -53,12 +53,14 @@ Exactness:
   HIGHEST precision — counts are < 2^24, so f32 accumulation is exact;
 * lb1 is the int32 chain of `_lb1_tile_lb` (bit-exact vs `_lb1_chunk` on
   open slots);
-* lb2 is only allowed to arm when the instance passes the bf16-exactness
-  gate (`PFSPDeviceTables.exact_bf16`: every processing time < 2^8, so
-  every matmul operand is exactly representable in bf16) — otherwise
-  :func:`resolve` refuses and records why (banner + SearchResult).  Its
-  pair loop is int32 scalar-times-slab work on lane-dense child slabs
-  (`pallas_kernels._lb2_tile_lb`).
+* the PFSP bound prologue (`pallas_kernels._tile_parent_state`) and
+  lb2's pair loop (`pallas_kernels._lb2_tile_lb`) are int32 selects and
+  max-plus on lane-dense slabs, with no matmul; lb2 still arms only when
+  the instance passes the bf16-exactness gate
+  (`PFSPDeviceTables.exact_bf16`: every processing time < 2^8), the
+  condition its prologue's one-hot matmul gathers once needed —
+  otherwise :func:`resolve` refuses and records why (banner +
+  SearchResult).
 
 Routing (`TTS_MEGAKERNEL=auto|0|force`, resolved like the compact auto
 policy): ``auto`` arms only on a TPU device and when the per-tile
@@ -121,6 +123,13 @@ def _pool_itemsize(fam: str, n: int) -> int:
     if fam == "nqueens":
         return 1
     return 1 if n <= 127 else (2 if n <= 32767 else 4)
+
+#: Widest streamed pool tile of a PFSP cycle (`_resolve_mt`): the bound
+#: prologue puts the tile's parents on the 128 lanes of its slabs
+#: (`pallas_kernels._tile_parent_state`), and the compaction's (Mt*n, Mt)
+#: selection does Mt times the work per node; the VMEM model alone admits
+#: wider tiles at 20 jobs.
+PFSP_MAX_MT = 128
 
 #: mirrors problems.base.INF_BOUND without importing the problems package
 #: into a kernel module (the packages import each other lazily).
@@ -373,9 +382,9 @@ def _fits(problem, fam: str, M: int, n: int,
     extra = _mega_pool_bytes(M, n, itemsize, mt=mt,
                              lb_stash=(fam != "nqueens"))
     if fam == "nqueens":
-        need = PK._model_bytes(t, n, 1, extra, 3)
+        need = PK._model_bytes(t, n, 0, extra, 3)
     elif fam == "lb1":
-        need = PK._model_bytes(t, n, problem.machines, extra, 3)
+        need = PK._model_bytes(t, n, problem.machines, extra, 0)
     else:  # lb2
         from . import pfsp_device as PD
 
@@ -384,7 +393,7 @@ def _fits(problem, fam: str, M: int, n: int,
         pg = PD.lb2_kernel_pair_group(P, n)
         # The lane-dense pair body: its tables stream through SMEM.
         need = PK._model_bytes(
-            t, n, m, extra, 3, lane_slabs=PK._lb2_lane_slabs(n, m, pg))
+            t, n, m, extra, 0, lane_slabs=PK._lb2_lane_slabs(n, m, pg))
     budget = PK._vmem_budget()
     if need > budget:
         if mt is None:
@@ -402,13 +411,24 @@ def _fits(problem, fam: str, M: int, n: int,
     return True, None
 
 
+def _widest_mt(fam: str, M: int) -> int:
+    """The widest streamed pool tile a cycle may take: M, or for PFSP the
+    largest multiple of 8 that divides M up to :data:`PFSP_MAX_MT`."""
+    if fam == "nqueens" or M <= PFSP_MAX_MT:
+        return M
+    mt = PFSP_MAX_MT
+    while M % mt:
+        mt -= 8
+    return mt
+
+
 def _resolve_mt(problem, fam: str, M: int, n: int) -> int | None:
     """Resolve the streamed pool-tile width `_auto_compact`-style: the
-    largest halving-ladder Mt (multiple of 8, divides M) whose tile stays
-    inside the small-M window AND whose per-tile VMEM model fits.  None
-    when even the smallest tile cannot fit (the caller records the
-    refusal)."""
-    mt = M
+    largest halving-ladder Mt (multiple of 8, divides M, at most
+    `_widest_mt`) whose tile stays inside the small-M window AND whose
+    per-tile VMEM model fits.  None when even the smallest tile cannot fit
+    (the caller records the refusal)."""
+    mt = _widest_mt(fam, M)
     while True:
         if _tile_window_ok(fam, mt, n) and _fits(problem, fam, M, n, mt)[0]:
             return mt
@@ -450,9 +470,9 @@ def resolve(problem, M: int, device=None, mp_axis: str | None = None,
         if not getattr(t, "exact_bf16", False):
             return Decision(False, auto, False,
                             "lb2 bf16-exactness gate: max processing time "
-                            ">= 256, the max-plus MXU formulation is not "
-                            "bit-exact (f32 pair-blocked oracle keeps the "
-                            "cycle)")
+                            ">= 256; the cycle arms only on instances that "
+                            "pass it (the f32 pair-blocked oracle keeps "
+                            "the cycle)")
     mt_env = megakernel_mt()
     if mt_env is not None and (mt_env % 8 != 0 or M % mt_env != 0):
         return Decision(False, auto, False,
@@ -925,7 +945,9 @@ def _pfsp_push_cycle(bound, prmu_ref, limit1_ref, valid_ref, best_ref,
     incumbent — no tile may prune before every tile's leaves are folded
     (PFSP exactness rule); phase 1 re-streams the tiles (bounds come back
     from the stash, not recomputed), prunes and compacts against the
-    final incumbent, and pushes."""
+    final incumbent, and pushes.  ``bound(prmu, limit1)`` takes the tile's
+    (Mt, 1) limit column as its block holds it
+    (`pallas_kernels._tile_parent_state`)."""
     p = pl.program_id(0)
     i = pl.program_id(1)
 
@@ -941,7 +963,7 @@ def _pfsp_push_cycle(bound, prmu_ref, limit1_ref, valid_ref, best_ref,
     @pl.when(p == 0)
     def _sweep():
         with jax.named_scope(BOUND_SCOPE):
-            lb = bound(prmu, limit1)
+            lb = bound(prmu, limit1_ref[:].astype(jnp.int32))
         lb_ref[i] = lb
         r.carry[2] = jnp.minimum(
             r.carry[2], _pfsp_leaf_min(limit1, valid, lb, n=n, M=Mt))
@@ -961,17 +983,16 @@ def _pfsp_push_cycle(bound, prmu_ref, limit1_ref, valid_ref, best_ref,
 
 
 def _mega_lb1_push_kernel(prmu_ref, limit1_ref, valid_ref, best_ref,
-                          size_ref, ptm_ref, heads_ref, tails_ref,
+                          size_ref, pt_ref, heads_ref, tails_ref,
                           pv_in, pa_in, pv_ref, pa_ref, scal_ref,
-                          scan_ref, lb_ref, stage_v, stage_a, head_v, head_a,
+                          times_ref, lb_ref, stage_v, stage_a, head_v, head_a,
                           sems, carry_ref, *, n: int, m: int, Mt: int,
-                          G: int, bf16: bool):
+                          G: int):
     del pv_in, pa_in  # aliased to pv_ref / pa_ref
 
     def bound(prmu, limit1):
-        return PK._lb1_tile_lb(prmu, limit1, ptm_ref[:].astype(jnp.float32),
-                               heads_ref[:], tails_ref[:], scan_ref,
-                               n=n, m=m, bf16=bf16)
+        return PK._lb1_tile_lb(prmu, limit1, pt_ref, heads_ref, tails_ref,
+                               times_ref, n=n, m=m)
 
     _pfsp_push_cycle(
         bound, prmu_ref, limit1_ref, valid_ref, best_ref,
@@ -981,19 +1002,17 @@ def _mega_lb1_push_kernel(prmu_ref, limit1_ref, valid_ref, best_ref,
 
 
 def _mega_lb2_push_kernel(prmu_ref, limit1_ref, valid_ref, best_ref,
-                          size_ref, ptm_ref, heads_ref, slots_ref, pairs_ref,
+                          size_ref, pt_ref, heads_ref, slots_ref, pairs_ref,
                           pv_in, pa_in, pv_ref, pa_ref, scal_ref,
-                          scan_ref, lb_ref, stage_v, stage_a, head_v, head_a,
+                          times_ref, lb_ref, stage_v, stage_a, head_v, head_a,
                           sems, carry_ref, *lane_scratch, n: int, m: int,
-                          P: int, Mt: int, pg: int, G: int, bf16: bool):
+                          P: int, Mt: int, pg: int, G: int):
     del pv_in, pa_in  # aliased to pv_ref / pa_ref
     lanes = PK._Lb2Lanes(slots_ref, pairs_ref, *lane_scratch)
 
     def bound(prmu, limit1):
-        return PK._lb2_tile_lb(
-            prmu, limit1, ptm_ref[:].astype(jnp.float32), heads_ref[:],
-            lanes, scan_ref, n=n, m=m, P=P, pg=pg, bf16=bf16,
-        )
+        return PK._lb2_tile_lb(prmu, limit1, pt_ref, heads_ref, lanes,
+                               times_ref, n=n, m=m, P=P, pg=pg)
 
     _pfsp_push_cycle(
         bound, prmu_ref, limit1_ref, valid_ref, best_ref,
@@ -1099,26 +1118,21 @@ def _nqueens_push_call(N: int, g: int, M: int, mt: int, W: int, rows: int,
 
 
 @lru_cache(maxsize=None)
-def _lb1_push_call(n: int, m: int, M: int, mt: int, bf16: bool, W: int,
-                   rows: int, vdt, adt, interpret: str | None):
+def _lb1_push_call(n: int, m: int, M: int, mt: int, W: int, rows: int, vdt,
+                   adt, interpret: str | None):
     G = M // mt
-    full = lambda p, i: (0, 0)
     in_specs, out_shape, out_specs, scratch = _push_plumbing(
         mt, n, W, rows, vdt, adt, two_phase=True)
-    in_specs = in_specs + [
-        pl.BlockSpec((n, m), full, memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, m), full, memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, m), full, memory_space=pltpu.VMEM),
-    ]
+    in_specs = in_specs + PK._pfsp_table_specs(n, m, lambda p, i: (0,))
     k = len(in_specs)
     return pl.pallas_call(
-        partial(_mega_lb1_push_kernel, n=n, m=m, Mt=mt, G=G, bf16=bf16),
+        partial(_mega_lb1_push_kernel, n=n, m=m, Mt=mt, G=G),
         out_shape=out_shape,
         name="tts_cycle_lb1",
         grid=(2, G),
         in_specs=in_specs + [pl.BlockSpec(memory_space=pl.ANY)] * 2,
         out_specs=out_specs,
-        scratch_shapes=[pltpu.VMEM((n, mt, m), jnp.int32),
+        scratch_shapes=[PK._times_scratch(n, m, mt),
                         pltpu.VMEM((G, mt, n), jnp.int32)] + scratch,
         input_output_aliases={k: 0, k + 1: 1},
         compiler_params=PK._compiler_params(ndims=2),
@@ -1127,28 +1141,24 @@ def _lb1_push_call(n: int, m: int, M: int, mt: int, bf16: bool, W: int,
 
 
 @lru_cache(maxsize=None)
-def _lb2_push_call(n: int, m: int, P: int, M: int, mt: int, pg: int,
-                   bf16: bool, W: int, rows: int, vdt, adt,
-                   interpret: str | None):
+def _lb2_push_call(n: int, m: int, P: int, M: int, mt: int, pg: int, W: int,
+                   rows: int, vdt, adt, interpret: str | None):
     G = M // mt
-    full = lambda p, i: (0, 0)
     in_specs, out_shape, out_specs, scratch = _push_plumbing(
         mt, n, W, rows, vdt, adt, two_phase=True)
     in_specs = in_specs + [
-        pl.BlockSpec((n, m), full, memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, m), full, memory_space=pltpu.VMEM),
+        *PK._pfsp_table_specs(n, m, lambda p, i: (0,))[:2],
         *PK._lb2_lane_specs(P, lambda p, i: (0,)),
     ]
     k = len(in_specs)
     return pl.pallas_call(
-        partial(_mega_lb2_push_kernel, n=n, m=m, P=P, Mt=mt, pg=pg, G=G,
-                bf16=bf16),
+        partial(_mega_lb2_push_kernel, n=n, m=m, P=P, Mt=mt, pg=pg, G=G),
         out_shape=out_shape,
         name="tts_cycle_lb2",
         grid=(2, G),
         in_specs=in_specs + [pl.BlockSpec(memory_space=pl.ANY)] * 2,
         out_specs=out_specs,
-        scratch_shapes=[pltpu.VMEM((n, mt, m), jnp.int32),
+        scratch_shapes=[PK._times_scratch(n, m, mt),
                         pltpu.VMEM((G, mt, n), jnp.int32)] + scratch
         + PK._lb2_lane_scratch(n, m, mt, pg),
         input_output_aliases={k: 0, k + 1: 1},
@@ -1197,13 +1207,12 @@ def make_cycle(problem, M: int, device, decision: Decision,
     else:
         t = problem.device_tables()
         m = problem.machines
-        bf16 = bool(getattr(t, "exact_bf16", False))
+        inst = PK.pfsp_kernel_tables(t.ptm_t, t.min_heads, t.min_tails)
         if fam == "lb1":
-            tables = (t.ptm_t, t.min_heads[None, :], t.min_tails[None, :])
+            tables = inst
 
             def push_call(W, rows, vdt, adt, mode):
-                return _lb1_push_call(n, m, M, mt, bf16, W, rows, vdt,
-                                      adt, mode)
+                return _lb1_push_call(n, m, M, mt, W, rows, vdt, adt, mode)
         else:
             # lb2 — Johnson-ordered tables resolved exactly like
             # `pfsp_lb2_bounds` (device cache when eager, numpy constants
@@ -1215,12 +1224,11 @@ def make_cycle(problem, M: int, device, decision: Decision,
             ordered = (t.johnson_ordered_device(pg) if PK._eager_context()
                        else t.johnson_ordered_mp(pg))
             Pp = ordered.lag_o.shape[0]
-            tables = (t.ptm_t, t.min_heads[None, :],
-                      *PK.lb2_kernel_tables(ordered, pg))
+            tables = (*inst[:2], *PK.lb2_kernel_tables(ordered, pg))
 
             def push_call(W, rows, vdt, adt, mode):
-                return _lb2_push_call(n, m, Pp, M, mt, pg, bf16, W, rows,
-                                      vdt, adt, mode)
+                return _lb2_push_call(n, m, Pp, M, mt, pg, W, rows, vdt,
+                                      adt, mode)
 
     def chunk(vals_c, aux_c, valid, best):
         return (vals_c, aux_c[:, None], valid.astype(jnp.int32)[:, None],
@@ -1253,12 +1261,11 @@ def _eval_nqueens_kernel(board_ref, depth_ref, out_ref, *, N: int, g: int):
     out_ref[:] = labels.astype(jnp.int32)
 
 
-def _eval_lb1_kernel(prmu_ref, limit1_ref, ptm_ref, heads_ref, tails_ref,
-                     out_ref, scan_ref, *, n: int, m: int, bf16: bool):
+def _eval_lb1_kernel(prmu_ref, limit1_ref, pt_ref, heads_ref, tails_ref,
+                     out_ref, times_ref, *, n: int, m: int):
     out_ref[:] = PK._lb1_tile_lb(
-        prmu_ref[:].astype(jnp.int32), limit1_ref[:, 0].astype(jnp.int32),
-        ptm_ref[:].astype(jnp.float32), heads_ref[:], tails_ref[:],
-        scan_ref, n=n, m=m, bf16=bf16)
+        prmu_ref[:].astype(jnp.int32), limit1_ref[:].astype(jnp.int32),
+        pt_ref, heads_ref, tails_ref, times_ref, n=n, m=m)
 
 
 @lru_cache(maxsize=None)
@@ -1278,25 +1285,21 @@ def _eval_nqueens_call(N: int, g: int, B: int, mt: int, interpret: bool):
 
 
 @lru_cache(maxsize=None)
-def _eval_lb1_call(n: int, m: int, B: int, mt: int, bf16: bool,
-                   interpret: bool):
+def _eval_lb1_call(n: int, m: int, B: int, mt: int, interpret: bool):
     tm = lambda i: (i, 0)
-    full = lambda i: (0, 0)
     bs = PK._bs
     return pl.pallas_call(
-        partial(_eval_lb1_kernel, n=n, m=m, bf16=bf16),
+        partial(_eval_lb1_kernel, n=n, m=m),
         out_shape=jax.ShapeDtypeStruct((B, n), jnp.int32),
         name="tts_eval_lb1",
         grid=(B // mt,),
         in_specs=[
             bs((mt, n), tm),
             bs((mt, 1), tm),
-            bs((n, m), full),
-            bs((1, m), full),
-            bs((1, m), full),
+            *PK._pfsp_table_specs(n, m, lambda i: (0,)),
         ],
         out_specs=bs((mt, n), tm),
-        scratch_shapes=[pltpu.VMEM((n, mt, m), jnp.int32)],
+        scratch_shapes=[PK._times_scratch(n, m, mt)],
         compiler_params=PK._compiler_params(parallel=True),
         interpret=interpret,
     )
@@ -1330,11 +1333,10 @@ def streamed_eval_bounds(problem, vals, aux, mt: int | None = None,
         return call(vals_c, aux_c)
     t = problem.device_tables()
     n, m = problem.jobs, problem.machines
-    bf16 = bool(getattr(t, "exact_bf16", False))
+    inst = PK.pfsp_kernel_tables(t.ptm_t, t.min_heads, t.min_tails)
     if fam == "lb1":
-        call = _eval_lb1_call(n, m, B, mt, bf16, interpret)
-        return call(vals_c, aux_c, t.ptm_t, t.min_heads[None, :],
-                    t.min_tails[None, :])
+        call = _eval_lb1_call(n, m, B, mt, interpret)
+        return call(vals_c, aux_c, *inst)
     from . import pfsp_device as PD
 
     P = t.pairs.shape[0]
@@ -1344,20 +1346,17 @@ def streamed_eval_bounds(problem, vals, aux, mt: int | None = None,
     Pp = ordered.lag_o.shape[0]
     # The carry-free lb2 pass: the standalone kernel at tile ``mt`` with
     # every grid axis Megacore-parallel.
-    call = PK._lb2_call(n, m, Pp, B, mt, interpret, bf16, pg, parallel=True)
-    return call(vals_c, aux_c, t.ptm_t, t.min_heads[None, :],
-                *PK.lb2_kernel_tables(ordered, pg))
+    call = PK._lb2_call(n, m, Pp, B, mt, interpret, pg, parallel=True)
+    return call(vals_c, aux_c, *inst[:2], *PK.lb2_kernel_tables(ordered, pg))
 
 
 def megakernel_lb2_bounds(prmu, limit1, tables, interpret: bool | None = None):
     """The lb2 bound values the megakernel arms with, as a standalone
-    (B, n) call — the shared `_lb2_tile_lb` body with the prologue's bf16
-    one-hot gathers.  The bf16-exactness gate test bit-compares this
-    against the f32 pair-blocked oracle (`pfsp_device._lb2_chunk`) on real
-    Taillard instances; a mismatch means :func:`resolve`'s gate is wrong
-    and the kernel must refuse to arm."""
-    return PK.pfsp_lb2_bounds(prmu, limit1, tables, interpret=interpret,
-                              bf16=True)
+    (B, n) call — the shared `_lb2_tile_lb` body, all int32.  The
+    bf16-exactness gate test bit-compares this against the f32
+    pair-blocked oracle (`pfsp_device._lb2_chunk`) on real Taillard
+    instances; a mismatch means the kernel must refuse to arm."""
+    return PK.pfsp_lb2_bounds(prmu, limit1, tables, interpret=interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -1564,4 +1563,58 @@ def _contract_megakernel_lb2_lanes(art, cell):
                             "axes", ()):
                     out.append(f"a loop in the cycle kernel holds a lane "
                                f"reduction ({pname})")
+    return sorted(set(out))
+
+
+def _scope_eqns(jaxpr, scope: str, inside: bool = False, out=None):
+    """The eqns of a kernel body and its sub-jaxprs traced under the named
+    scope ``scope``, or under an eqn that was."""
+    from ..analysis.contracts import subjaxprs
+
+    if hasattr(jaxpr, "jaxpr"):  # ClosedJaxpr
+        jaxpr = jaxpr.jaxpr
+    out = [] if out is None else out
+    for eqn in jaxpr.eqns:
+        here = inside or scope in str(eqn.source_info.name_stack).split("/")
+        if here:
+            out.append(eqn)
+        for v in eqn.params.values():
+            for sub in subjaxprs(v):
+                _scope_eqns(sub, scope, here, out)
+    return out
+
+
+@contract(
+    "megakernel-pfsp-bound-lanes",
+    claim="the armed PFSP cycle (lb1 and lb2) bounds its tile on "
+          "lane-dense slabs: inside the kernel's tts_bound region no value "
+          "has rank 3 and none has Mt*n rows — no (Mt, n, n) one-hot, no "
+          "(Mt*n, n) gather operand, no (Mt, n, m) times",
+    artifact="resident-step",
+    applies=lambda cell: cell is not None
+    and getattr(cell, "megakernel", None) == "force"
+    and cell.family in ("pfsp-lb1", "pfsp-lb2"),
+)
+def _contract_megakernel_pfsp_bound_lanes(art, cell):
+    prog = art.prog
+    dec = getattr(prog, "megakernel", None)
+    if dec is None or not dec.enabled:
+        return []
+    flat = dec.mt * int(prog.problem.child_slots)
+    out = []
+    for name, e in _outer_eqns(art.jaxpr):
+        if name != "pallas_call":
+            continue
+        eqns = _scope_eqns(e.params["jaxpr"], BOUND_SCOPE)
+        if not eqns:
+            out.append(f"cycle kernel has no {BOUND_SCOPE} region")
+        for eqn in eqns:
+            for v in eqn.outvars:
+                shape = tuple(getattr(v.aval, "shape", ()))
+                if len(shape) >= 3:
+                    out.append(f"{BOUND_SCOPE} holds a rank-3 value "
+                               f"{shape} ({eqn.primitive.name})")
+                elif shape and shape[0] == flat:
+                    out.append(f"{BOUND_SCOPE} holds an (Mt*n, ...) value "
+                               f"{shape} ({eqn.primitive.name})")
     return sorted(set(out))

@@ -3,10 +3,11 @@
 The XLA (jnp) evaluators in `nqueens_device.py` / `pfsp_device.py` are the
 semantic oracles and the portable path; these kernels are the hand-scheduled
 TPU variants: one VMEM-resident pass per batch tile — the instance tables
-(processing times, min heads/tails) are pinned in VMEM for the whole grid,
-every intermediate (the one-hot gather, the O(n) schedule_front scan, the
-per-child bound chain) lives in registers/VMEM, and nothing round-trips
-through HBM between fusion boundaries.
+(processing times, min heads/tails) sit in SMEM for the whole grid, every
+intermediate (the processing-time gather, the O(n) schedule_front scan,
+the per-child bound chain) lives in registers/VMEM on lane-dense slabs
+(the tile's parents on the 128 lanes), and nothing round-trips through HBM
+between fusion boundaries.
 
 Reference counterparts: `evaluate_gpu` (`nqueens_gpu_chpl.chpl:97-123`) and
 `evaluate_gpu_lb1` (`evaluate.cu:25-49`, device math `c_bounds_gpu.cu:15-195`)
@@ -25,6 +26,7 @@ from __future__ import annotations
 import dataclasses
 import os
 from functools import lru_cache, partial
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -98,11 +100,10 @@ def _round_up(x: int, k: int) -> int:
 
 def _vmem_limit_bytes() -> int | None:
     """Scoped VMEM ceiling for the PFSP kernels: the Mosaic scoped-VMEM
-    charge. The Mosaic default (16 MB) rejects the lb-family kernels above
-    tile 64 (the (T, n, n) one-hot and the (n, T, m) scan scratch pad n/m
-    up to the 128-lane tile); v5e has 128 MB of VMEM, so raising the scope
-    to 96 MB is safe for a standalone pallas_call and lets the batch tile
-    grow to MXU-efficient sizes."""
+    charge. The Mosaic default (16 MB) is below what the one-kernel
+    cycle's compaction holds at its 128-wide pool tile (about 12 MB at 20
+    jobs, 31 MB at 50); v5e has 128 MB of VMEM, so raising the scope to
+    96 MB is safe for a standalone pallas_call and lets the tiles grow."""
     mb = int(os.environ.get("TTS_PALLAS_VMEM_MB", "96"))
     if mb < 0:
         raise ValueError(f"TTS_PALLAS_VMEM_MB must be >= 0 (0 disables), got {mb}")
@@ -145,21 +146,21 @@ def _model_bytes(t: int, n: int, m: int, extra_bytes: int,
                  pair_group: int = 1, lane_slabs: int = 0) -> int:
     """The kernels' modeled VMEM footprint at batch tile ``t`` — the single
     source of truth shared by the tile chooser and the routing gate.
-    ``tn2_copies`` counts the shared (T, n, n)-class f32 live values
-    (one-hot + reshape copies); ``pair_copies`` the per-pair ones (the
-    self-bound kernel's matmul pair body: u_o/mp0/mp1/cum0/suf1), charged
-    once per member of the unrolled pair group (conservative: Mosaic may overlap the unrolled bodies'
+    ``m`` machines charge the PFSP tile prologue (`_tile_parent_state`,
+    `_prologue_slabs`), ``m = 0`` none (the N-Queens labels).
+    ``tn2_copies`` counts (T, n, n)-class f32 live values (the N-Queens
+    clash planes, the self-bound kernel's free-flag one-hot);
+    ``pair_copies`` the per-pair ones (the self-bound kernel's matmul pair
+    body: u_o/mp0/mp1/cum0/suf1), charged once per member of the unrolled
+    pair group (conservative: Mosaic may overlap the unrolled bodies'
     temporaries, so the model assumes they are all live). ``lane_slabs``
-    counts (n, T) int32 slabs, the parents on the lanes (the TPU lb2 pair
-    loop, `_lb2_lane_slabs`). ``extra_bytes`` adds tile-independent
+    counts further (n, T) int32 slabs, the parents on the lanes (the lb2
+    pair loop, `_lb2_lane_slabs`). ``extra_bytes`` adds tile-independent
     residents (lb2's per-pair tables)."""
     tn2 = (tn2_copies + pair_copies * pair_group) * t * _r8(n) * _r128(n) * 4
-    oh_nt = n * _r8(t) * _r128(n) * 4
-    scan = n * _r8(t) * _r128(m) * 4
-    ptg = t * _r8(n) * _r128(m) * 4
-    chains = 2 * m * t * _r128(n) * 4
-    slabs = lane_slabs * _r8(n) * _r128(t) * 4
-    return tn2 + oh_nt + scan + ptg + chains + slabs + extra_bytes
+    slab = _r8(n) * _r128(t) * 4
+    prologue = (_prologue_slabs(m) * slab + m * 8 * _r128(t) * 4) if m else 0
+    return tn2 + prologue + lane_slabs * slab + extra_bytes
 
 
 def _vmem_budget() -> int:
@@ -174,8 +175,7 @@ def _auto_tile(n: int, m: int, default: int, extra_bytes: int = 0,
 
     The reference rebuilds with bigger compile-time params for large
     instances (`Taillard.chpl:29-52`); here the same kernel covers 20-500
-    jobs by trading batch-tile size for job count — the big matmuls keep
-    T*n rows, so MXU utilization survives small T at large n. The model
+    jobs by trading batch-tile size for job count. The model
     (``_model_bytes``) is checked against half the scoped budget, halving
     the tile until it fits (floor 8)."""
     budget = _vmem_budget()
@@ -206,32 +206,41 @@ def _lb2_static_extra(n: int, m: int, P: int) -> int:
     return (P * _r8(n) * _r128(n) + 3 * P * _r128(n) + 2 * P * _r128(m)) * 4
 
 
+def _prologue_slabs(m: int) -> int:
+    """(n, T) int32 slabs the PFSP tile prologue holds: the (m, n, T)
+    times scratch, the gather's m running slabs, the m child-front slabs,
+    the job and open-slot slabs and two chain temporaries.  Its m (1, T)
+    front rows are charged apart (`_model_bytes`)."""
+    return 3 * m + 4
+
+
 # The single source of truth for each kernel's VMEM-model parameters:
-# (tile env knob, tile default, shared tn2_copies, needs per-pair extra,
+# (tile env knob, tile default, tn2_copies, needs per-pair extra,
 # per-pair tn2 copies — charged once per unrolled pair-group member).
-# Tile defaults: lb1 64 and lb1d 256 are MEASURED on the real v5e
-# (docs/HW_VALIDATION.md; lb1 at 128 compiled >270s — Mosaic compile time
-# grows superlinearly with tile). The lb2 kernel puts the tile's parents
-# on the 128 lanes of its pair loop (`_lb2_tile_lb`), so its tile is 128:
-# the width that fills them. Its pair loop holds no (T, n, n) value and its
-# tables stream through SMEM; `_lb2_lane_slabs` charges its (n, T) slabs.
-# The self-bound kernel keeps a matmul pair body and starts in the
-# compile-time-safe class lb1 proved (64). scripts/tile_sweep.py re-measures per (kernel, tile) so the defaults can
-# be raised with data.
+# Tile defaults: lb1 64 and lb1d 256 were MEASURED on the real v5e with
+# the (T, n, n) one-hot prologue (docs/HW_VALIDATION.md; lb1 at 128
+# compiled >270s then). The lb2 kernel puts the tile's parents on the 128
+# lanes of its pair loop (`_lb2_tile_lb`), so its tile is 128: the width
+# that fills them. The lb1-family and lb2 kernels hold no (T, n, n) value;
+# the prologue's slabs (`_prologue_slabs`) and lb2's (`_lb2_lane_slabs`)
+# are charged per (n, T) slab. The self-bound kernel keeps a (T, n, n)
+# free-flag one-hot and a matmul pair body and starts in the
+# compile-time-safe class lb1 proved (64). scripts/tile_sweep.py
+# re-measures per (kernel, tile) so the defaults can be raised with data.
 _KERNEL_MODEL = {
-    "lb1": ("TTS_TILE_LB1", 64, 3, False, 0),
-    "lb1d": ("TTS_TILE_LB1D", 256, 3, False, 0),
-    "lb2": ("TTS_TILE_LB2", 128, 3, False, 0),
+    "lb1": ("TTS_TILE_LB1", 64, 0, False, 0),
+    "lb1d": ("TTS_TILE_LB1D", 256, 0, False, 0),
+    "lb2": ("TTS_TILE_LB2", 128, 0, False, 0),
     "lb2self": ("TTS_TILE_LB2SELF", 64, 1, True, 5),
 }
 
 
 def _lb2_lane_slabs(n: int, m: int, pg: int) -> int:
-    """(n, T) int32 slabs live in the lb2 pair loop: the m staged
-    child-front columns and the n free-job flag slabs (VMEM scratch), two
-    chains per unrolled pair, and the running max, the transposed
-    permutation, the open-slot mask and one temporary."""
-    return m + n + 2 * pg + 4
+    """(n, T) int32 slabs the lb2 pair loop holds beyond the prologue's:
+    the m staged child-front slabs and the n free-job flag slabs (VMEM
+    scratch), two chains per unrolled pair, the running max and one
+    temporary."""
+    return m + n + 2 * pg + 2
 
 
 def _resolve_pair_group(kernel: str, n: int, P: int | None,
@@ -358,7 +367,7 @@ def nqueens_labels(board, depth, N: int, g: int = 1,
 
 
 # ---------------------------------------------------------------------------
-# PFSP lb1 child bounds
+# PFSP bound tiles: the shared lane-dense prologue, lb1 and lb1_d
 # ---------------------------------------------------------------------------
 
 
@@ -381,128 +390,161 @@ def _hp_dot(a, b, bf16: bool = False):
     )
 
 
-def _front_scan(prmu, limit1, ptm, scan_ref, n: int, m: int,
-                bf16: bool = False):
-    """The masked schedule_front scan (`c_bound_simple.c:51-69`) over a
-    (T, n) permutation tile — shared by `_tile_parent_state` and the
-    staged self-bound kernel.  Returns the (T, m) int32 front.
-
-    ``scan_ref`` is an (n, T, m) VMEM scratch: Mosaic cannot dynamic_slice
-    a *value* with the traced loop index, but it can dynamically index a
-    Ref on its leading axis — so the scan's per-position processing times
-    are staged there (position-major: the same one-hot trick as the child
-    gather, rows swapped so the reshape lands (n, T, m) without a 3-D
-    transpose) and the fori_loop reads ``scan_ref[i]``."""
-    T = prmu.shape[0]
-    iota_nT = jax.lax.broadcasted_iota(jnp.int32, (n, T, n), 2)
-    oh_nT = (iota_nT == prmu.T[:, :, None]).astype(jnp.float32)
-    pts = (
-        _hp_dot(oh_nT.reshape(n * T, n), ptm, bf16)
-        .reshape(n, T, m).astype(jnp.int32)
-    )
-
-    def step(i, pt, front):
-        cols = [front[:, 0] + pt[:, 0]]
-        for j in range(1, m):
-            cols.append(jnp.maximum(cols[-1], front[:, j]) + pt[:, j])
-        newf = jnp.stack(cols, axis=-1)
-        return jnp.where((i <= limit1)[:, None], newf, front)
-
-    front0 = jnp.zeros((T, m), jnp.int32)
-    scan_ref[...] = pts
-    return jax.lax.fori_loop(
-        0, n, lambda i, f: step(i, scan_ref[i], f), front0
-    )
+def _pfsp_table_specs(n: int, m: int, index):
+    """in_specs of the instance tables the PFSP bound kernels read as SMEM
+    scalars (`pfsp_kernel_tables`): the processing times, job-major and
+    flat (``n*m``: job q on machine j at ``q*m + j``), the min heads and
+    the min tails (``m`` each).  A kernel that reads fewer takes a prefix.
+    ``index`` is the grid's constant index map of a 1-D block."""
+    return [pl.BlockSpec((n * m,), index, memory_space=pltpu.SMEM),
+            pl.BlockSpec((m,), index, memory_space=pltpu.SMEM),
+            pl.BlockSpec((m,), index, memory_space=pltpu.SMEM)]
 
 
-def _tile_parent_state(prmu, limit1, ptm, heads, scan_ref, n: int, m: int,
-                       bf16: bool = False):
-    """Shared tile prologue of the PFSP bound kernels: the one-hot MXU gather
-    of per-position processing times, the masked schedule_front scan
-    (`_front_scan`, staged through ``scan_ref``), and the per-child
-    add_forward fronts.
-
-    Returns (onehot, ptg, front, child_front_cols) with child_front_cols a
-    list of m (T, n) columns.
-    """
-    T = prmu.shape[0]
-    jobs_iota = jax.lax.broadcasted_iota(jnp.int32, (T, n, n), 2)
-    onehot = (jobs_iota == prmu[:, :, None]).astype(jnp.float32)
-    ptg = (
-        _hp_dot(onehot.reshape(T * n, n), ptm, bf16)
-        .reshape(T, n, m).astype(jnp.int32)
-    )
-
-    front = _front_scan(prmu, limit1, ptm, scan_ref, n, m, bf16)
-    front = jnp.where((limit1 == -1)[:, None], heads, front)
-
-    # Remaining work per machine over the open positions (sum_unscheduled,
-    # `c_bound_simple.c:108-124`).
-    unsched = (
-        jax.lax.broadcasted_iota(jnp.int32, (T, n), 1) >= (limit1 + 1)[:, None]
-    ).astype(jnp.int32)
-    remain = jnp.sum(ptg * unsched[:, :, None], axis=1)  # (T, m)
-
-    # 2-D static lane slices only: the (T, 1, m) reshape-then-extract form
-    # (front[:, None, :][..., j]) sends Mosaic down a pathological relayout
-    # path — ~17x slower compiles per chain and an XLA `array.h` check crash
-    # in larger compositions (measured on v5e, jax 0.9).
-    child_front = [front[:, 0:1] + ptg[..., 0]]
-    for j in range(1, m):
-        child_front.append(
-            jnp.maximum(child_front[-1], front[:, j:j + 1]) + ptg[..., j]
-        )
-    return onehot, ptg, front, remain, child_front
+def _flat_times(ptm_t):
+    """The (n, m) job-major processing times as the flat int32 table of
+    `_pfsp_table_specs`."""
+    return jnp.reshape(jnp.asarray(ptm_t).astype(jnp.int32), (-1,))
 
 
-def _lb1_tile_lb(prmu, limit1, ptm, heads, tails, scan_ref,
-                 *, n: int, m: int, bf16: bool = False):
+def pfsp_kernel_tables(ptm_t, min_heads, min_tails) -> tuple:
+    """The operands of `_pfsp_table_specs`, in its order."""
+    return (_flat_times(ptm_t), jnp.asarray(min_heads).astype(jnp.int32),
+            jnp.asarray(min_tails).astype(jnp.int32))
+
+
+def _times_scratch(n: int, m: int, t: int):
+    """The prologue's (m, n, T) int32 VMEM scratch: the processing times
+    at each position by machine (`_gather_times`)."""
+    return pltpu.VMEM((m, n, t), jnp.int32)
+
+
+class _TileState(NamedTuple):
+    """The PFSP tile prologue (`_tile_parent_state`) on lane-dense slabs:
+    position or child slot k on the sublanes, the tile's T parents on the
+    lanes."""
+
+    job: object     # (n, T) int32: the job at position k
+    free: object    # (n, T) int32: 1 where position k is open (k > limit1)
+    pt: list        # m (n, T): that job's processing time on machine j
+    front: list     # m (1, T): the parent's schedule front on machine j
+    remain: list    # m (1, T): the open jobs' total time on machine j
+    cf: list        # m (n, T): child k's front on machine j
+
+
+def _gather_times(job, pt, times, *, n: int, m: int):
+    """The processing times at every position of the tile, as m (n, T)
+    slabs (one per machine): a select per job and machine over the (n, T)
+    job slab, the time read from the SMEM table ``pt`` — no one-hot, no
+    matmul.  A job id outside [0, n) reads 0, as a one-hot row of zeros
+    would.  The slabs are also staged into the (m, n, T) scratch
+    ``times``, where `_front_scan` reads them a position row at a time."""
+
+    def body(q, acc):
+        at = job == q
+        return tuple(jnp.where(at, pt[q * m + j], a)
+                     for j, a in enumerate(acc))
+
+    zero = jnp.zeros(job.shape, jnp.int32)
+    slabs = jax.lax.fori_loop(0, n, body, (zero,) * m)
+    for j in range(m):
+        times[j] = slabs[j]
+    return list(slabs)
+
+
+def _front_scan(lim, times, *, n: int, m: int):
+    """The masked schedule_front scan (`c_bound_simple.c:51-69`) on (1, T)
+    lane rows, one per machine: step i reads position i's times as row i
+    of the ``times`` slabs (a dynamic sublane index into the scratch
+    `_gather_times` filled) and runs the add_forward chain over the
+    machines; parent b takes the step while ``i <= lim[b]``.  Returns the
+    m (1, T) front rows (zero where ``lim`` is -1)."""
+
+    def step(i, front):
+        new = []
+        for j in range(m):
+            base = front[0] if j == 0 else jnp.maximum(new[-1], front[j])
+            new.append(base + times[j, pl.ds(i, 1), :])
+        take = i <= lim
+        return tuple(jnp.where(take, a, f) for a, f in zip(new, front))
+
+    zero = jnp.zeros(lim.shape, jnp.int32)
+    return list(jax.lax.fori_loop(0, n, step, (zero,) * m))
+
+
+def _tile_parent_state(prmu, limit1, pt, heads, times, *, n: int,
+                       m: int) -> _TileState:
+    """Shared tile prologue of the PFSP bound kernels, on lane-dense int32
+    slabs: the (T, n) permutation tile is transposed once, so that its T
+    parents sit on the 128 lanes and the positions on the sublanes.  The
+    processing times are gathered per machine (`_gather_times`), the
+    parents' fronts scanned on (1, T) rows (`_front_scan`; ``heads`` at
+    the root, limit1 == -1, `c_bound_simple.c:58-61`), and the remaining
+    work (sum_unscheduled, `c_bound_simple.c:108-124`) and every child's
+    add_forward front are computed on the (n, T) slabs.  No value is 3-D
+    and none is a one-hot: nothing moves between sublanes and lanes but
+    the transposes of the two tile operands in and the caller's one
+    transpose of its (n, T) bound out.  ``limit1`` is the tile's (T, 1)
+    column as its block holds it: transposed, its (1, T) row takes the
+    slabs' (8, 128) layout.  A row made from a 1-D value
+    (``limit1[:, 0][None, :]``) keeps Mosaic's implicit-dimension layout,
+    and every slab computed from it inherits that layout: compiled for a
+    v5e, the lb1 tile at 20 jobs x 10 machines, T = 128, then asks for
+    23 MB of scoped VMEM instead of under 1 MB.  ``pt``/``heads`` are
+    SMEM tables (`_pfsp_table_specs`), ``times`` the (m, n, T) scratch
+    (`_times_scratch`)."""
+    job = prmu.T
+    lim = limit1.T  # (1, T)
+    slot = jax.lax.broadcasted_iota(jnp.int32, job.shape, 0)
+    free = (slot > lim).astype(jnp.int32)
+    pts = _gather_times(job, pt, times, n=n, m=m)
+    root = lim == -1
+    front = [jnp.where(root, heads[j], f)
+             for j, f in enumerate(_front_scan(lim, times, n=n, m=m))]
+    remain = [jnp.sum(p * free, axis=0, keepdims=True) for p in pts]
+    cf = []
+    for j in range(m):
+        base = front[0] if j == 0 else jnp.maximum(cf[-1], front[j])
+        cf.append(base + pts[j])
+    return _TileState(job, free, pts, front, remain, cf)
+
+
+def _lb1_tile_lb(prmu, limit1, pt, heads, tails, times, *, n: int, m: int):
     """(T, n) int32 lb1 bound of every child in the tile — the body of
-    `_lb1_kernel`, shared with the one-kernel cycle (`ops/megakernel.py`)."""
-    _, ptg, _, remain, child_front = _tile_parent_state(
-        prmu, limit1, ptm, heads, scan_ref, n, m, bf16
-    )
-
-    # Child k: machine bound chain, unrolled over m. Per-machine remain as a
-    # 2-D slice (see the relayout note in _tile_parent_state).
-    tmp0 = child_front[0] + (remain[:, 0:1] - ptg[..., 0])
-    lb = tmp0 + tails[0, 0]
+    `_lb1_kernel`, shared with the one-kernel cycle (`ops/megakernel.py`):
+    the machine bound chain (`c_bound_simple.c:126-141`) of every child
+    on the prologue's (n, T) slabs, transposed back once."""
+    s = _tile_parent_state(prmu, limit1, pt, heads, times, n=n, m=m)
+    tmp0 = s.cf[0] + (s.remain[0] - s.pt[0])
+    lb = tmp0 + tails[0]
     for i in range(1, m):
-        tmp1 = jnp.maximum(
-            tmp0, child_front[i] + (remain[:, i:i + 1] - ptg[..., i])
-        )
-        lb = jnp.maximum(lb, tmp1 + tails[0, i])
+        tmp1 = jnp.maximum(tmp0, s.cf[i] + (s.remain[i] - s.pt[i]))
+        lb = jnp.maximum(lb, tmp1 + tails[i])
         tmp0 = tmp1
-    return lb
+    return lb.T
 
 
-def _lb1_kernel(
-    prmu_ref, limit1_ref, ptm_ref, heads_ref, tails_ref, out_ref, scan_ref,
-    *, n: int, m: int, bf16: bool = False
-):
+def _lb1_kernel(prmu_ref, limit1_ref, pt_ref, heads_ref, tails_ref, out_ref,
+                times_ref, *, n: int, m: int):
     """Full lb1 bound of every child of every parent in the tile.
 
     Math identical to `ops/pfsp_device._lb1_chunk` (itself the batched form
     of `c_bound_simple.c:51-141` + one incremental `add_forward` per child);
     here the whole chain runs on one VMEM tile.
     """
-    prmu = prmu_ref[:].astype(jnp.int32)  # (T, n)
-    limit1 = limit1_ref[:, 0].astype(jnp.int32)  # (T,)
-    ptm = ptm_ref[:].astype(jnp.float32)  # (n, m) job-major
     out_ref[:] = _lb1_tile_lb(
-        prmu, limit1, ptm, heads_ref[:], tails_ref[:], scan_ref,
-        n=n, m=m, bf16=bf16,
+        prmu_ref[:].astype(jnp.int32), limit1_ref[:].astype(jnp.int32),
+        pt_ref, heads_ref, tails_ref, times_ref, n=n, m=m,
     )
 
 
 @lru_cache(maxsize=None)
 def _lb1_family_call(kernel_fn, n: int, m: int, B: int, tile: int,
-                     interpret: bool, bf16: bool = False,
-                     name: str = "tts_eval_lb1"):
+                     interpret: bool, name: str = "tts_eval_lb1"):
     """Shared pallas_call factory for the lb1-shaped kernels (lb1 / lb1_d):
-    same operand layout — (prmu, limit1, ptm, heads, tails) -> (B, n) —
-    same tiling, same scan scratch."""
-    kernel = partial(kernel_fn, n=n, m=m, bf16=bf16)
+    same operand layout — (prmu, limit1, the SMEM tables) -> (B, n) — same
+    tiling, same times scratch."""
+    kernel = partial(kernel_fn, n=n, m=m)
     grid = (B // tile,)
     return pl.pallas_call(
         kernel,
@@ -512,12 +554,10 @@ def _lb1_family_call(kernel_fn, n: int, m: int, B: int, tile: int,
         in_specs=[
             _bs((tile, n), lambda i: (i, 0)),
             _bs((tile, 1), lambda i: (i, 0)),
-            _bs((n, m), lambda i: (0, 0)),
-            _bs((1, m), lambda i: (0, 0)),
-            _bs((1, m), lambda i: (0, 0)),
+            *_pfsp_table_specs(n, m, lambda i: (0,)),
         ],
         out_specs=_bs((tile, n), lambda i: (i, 0)),
-        scratch_shapes=[pltpu.VMEM((n, tile, m), jnp.int32)],
+        scratch_shapes=[_times_scratch(n, m, tile)],
         compiler_params=_compiler_params(),
         interpret=interpret,
     )
@@ -525,67 +565,54 @@ def _lb1_family_call(kernel_fn, n: int, m: int, B: int, tile: int,
 
 def _lb1_family_bounds(
     kernel_fn, prmu, limit1, ptm_t, min_heads, min_tails, interpret: bool,
-    bf16: bool = False, kernel_name: str = "lb1",
+    kernel_name: str = "lb1",
 ):
     B, n = prmu.shape
     m = ptm_t.shape[1]
-    # Per-kernel tile defaults are measured, not uniform (_KERNEL_MODEL):
-    # Mosaic compile time for the lb1 kernel grows superlinearly with the
-    # batch tile (64 -> ~16s, 128 -> >270s on v5e), while lb1_d compiles at
-    # 256 in ~50s. Large instances then shrink the tile further until the
-    # VMEM model fits.
+    # Per-kernel tile defaults (_KERNEL_MODEL); large instances shrink the
+    # tile further until the VMEM model fits.
     tile = effective_tile(kernel_name, n, m, batch=B)
     Bp = _round_up(B, tile)
     if Bp != B:
         prmu = jnp.pad(prmu, ((0, Bp - B), (0, 0)))
         limit1 = jnp.pad(limit1, ((0, Bp - B),))
     name = "tts_eval_lb1_d" if kernel_name == "lb1d" else "tts_eval_lb1"
-    out = _lb1_family_call(kernel_fn, n, m, Bp, tile, interpret, bf16,
-                           name)(
+    out = _lb1_family_call(kernel_fn, n, m, Bp, tile, interpret, name)(
         prmu.astype(jnp.int32),
         limit1.astype(jnp.int32)[:, None],
-        ptm_t.astype(jnp.int32),
-        min_heads.astype(jnp.int32)[None, :],
-        min_tails.astype(jnp.int32)[None, :],
+        *pfsp_kernel_tables(ptm_t, min_heads, min_tails),
     )
     return out[:B]
 
 
-def _lb1_d_kernel(
-    prmu_ref, limit1_ref, ptm_ref, heads_ref, tails_ref, out_ref, scan_ref,
-    *, n: int, m: int, bf16: bool = False
-):
+def _lb1_d_kernel(prmu_ref, limit1_ref, pt_ref, heads_ref, tails_ref,
+                  out_ref, times_ref, *, n: int, m: int):
     """lb1_d bounds of every child in the tile: the O(m)-per-child weak bound
     from the parent's front/remain (`add_front_and_bound`,
     `c_bound_simple.c:213-244`; device: `evaluate.cu:51-71`). Math identical
-    to `ops/pfsp_device._lb1_d_chunk`; shares the VMEM tile prologue."""
-    prmu = prmu_ref[:].astype(jnp.int32)  # (T, n)
-    limit1 = limit1_ref[:, 0].astype(jnp.int32)  # (T,)
-    ptm = ptm_ref[:].astype(jnp.float32)  # (n, m)
-    T = prmu.shape[0]
-    _, ptg, front, remain, _ = _tile_parent_state(
-        prmu, limit1, ptm, heads_ref[:], scan_ref, n, m, bf16
+    to `ops/pfsp_device._lb1_d_chunk`; shares the tile prologue and runs
+    on its (n, T) slabs."""
+    s = _tile_parent_state(
+        prmu_ref[:].astype(jnp.int32), limit1_ref[:].astype(jnp.int32),
+        pt_ref, heads_ref, times_ref, n=n, m=m,
     )
-    back = tails_ref[:]  # (1, m)
-    # 2-D slices throughout (see the relayout note in _tile_parent_state).
-    lb = front[:, 0:1] + remain[:, 0:1] + back[0, 0]  # (T, 1) -> (T, n)
-    tmp0 = front[:, 0:1] + ptg[..., 0]  # (T, n)
+    lb = s.front[0] + s.remain[0] + tails_ref[0]  # (1, T)
+    tmp0 = s.front[0] + s.pt[0]  # (n, T)
     for i in range(1, m):
-        tmp1 = jnp.maximum(tmp0, front[:, i:i + 1])
-        lb = jnp.maximum(lb, tmp1 + remain[:, i:i + 1] + back[0, i])
-        tmp0 = tmp1 + ptg[..., i]
-    out_ref[:] = jnp.broadcast_to(lb, (T, n))
+        tmp1 = jnp.maximum(tmp0, s.front[i])
+        lb = jnp.maximum(lb, tmp1 + s.remain[i] + tails_ref[i])
+        tmp0 = tmp1 + s.pt[i]
+    out_ref[:] = jnp.broadcast_to(lb, s.job.shape).T
 
 
 def pfsp_lb1_d_bounds(
     prmu, limit1, ptm_t, min_heads, min_tails, interpret: bool | None = None,
-    bf16: bool = False,
 ):
     """(B, n) int32 lb1_d child bounds; same contract as `_lb1_d_chunk`."""
     interpret = pallas_interpret() if interpret is None else interpret
     return _lb1_family_bounds(
         _lb1_d_kernel, prmu, limit1, ptm_t, min_heads, min_tails, interpret,
-        bf16, kernel_name="lb1d",
+        kernel_name="lb1d",
     )
 
 
@@ -645,16 +672,17 @@ def _lb2_lane_scratch(n: int, m: int, t: int, pg: int):
             pltpu.SemaphoreType.DMA((2,))]
 
 
-def _lb2_tile_lb(prmu, limit1, ptm, heads, lanes: _Lb2Lanes, scan_ref,
-                 *, n: int, m: int, P: int, pg: int = 1, bf16: bool = False):
+def _lb2_tile_lb(prmu, limit1, pt, heads, lanes: _Lb2Lanes, times,
+                 *, n: int, m: int, P: int, pg: int = 1):
     """(T, n) int32 lb2 bound of every child in the tile — the body of
     `_lb2_kernel`, shared with the one-kernel cycle (`ops/megakernel.py`).
 
-    After the shared prologue (`_tile_parent_state`) the tile is turned
-    once into slabs with the child slot k on the sublanes and the T
-    parents on the lanes: the m child-front columns into ``lanes.cf`` and
-    the free-job flags ``U[j][k, b]`` (job j open in parent b and not the
-    job child k schedules) into ``lanes.u``.  Each pair then runs the
+    The shared prologue (`_tile_parent_state`) already holds the tile as
+    (n, T) slabs, the child slot k on the sublanes and the T parents on
+    the lanes: its m child-front slabs are staged as they are into
+    ``lanes.cf``, and the free-job flags ``U[j][k, b]`` (job j open in
+    parent b and not the job child k schedules) are built from its job and
+    open-slot slabs into ``lanes.u``.  Each pair then runs the
     reference's Johnson scan (`c_bound_johnson.c:190-209`) slot by slot
     (`_lb2_slot_unroll` slots to a loop iteration), reading the job and
     its times from SMEM, where each pair group's tables arrive by DMA while
@@ -682,14 +710,10 @@ def _lb2_tile_lb(prmu, limit1, ptm, heads, lanes: _Lb2Lanes, scan_ref,
             lanes.sems.at[half])
 
     fetch(0, 0).start()  # the first group's tables arrive behind the prologue
-    _, _, _, _, cf = _tile_parent_state(
-        prmu, limit1, ptm, heads, scan_ref, n, m, bf16
-    )
+    s = _tile_parent_state(prmu, limit1, pt, heads, times, n=n, m=m)
     for i in range(m):
-        lanes.cf[i] = cf[i].T
-    job = prmu.T  # (n, T): the job child k schedules
-    slot = jax.lax.broadcasted_iota(i32, (T, n), 1)
-    free = (slot > limit1[:, None]).astype(i32).T  # (n, T): slot k open
+        lanes.cf[i] = s.cf[i]
+    job, free = s.job, s.free
 
     def flags(j, carry):
         eq = job == j
@@ -745,9 +769,9 @@ def _lb2_tile_lb(prmu, limit1, ptm, heads, lanes: _Lb2Lanes, scan_ref,
     return lb.T
 
 
-def _lb2_kernel(prmu_ref, limit1_ref, ptm_ref, heads_ref, slots_ref,
-                pairs_ref, out_ref, scan_ref, *lane_scratch, n: int, m: int,
-                P: int, pg: int = 1, bf16: bool = False):
+def _lb2_kernel(prmu_ref, limit1_ref, pt_ref, heads_ref, slots_ref,
+                pairs_ref, out_ref, times_ref, *lane_scratch, n: int, m: int,
+                P: int, pg: int = 1):
     """Full lb2 (two-machine Johnson) bound of every child in the tile.
 
     Math identical to `ops/pfsp_device._lb2_chunk` (`c_bound_johnson.c:
@@ -761,33 +785,30 @@ def _lb2_kernel(prmu_ref, limit1_ref, ptm_ref, heads_ref, slots_ref,
     iteration, so independent chains hide each other's latency."""
     lanes = _Lb2Lanes(slots_ref, pairs_ref, *lane_scratch)
     out_ref[:] = _lb2_tile_lb(
-        prmu_ref[:].astype(jnp.int32), limit1_ref[:, 0].astype(jnp.int32),
-        ptm_ref[:].astype(jnp.float32), heads_ref[:], lanes, scan_ref,
-        n=n, m=m, P=P, pg=pg, bf16=bf16,
+        prmu_ref[:].astype(jnp.int32), limit1_ref[:].astype(jnp.int32),
+        pt_ref, heads_ref, lanes, times_ref, n=n, m=m, P=P, pg=pg,
     )
 
 
 @lru_cache(maxsize=None)
 def _lb2_call(n: int, m: int, P: int, B: int, tile: int, interpret: bool,
-              bf16: bool = False, pg: int = 1, parallel: bool = False):
+              pg: int = 1, parallel: bool = False):
     """The standalone lb2 kernel over ``B // tile`` tiles; ``parallel``
     marks the grid Megacore-splittable (it carries nothing across tiles —
     `megakernel.streamed_eval_bounds`)."""
-    full = lambda i: (0, 0)
     return pl.pallas_call(
-        partial(_lb2_kernel, n=n, m=m, P=P, pg=pg, bf16=bf16),
+        partial(_lb2_kernel, n=n, m=m, P=P, pg=pg),
         out_shape=jax.ShapeDtypeStruct((B, n), jnp.int32),
         name="tts_eval_lb2",
         grid=(B // tile,),
         in_specs=[
             _bs((tile, n), lambda i: (i, 0)),
             _bs((tile, 1), lambda i: (i, 0)),
-            _bs((n, m), full),
-            _bs((1, m), full),
+            *_pfsp_table_specs(n, m, lambda i: (0,))[:2],
             *_lb2_lane_specs(P, lambda i: (0,)),
         ],
         out_specs=_bs((tile, n), lambda i: (i, 0)),
-        scratch_shapes=[pltpu.VMEM((n, tile, m), jnp.int32),
+        scratch_shapes=[_times_scratch(n, m, tile),
                         *_lb2_lane_scratch(n, m, tile, pg)],
         compiler_params=_compiler_params(parallel=parallel),
         interpret=interpret,
@@ -796,7 +817,7 @@ def _lb2_call(n: int, m: int, P: int, B: int, tile: int, interpret: bool,
 
 def lb2_kernel_tables(ordered, pg: int) -> tuple:
     """The pair-table operands of an lb2 kernel over ``ordered`` (padded to
-    a multiple of the pair group ``pg``), after ptm and the heads
+    a multiple of the pair group ``pg``), after the times and the heads
     (`_Lb2Lanes`: the slot table holds each group at its
     `_lb2_group_stride`, then the per-pair table)."""
     P, n = ordered.lag_o.shape
@@ -815,15 +836,12 @@ def _eager_context() -> bool:
 
 
 def pfsp_lb2_bounds(prmu, limit1, tables, interpret: bool | None = None,
-                    bf16: bool | None = None,
                     pair_group: int | None = None):
     """(B, n) int32 lb2 child bounds; same contract as `_lb2_chunk`.
     ``pair_group``: pair-group unroll per grid step (None resolves the
     shared TTS_LB2_PAIRBLOCK knob); the pair tables are padded to a
     multiple of it with copies of pair 0 (max is idempotent)."""
     interpret = pallas_interpret() if interpret is None else interpret
-    if bf16 is None:
-        bf16 = getattr(tables, "exact_bf16", False)
     B, n = prmu.shape
     m = tables.ptm_t.shape[1]
     P = tables.pairs.shape[0]
@@ -840,11 +858,11 @@ def pfsp_lb2_bounds(prmu, limit1, tables, interpret: bool | None = None,
     ordered = (tables.johnson_ordered_device(pg) if _eager_context()
                else tables.johnson_ordered_mp(pg))
     Pp = ordered.lag_o.shape[0]
-    out = _lb2_call(n, m, Pp, Bp, tile, interpret, bf16, pg)(
+    out = _lb2_call(n, m, Pp, Bp, tile, interpret, pg)(
         prmu.astype(jnp.int32),
         limit1.astype(jnp.int32)[:, None],
-        tables.ptm_t,
-        tables.min_heads[None, :],
+        *pfsp_kernel_tables(tables.ptm_t, tables.min_heads,
+                            tables.min_tails)[:2],
         *lb2_kernel_tables(ordered, pg),
     )
     return out[:B]
@@ -852,13 +870,11 @@ def pfsp_lb2_bounds(prmu, limit1, tables, interpret: bool | None = None,
 
 def pfsp_lb1_bounds(
     prmu, limit1, ptm_t, min_heads, min_tails, interpret: bool | None = None,
-    bf16: bool = False,
 ):
     """(B, n) int32 lb1 child bounds; same contract as `_lb1_chunk`."""
     interpret = pallas_interpret() if interpret is None else interpret
     return _lb1_family_bounds(
         _lb1_kernel, prmu, limit1, ptm_t, min_heads, min_tails, interpret,
-        bf16,
     )
 
 
@@ -868,9 +884,9 @@ def pfsp_lb1_bounds(
 
 
 def _lb2_self_kernel(
-    prmu_ref, limit1_ref, nact_ref, ptm_ref,
+    prmu_ref, limit1_ref, nact_ref, pt_ref,
     p0_ref, p1_ref, lag_ref, t0_ref, t1_ref, msel0_ref, msel1_ref, jorder_ref,
-    out_ref, scan_ref, *, n: int, m: int, P: int, tile: int, pg: int = 1,
+    out_ref, times_ref, *, n: int, m: int, P: int, tile: int, pg: int = 1,
     bf16: bool = False,
 ):
     """Johnson bound of each ROW's own partial schedule (the staged
@@ -886,14 +902,20 @@ def _lb2_self_kernel(
     def _active():
         prmu = prmu_ref[:].astype(jnp.int32)  # (T, n)
         limit1 = limit1_ref[:, 0].astype(jnp.int32)  # (T,) — always >= 0
-        ptm = ptm_ref[:].astype(jnp.float32)  # (n, m)
         T = prmu.shape[0]
         hp = _hp_dot
 
-        # schedule_front via the position-major scan staging
-        # (`_front_scan`).
-        front = _front_scan(prmu, limit1, ptm, scan_ref, n, m,
-                            bf16).astype(jnp.float32)
+        # schedule_front: the prologue's gather and scan on the transposed
+        # tile (`_gather_times`, `_front_scan`), its m (1, T) rows laid
+        # out as an (m, T) tile and transposed once to (T, m).
+        _gather_times(prmu.T, pt_ref, times_ref, n=n, m=m)
+        rows = _front_scan(limit1_ref[:].astype(jnp.int32).T, times_ref,
+                           n=n, m=m)
+        machine = jax.lax.broadcasted_iota(jnp.int32, (m, T), 0)
+        front = jnp.zeros((m, T), jnp.int32)
+        for j, row in enumerate(rows):
+            front = jnp.where(machine == j, row, front)
+        front = front.T.astype(jnp.float32)
 
         # Free flags by job id.
         jobs_iota = jax.lax.broadcasted_iota(jnp.int32, (T, n, n), 2)
@@ -954,7 +976,6 @@ def _lb2_self_call(n: int, m: int, P: int, R: int, tile: int, interpret: bool,
     kernel = partial(_lb2_self_kernel, n=n, m=m, P=P, tile=tile, pg=pg,
                      bf16=bf16)
     grid = (R // tile,)
-    full = lambda i: (0, 0)
     full3 = lambda i: (0, 0, 0)
     return pl.pallas_call(
         kernel,
@@ -965,7 +986,7 @@ def _lb2_self_call(n: int, m: int, P: int, R: int, tile: int, interpret: bool,
             _bs((tile, n), lambda i: (i, 0)),
             _bs((tile, 1), lambda i: (i, 0)),
             _bs((1,), lambda i: (0,), space="smem"),
-            _bs((n, m), full),
+            *_pfsp_table_specs(n, m, lambda i: (0,))[:1],
             _bs((P, 1, n), full3),
             _bs((P, 1, n), full3),
             _bs((P, 1, n), full3),
@@ -976,7 +997,7 @@ def _lb2_self_call(n: int, m: int, P: int, R: int, tile: int, interpret: bool,
             _bs((P, n, n), full3),
         ],
         out_specs=_bs((tile, 1), lambda i: (i, 0)),
-        scratch_shapes=[pltpu.VMEM((n, tile, m), jnp.int32)],
+        scratch_shapes=[_times_scratch(n, m, tile)],
         compiler_params=_compiler_params(),
         interpret=interpret,
     )
@@ -1025,7 +1046,7 @@ def pfsp_lb2_self_bounds_tables(prmu, limit1, n_active, ptm_t, ordered,
         prmu.astype(jnp.int32),
         limit1.astype(jnp.int32)[:, None],
         jnp.asarray(n_active, dtype=jnp.int32).reshape(1),
-        ptm_t,
+        _flat_times(ptm_t),
         ordered.p0_o[:, None, :],
         ordered.p1_o[:, None, :],
         ordered.lag_o[:, None, :],

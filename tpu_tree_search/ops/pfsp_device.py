@@ -465,7 +465,6 @@ def lb1_bounds(prmu, limit1, tables: "PFSPDeviceTables", device=None):
     if lb1_kernel_routed(n, m, device):
         return PK.pfsp_lb1_bounds(
             prmu, limit1, tables.ptm_t, tables.min_heads, tables.min_tails,
-            bf16=tables.exact_bf16,
         )
     return _lb1_chunk(prmu, limit1, tables.ptm_t, tables.min_heads,
                       tables.min_tails, bf16=tables.exact_bf16)
@@ -480,7 +479,6 @@ def lb1_d_bounds(prmu, limit1, tables: "PFSPDeviceTables", device=None):
     if lb1_kernel_routed(n, m, device):
         return PK.pfsp_lb1_d_bounds(
             prmu, limit1, tables.ptm_t, tables.min_heads, tables.min_tails,
-            bf16=tables.exact_bf16,
         )
     return _lb1_d_chunk(
         prmu, limit1, tables.ptm_t, tables.min_heads, tables.min_tails,

@@ -53,7 +53,7 @@ if kind == "kernel":
     limit1 = jnp.zeros((B,), dtype=jnp.int32)
     if lb == "lb1":
         out = PK.pfsp_lb1_bounds(prmu, limit1, t.ptm_t, t.min_heads,
-                                 t.min_tails, bf16=t.exact_bf16)
+                                 t.min_tails)
     else:
         out = PK.pfsp_lb2_bounds(prmu, limit1, t)
     out.block_until_ready()
